@@ -236,10 +236,14 @@ class AotScorer:
                 if not isinstance(d, dict) or d.get("v") != _BLOB_VERSION:
                     raise ArtifactError("unsupported executable blob "
                                         "version")
+                import jax
                 from jax.experimental import serialize_executable as se
 
-                loaded = se.deserialize_and_load(d["payload"], d["in_tree"],
-                                                 d["out_tree"])
+                # artifact programs are single-device by contract: load on
+                # one device, or the executable wants a shard per device
+                loaded = se.deserialize_and_load(
+                    d["payload"], d["in_tree"], d["out_tree"],
+                    execution_devices=jax.devices()[:1])
             except pickle.UnpicklingError:
                 raise            # tampered blob: refuse, never fall back
             except Exception:    # noqa: BLE001 — backend can't load: HLO
@@ -258,9 +262,15 @@ class AotScorer:
                     "mapping — re-export the artifact on a current "
                     "framework build")
             import jax
+            from jax.extend import backend as jex_backend
+            from jaxlib import xla_client as xc
 
             text = _read_payload(self.dir, e).decode("utf-8")
-            raw = jax.devices()[0].client.compile(text)
+            dev = jax.devices()[0]
+            raw = dev.client.compile_and_load(
+                text, xc.DeviceList((dev,)),
+                jex_backend.get_compile_options(num_replicas=1,
+                                                num_partitions=1))
             self._exec[bucket] = ("raw", raw, [int(i) for i in kept])
             self.loaded_from[bucket] = "hlo"
             return self._exec[bucket]

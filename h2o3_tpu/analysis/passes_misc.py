@@ -6,8 +6,8 @@
   untrusted input and one raw load is a pickle-RCE door.
 - **compat-routing** — device-only / version-mobile jax APIs
   (``registry.DEVICE_ONLY_APIS``) must be imported through
-  ``h2o3_tpu/compat.py``, never directly: a direct import crashes the
-  CPU/old-jax fallback paths the container relies on.
+  ``h2o3_tpu/compat.py``, never directly: compat.py is written against
+  the one pinned jax, so an upgrade is edited there and nowhere else.
 - **sync-hygiene** — inside ``obs.tracing.span(...)``-instrumented
   blocks, device-sync-forcing calls (``np.asarray``/``np.array`` on
   device values, ``.block_until_ready()``, ``jax.device_get``,
@@ -108,7 +108,7 @@ def run_compat(ctx: Context) -> List[Finding]:
             findings.append(ctx.finding(
                 "compat-routing", mod, node,
                 f"direct {how} of `{api}` ({apis[api]}) — route through "
-                f"h2o3_tpu/compat.py so CPU/old-jax fallbacks survive",
+                f"h2o3_tpu/compat.py, the one module edited on a jax upgrade",
                 symbol=mod.rel))
 
         for node in ast.walk(mod.tree):

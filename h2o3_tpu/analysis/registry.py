@@ -45,6 +45,7 @@ KNOB_HELPERS = frozenset({
     "h2o3_tpu.scoring._env_buckets",
     "h2o3_tpu.parallel.ckpt.job_ckpt_iters",
     "h2o3_tpu.core.runtime.OptArgs.from_env",      # boot-time config fold
+    "h2o3_tpu.core.runtime.place_jax_cache",       # JAX cache DIR (host I/O)
     "h2o3_tpu.core.sharded_frame.enabled",         # H2O_TPU_SHARDED_PLANE
     "h2o3_tpu.rapids.fusion.enabled",              # H2O_TPU_RAPIDS_FUSION
     "h2o3_tpu.rapids.planner.enabled",             # H2O_TPU_RAPIDS_LAZY —
@@ -211,14 +212,14 @@ PICKLE_ALLOWED = (
 # device-only / version-mobile jax APIs that must be imported via
 # h2o3_tpu/compat.py (module prefix -> why)
 DEVICE_ONLY_APIS = {
-    "jax.experimental.shard_map": "moved to jax.shard_map in 0.5",
-    "jax.shard_map": "absent before 0.5 — use compat.shard_map",
-    "jax.experimental.serialize_executable": "moved/changed signature "
-                                             "across releases",
-    "jax.experimental.pallas": "TPU-only lowering; CPU fallback must not "
-                               "import-crash",
-    "jax.profiler": "kwargs shifted across releases; REST maps its "
-                    "errors to clean 4xx",
+    "jax.experimental.shard_map": "gone in the pinned jax — use "
+                                  "compat.shard_map",
+    "jax.shard_map": "use compat.shard_map",
+    "jax.experimental.serialize_executable": "experimental API; loads "
+                                             "must name their devices",
+    "jax.experimental.pallas": "TPU kernel surface, imported at call "
+                               "time only",
+    "jax.profiler": "REST maps its errors to clean 4xx",
 }
 COMPAT_MODULE = "h2o3_tpu/compat.py"
 

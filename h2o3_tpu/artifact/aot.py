@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import io
 import pickle
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import numpy as np
 
@@ -78,19 +78,19 @@ def lower_bucket(bucket: int, meta: Dict[str, Any], edges, is_cat, init,
     return fn.lower(*_arg_structs(bucket, edges, is_cat, init, forest_args))
 
 
-def serialize_exec_blob(compiled) -> Optional[bytes]:
-    """Executable -> self-contained blob (None when this jax cannot
-    serialize executables). The blob is a pickle of
-    ``{v, payload, in_tree, out_tree}`` — loaded ONLY through
-    :func:`load_exec_blob`'s restricted unpickler."""
+def serialize_exec_blob(compiled) -> bytes:
+    """Executable -> self-contained blob: a pickle of
+    ``{v, payload, in_tree, out_tree, devices}`` — loaded ONLY through
+    :func:`load_exec_blob`'s restricted unpickler. ``devices`` are the ids
+    the program was compiled for, so a mesh-wide serving executable loads
+    back over the same mesh and a single-device one over one device.
+    Raises when the backend cannot serialize the executable."""
     from h2o3_tpu import compat
 
-    got = compat.serialize_compiled(compiled)
-    if got is None:
-        return None
-    payload, in_tree, out_tree = got
+    payload, in_tree, out_tree = compat.serialize_compiled(compiled)
     return pickle.dumps({"v": BLOB_VERSION, "payload": payload,
-                         "in_tree": in_tree, "out_tree": out_tree},
+                         "in_tree": in_tree, "out_tree": out_tree,
+                         "devices": compat.compiled_device_ids(compiled)},
                         protocol=pickle.HIGHEST_PROTOCOL)
 
 
@@ -120,7 +120,8 @@ def load_exec_blob(blob: bytes):
         raise ValueError(f"unsupported executable blob version "
                          f"{d.get('v') if isinstance(d, dict) else '?'}")
     return compat.deserialize_compiled(d["payload"], d["in_tree"],
-                                       d["out_tree"])
+                                       d["out_tree"],
+                                       device_ids=d.get("devices"))
 
 
 def kept_arg_indices(compiled, text: str, nargs: int):
@@ -144,8 +145,8 @@ def kept_arg_indices(compiled, text: str, nargs: int):
 
 
 def compile_bucket(bucket: int, meta: Dict[str, Any], edges, is_cat, init,
-                   forest_args) -> Tuple[Any, Optional[bytes], str, Any]:
-    """AOT-compile one bucket; returns (compiled, blob_or_None, stablehlo
+                   forest_args) -> Tuple[Any, bytes, str, Any]:
+    """AOT-compile one bucket; returns (compiled, blob, stablehlo
     text, kept_arg_indices_or_None)."""
     from h2o3_tpu.obs import compiles
 

@@ -98,10 +98,9 @@ def _export_glm(model, out_dir: str, buckets: List[int]) -> Dict[str, Any]:
     execs, hlos = [], []
     for b in buckets:
         _compiled, blob, text, kept = glm.compile_glm_bucket(b, model)
-        if blob is not None:
-            e = manifest.write_payload(out_dir, f"exec_b{b}.bin", blob)
-            e.update(bucket=b, backend=fingerprint)
-            execs.append(e)
+        e = manifest.write_payload(out_dir, f"exec_b{b}.bin", blob)
+        e.update(bucket=b, backend=fingerprint)
+        execs.append(e)
         h = manifest.write_payload(out_dir, f"hlo_b{b}.mlir",
                                    text.encode("utf-8"))
         h.update(bucket=b, kept_args=kept)
@@ -176,10 +175,9 @@ def export_model(model, out_dir: str,
     for b in buckets:
         _compiled, blob, text, kept = aot.compile_bucket(
             b, meta, edges, is_cat, init, forest_args)
-        if blob is not None:
-            e = manifest.write_payload(out_dir, f"exec_b{b}.bin", blob)
-            e.update(bucket=b, backend=fingerprint)
-            execs.append(e)
+        e = manifest.write_payload(out_dir, f"exec_b{b}.bin", blob)
+        e.update(bucket=b, backend=fingerprint)
+        execs.append(e)
         h = manifest.write_payload(out_dir, f"hlo_b{b}.mlir",
                                    text.encode("utf-8"))
         h.update(bucket=b, kept_args=kept)

@@ -120,9 +120,9 @@ def lower_glm_bucket(bucket: int, model):
 
 
 def compile_glm_bucket(bucket: int, model
-                       ) -> Tuple[Any, Optional[bytes], str, Any]:
+                       ) -> Tuple[Any, bytes, str, Any]:
     """AOT-compile the GLM program for one row bucket; returns
-    (compiled, blob_or_None, stablehlo_text, kept_arg_indices_or_None) —
+    (compiled, blob, stablehlo_text, kept_arg_indices_or_None) —
     the GLM twin of aot.compile_bucket, ledger family "artifact"."""
     from h2o3_tpu.artifact import aot
     from h2o3_tpu.obs import compiles

@@ -269,7 +269,7 @@ def _scorer_fn(cap, inner: str, model):
 def compile_pipeline_bucket(bucket: int, cap, inner: str, model,
                             sig_hash: str):
     """AOT-compile one bucket of the fused pipeline; returns (compiled,
-    blob_or_None, stablehlo_text, kept_arg_indices_or_None)."""
+    blob, stablehlo_text, kept_arg_indices_or_None)."""
     import jax
 
     from h2o3_tpu.obs import compiles
@@ -346,10 +346,9 @@ def export_pipeline(model, frame, out_dir: str,
     for b in buckets:
         _compiled, blob, text, kept = compile_pipeline_bucket(
             b, cap, inner, model, sig_hash)
-        if blob is not None:
-            e = manifest.write_payload(out_dir, f"exec_b{b}.bin", blob)
-            e.update(bucket=b, backend=fingerprint)
-            execs.append(e)
+        e = manifest.write_payload(out_dir, f"exec_b{b}.bin", blob)
+        e.update(bucket=b, backend=fingerprint)
+        execs.append(e)
         h = manifest.write_payload(out_dir, f"hlo_b{b}.mlir",
                                    text.encode("utf-8"))
         h.update(bucket=b, kept_args=kept)

@@ -1,81 +1,72 @@
-"""JAX version compatibility shims.
-
-The framework targets the promoted `jax.shard_map` API; older jax releases
-(< 0.5) only ship it as `jax.experimental.shard_map.shard_map`. Every
-shard_map call site routes through :func:`shard_map` so the framework runs
-on both without scattering version checks."""
+"""The one place the framework touches jax APIs that sit outside the
+stable `jax.numpy` / `jax.jit` surface: shard_map, the varying-axis cast,
+executable (de)serialization, the profiler, Pallas, memory analysis and the
+raw StableHLO compile. Written for the one installation there is —
+jax 0.9.0 / jaxlib 0.9.0 (pinned in pyproject.toml); each function is the
+direct 0.9.0 call. Call sites and the analyzer's `compat-routing` pass
+import these names, so an upgrade is edited here and nowhere else."""
 
 from __future__ import annotations
 
 
-def shard_map(f=None, **kw):
-    """`jax.shard_map` where available, else the experimental spelling.
-    Translates the renamed replication-check kwarg (check_vma, jax>=0.6)
-    to the older check_rep when falling back."""
-    import inspect
-
+def shard_map(f, **kw):
+    """`jax.shard_map` (keywords: mesh, in_specs, out_specs, check_vma)."""
     import jax
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    if "check_vma" in kw and "check_vma" not in params:
-        kw["check_rep"] = kw.pop("check_vma")
-    elif "check_rep" in kw and "check_rep" not in params:
-        kw["check_vma"] = kw.pop("check_rep")
-    return sm(f, **kw) if f is not None else lambda g: sm(g, **kw)
+    return jax.shard_map(f, **kw)
 
 
 def pcast(x, axes, to="varying"):
-    """`jax.lax.pcast` (jax>=0.7 varying-mesh-axis annotation) with an
-    identity fallback: older shard_map has no vma system, so replicated→
-    varying casts are no-ops there."""
+    """`jax.lax.pcast`: annotate a replicated value as varying over mesh
+    `axes` (loop carries inside shard_map must be typed like the
+    per-shard data they accumulate)."""
     import jax
 
-    pc = getattr(jax.lax, "pcast", None)
-    if pc is not None:
-        return pc(x, axes, to=to)
-    pv = getattr(jax.lax, "pvary", None)
-    if pv is not None and to == "varying":
-        return pv(x, axes)
-    return x
+    return jax.lax.pcast(x, axes, to=to)
 
 
 # ---------------------------------------------------------------------------
 # AOT executable (de)serialization — the artifact/compile-cache substrate.
-# jax.experimental.serialize_executable has moved/changed signature across
-# releases; every artifact/cache call site routes through these three shims
-# so a jax without the API degrades to the StableHLO / recompile fallbacks
-# instead of crashing the exporter or the loader.
 # ---------------------------------------------------------------------------
 
 def serialize_compiled(compiled):
     """Serialize an AOT-compiled executable (``jit(f).lower(...).compile()``)
-    to ``(payload_bytes, in_tree, out_tree)``, or None when this jax/backend
-    cannot serialize executables (the caller falls back to StableHLO)."""
-    try:
-        from jax.experimental import serialize_executable as se
-
-        return se.serialize(compiled)
-    except Exception:   # noqa: BLE001 — capability probe by contract
-        return None
-
-
-def deserialize_compiled(payload, in_tree, out_tree):
-    """Load a serialized executable back into a callable. Raises when the
-    payload targets a different backend/topology or the API is missing —
-    callers treat any raise as 'unavailable on this target' and fall back."""
+    to ``(payload_bytes, in_tree, out_tree)``. Raises when the backend
+    cannot serialize it — on a TPU that is an error to see, not a reason
+    to quietly ship an artifact without its executable."""
     from jax.experimental import serialize_executable as se
 
-    return se.deserialize_and_load(payload, in_tree, out_tree)
+    return se.serialize(compiled)
+
+
+def compiled_device_ids(compiled):
+    """Ids of the devices an AOT-compiled executable runs on — stored next
+    to its serialized payload so the load targets the same devices."""
+    return [int(d.id) for d in compiled.runtime_executable().local_devices()]
+
+
+def deserialize_compiled(payload, in_tree, out_tree, device_ids=None):
+    """Load a serialized executable back into a callable on the devices it
+    was compiled for (`device_ids`; default: the first device — a
+    single-device program loaded over every local device would demand one
+    argument shard per device). Raises when the payload targets a
+    different backend/topology — callers treat that as a cache miss."""
+    import jax
+    from jax.experimental import serialize_executable as se
+
+    devs = jax.devices()
+    if device_ids is None:
+        devs = devs[:1]
+    else:
+        by_id = {d.id: d for d in devs}
+        devs = [by_id[i] for i in device_ids]
+    return se.deserialize_and_load(payload, in_tree, out_tree,
+                                   execution_devices=devs)
 
 
 def profiler_start(log_dir: str) -> None:
-    """``jax.profiler.start_trace`` across jax releases (the API predates
-    0.4 but its kwargs have shifted): positional log_dir only, which every
-    supported release accepts. Raises when a capture is already running —
-    the REST layer maps that to a clean 409."""
+    """``jax.profiler.start_trace``. Raises when a capture is already
+    running — the REST layer maps that to a clean 409."""
     import jax
 
     jax.profiler.start_trace(log_dir)
@@ -90,67 +81,47 @@ def profiler_stop() -> None:
 
 
 def profiler_annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` — a named region inside a capture
-    (the API spelling has been stable, but it lives on the same
-    version-mobile module as start/stop_trace, so it routes here too)."""
+    """``jax.profiler.TraceAnnotation`` — a named region inside a capture."""
     import jax
 
     return jax.profiler.TraceAnnotation(name)
 
 
 def pallas_modules():
-    """``(pallas, pallas.tpu)`` — the TPU kernel surface. Pallas is a
-    device-only lowering that has moved within jax.experimental across
-    releases; importing it at call time through this shim keeps CPU-only
-    deployments importable (callers already guard execution behind
-    ``H2O_TPU_PALLAS_HIST`` / interpret mode). The tpu submodule is None
-    when this jax does not ship it — callers fall back to default memory
-    spaces."""
+    """``(pallas, pallas.tpu)`` — the TPU kernel surface, imported at call
+    time so importing the package never pulls Pallas in."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError:         # pragma: no cover — very old jax
-        pltpu = None
     return pl, pltpu
 
 
 def memory_analysis(compiled):
     """Byte-level memory estimate of an AOT-compiled executable
-    (``compiled.memory_analysis()`` — the API and its field names are
-    version-mobile, and some backends return None). Normalized to
-    ``{argument_bytes, output_bytes, temp_bytes, generated_code_bytes}``
-    (missing fields omitted), or None when this jax/backend cannot say —
-    the compile ledger records it as the program's HBM estimate
-    ("Memory Safe Computations with XLA Compiler", PAPERS.md)."""
-    try:
-        ma = compiled.memory_analysis()
-    except Exception:   # noqa: BLE001 — capability probe by contract
-        return None
+    (``compiled.memory_analysis()``), normalized to
+    ``{argument_bytes, output_bytes, temp_bytes, generated_code_bytes}``,
+    or None when the backend reports none — the compile ledger records it
+    as the program's HBM estimate ("Memory Safe Computations with XLA
+    Compiler", PAPERS.md)."""
+    ma = compiled.memory_analysis()
     if ma is None:
         return None
-    out = {}
-    for key, attrs in (
-            ("argument_bytes", ("argument_size_in_bytes",)),
-            ("output_bytes", ("output_size_in_bytes",)),
-            ("temp_bytes", ("temp_size_in_bytes",)),
-            ("generated_code_bytes", ("generated_code_size_in_bytes",))):
-        for a in attrs:
-            v = getattr(ma, a, None)
-            if v is not None:
-                try:
-                    out[key] = int(v)
-                except (TypeError, ValueError):
-                    pass
-                break
-    return out or None
+    return {"argument_bytes": int(ma.argument_size_in_bytes),
+            "output_bytes": int(ma.output_size_in_bytes),
+            "temp_bytes": int(ma.temp_size_in_bytes),
+            "generated_code_bytes": int(ma.generated_code_size_in_bytes)}
 
 
 def compile_stablehlo(text: str):
-    """Portable lowering fallback: compile StableHLO module text through the
-    local XLA client. Returns an executable whose ``.execute([arrays])``
-    runs the program on the default device — the exact program the exporter
-    lowered, so results stay bitwise-identical to the source process."""
+    """Compile StableHLO module text through the local XLA client for the
+    first device. Returns a loaded executable whose ``.execute([arrays])``
+    runs the exact program the exporter lowered, so results stay
+    bitwise-identical to the source process."""
     import jax
+    from jax.extend import backend as jex_backend
+    from jaxlib import xla_client as xc
 
-    return jax.devices()[0].client.compile(text)
+    d = jax.devices()[0]
+    return d.client.compile_and_load(
+        text, xc.DeviceList((d,)),
+        jex_backend.get_compile_options(num_replicas=1, num_partitions=1))

@@ -21,6 +21,24 @@ from typing import Optional, Sequence
 import numpy as np
 
 
+# <checkout>/.jax_cache (listed in .gitignore)
+DEFAULT_JAX_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def place_jax_cache() -> None:
+    """JAX's persistent compile cache, placed from outside: where
+    JAX_COMPILATION_CACHE_DIR says when it is set (then nothing is set in
+    code), else one fixed directory in the checkout. The path is how a
+    later process finds the cache again, so it never holds a temp dir, a
+    pid or a timestamp. Called once, before the boot's first compile."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_JAX_CACHE)
+
+
 @dataclass
 class OptArgs:
     """Config/flag system (reference: water/H2O.java:316 OptArgs).
@@ -79,11 +97,8 @@ class Cluster:
         self.args = args
         self.start_time = time.time()
         self._jax = jax
-        # the boot sequence below is the engine's historically-dark path
-        # (ROADMAP item 1: every BENCH_r03-r05 device round wedged BEFORE
-        # any stage body, in backend init / the first tiny compile) —
-        # each step is now its own deadline-supervised lifecycle phase
-        # with timeline events, so a wedge names itself
+        # each boot step is its own deadline-supervised lifecycle phase
+        # with timeline events, so a start-up that hangs names the step
         if args.coordinator_address and args.num_processes > 1:
             with phases.enter("cloud_form", processes=args.num_processes):
                 jax.distributed.initialize(
@@ -93,7 +108,9 @@ class Cluster:
                 )
         with phases.enter("backend_init",
                           platforms=os.environ.get("JAX_PLATFORMS", "")):
-            # first XLA client touch — THE wedge site of the r03 autopsy
+            # first XLA client touch. Whatever platform JAX booted is
+            # the platform: nothing below switches to another one, and
+            # callers that need a chip (chip_smoke.py) assert it
             platform = jax.default_backend()
         with phases.enter("device_discovery", platform=platform):
             self.devices = (list(args.devices) if args.devices
@@ -116,10 +133,10 @@ class Cluster:
                 from h2o3_tpu.core.failure import HeartbeatThread
 
                 self._heartbeat = HeartbeatThread(interval_s=5.0).start()
+        place_jax_cache()
         with phases.enter("first_compile"):
             # the supervised tiny boot compile: separates "backend up but
-            # first compile wedges" from "backend init wedges" — exactly
-            # the distinction the r03-r05 autopsies could not make
+            # the first compile hangs" from "backend init hangs"
             import jax.numpy as jnp
 
             from h2o3_tpu.obs import compiles

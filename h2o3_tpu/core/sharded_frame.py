@@ -15,7 +15,7 @@ through the coordinator host.
 - **named row axis** — ``ROW_AXIS`` ("rows"), the mesh axis every column's
   ``NamedSharding`` partitions; the same axis the fused scorers
   ``shard_map`` over (compressed.py ``_fused_score_sharded_fn``, routed
-  through ``compat.shard_map`` for this container's jax).
+  through ``compat.shard_map``).
 - **pack_features** — the serving fast path's (bucket, F) float32 feature
   matrix built by ONE compiled program whose output keeps the row
   sharding: each process materializes only its addressable shards

@@ -227,8 +227,7 @@ def _forest_margins(binned, feat, thresh, na_left, left, right, leaf_val,
             nxt = jnp.where(go_left, tl[node], tr[node])
             return jnp.where(leaf, node, nxt)
 
-        node = jax.lax.fori_loop(0, max_depth + 1, step,
-                                 jnp.zeros(N, jnp.int32))
+        node = jax.lax.fori_loop(0, max_depth + 1, step, node0)
         contrib = tlv[node]
         if K > 1:
             acc = acc.at[:, tcls].add(contrib)
@@ -236,7 +235,14 @@ def _forest_margins(binned, feat, thresh, na_left, left, right, leaf_val,
             acc = acc + contrib
         return acc, None
 
-    acc0 = jnp.zeros((N, K), jnp.float32) if K > 1 else jnp.zeros(N, jnp.float32)
+    # loop carries are derived from `binned` so they carry its type: under
+    # shard_map the rows vary over the mesh axis and a fresh jnp.zeros
+    # would not, which the scan carry check rejects; under plain jit this
+    # is the same zeros
+    node0 = jnp.zeros_like(binned[:, 0], dtype=jnp.int32)
+    acc0 = node0.astype(jnp.float32)
+    if K > 1:
+        acc0 = jnp.broadcast_to(acc0[:, None], (N, K))
     acc, _ = jax.lax.scan(
         walk_one_tree, acc0,
         (feat, thresh, na_left, left, right, leaf_val, cat_split, tree_class))
@@ -288,8 +294,6 @@ def _forest_leaves(binned, feat, thresh, na_left, left, right, cat_split,
     import jax
     import jax.numpy as jnp
 
-    N = binned.shape[0]
-
     def walk(carry, tree):
         tf, tt, tnl, tl, tr, tcs = tree
 
@@ -307,10 +311,11 @@ def _forest_leaves(binned, feat, thresh, na_left, left, right, cat_split,
             return jnp.where(leaf, node,
                              jnp.where(go_left, tl[node], tr[node]))
 
-        node = jax.lax.fori_loop(0, max_depth + 1, step,
-                                 jnp.zeros(N, jnp.int32))
+        node = jax.lax.fori_loop(0, max_depth + 1, step, node0)
         return carry, node
 
+    # typed like the rows it walks (see _forest_margins)
+    node0 = jnp.zeros_like(binned[:, 0], dtype=jnp.int32)
     _, leaves = jax.lax.scan(
         walk, None, (feat, thresh, na_left, left, right, cat_split))
     return jnp.transpose(leaves)       # (N, T)
@@ -359,8 +364,8 @@ def _fused_score_fn(max_depth: int, nclasses: int, per_class: bool = False):
 def _fused_score_sharded_fn(max_depth: int, nclasses: int, per_class: bool,
                             mesh):
     """Sharded-data-plane serving path: the SAME fused core, executed per
-    row shard under shard_map over the named 'rows' axis (via the
-    compat.py shim for this jax). X arrives already row-sharded from
+    row shard under shard_map over the named 'rows' axis (via
+    compat.py). X arrives already row-sharded from
     ShardedFrame.pack_features; the forest/BinSpec tables are replicated
     (in_specs P()). Every op is per-row, so there is NO cross-shard
     communication inside the program — each process scores only its

@@ -1,4 +1,4 @@
-"""Loader for the native C++ fast CSV parser (built lazily via make).
+"""Loader for the native C++ fast CSV parser (built lazily with g++).
 
 The reference's ingest hot loop is Java (water/parser/CsvParser.java:16
 parseChunk); its only native code arrives via the XGBoost JNI channel
@@ -10,6 +10,7 @@ the pandas path in ingest/parser.py when the shared lib isn't built."""
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,30 +20,61 @@ import numpy as np
 
 _HERE = os.path.dirname(__file__)
 _LIB_PATH = os.path.join(_HERE, "libh2o3tpu.so")
+# hash of the sources the .so next to it was built from: a library whose
+# stamp does not match today's .cpp files (or that has none — copied in from
+# another machine) is rebuilt here rather than trusted
+_STAMP_PATH = _LIB_PATH + ".sha256"
+_SOURCES = ("csv_parser.cpp", "treeshap.cpp")
 _LOCK = threading.Lock()
 _LIB = None
 _TRIED = False
 
 
-def _build() -> bool:
-    srcs = [os.path.join(_HERE, f) for f in ("csv_parser.cpp", "treeshap.cpp")
-            if os.path.exists(os.path.join(_HERE, f))]
-    if not srcs:
-        return False
+def _source_hash() -> Optional[str]:
+    """sha256 over the native sources present in this checkout (None when
+    there are none)."""
+    h = hashlib.sha256()
+    found = False
+    for name in _SOURCES:
+        try:
+            with open(os.path.join(_HERE, name), "rb") as f:
+                h.update(name.encode() + b"\0" + f.read())
+            found = True
+        except OSError:
+            continue
+    return h.hexdigest() if found else None
+
+
+def _built_hash() -> Optional[str]:
     try:
-        # build to a temp name then rename: an in-place relink would reuse
-        # the inode, and glibc dlopen dedupes by dev/inode — a stale mapped
-        # handle would be returned by the next CDLL (and truncating a mapped
-        # .so can SIGBUS calls into the old mapping)
-        tmp = _LIB_PATH + ".build"
+        with open(_STAMP_PATH, encoding="ascii") as f:
+            return f.read().strip() or None
+    except OSError:
+        return None
+
+
+def _build(src_hash: str) -> bool:
+    srcs = [os.path.join(_HERE, f) for f in _SOURCES
+            if os.path.exists(os.path.join(_HERE, f))]
+    # build to a temp name then rename: an in-place relink would reuse
+    # the inode, and glibc dlopen dedupes by dev/inode — a stale mapped
+    # handle would be returned by the next CDLL (and truncating a mapped
+    # .so can SIGBUS calls into the old mapping). No -march=native: the
+    # library must run on whatever host the tree is copied to.
+    tmp = f"{_LIB_PATH}.{os.getpid()}.build"
+    try:
         subprocess.run(
-            ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
+            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
              "-pthread", "-o", tmp] + srcs,
             check=True, capture_output=True, timeout=120,
         )
         os.replace(tmp, _LIB_PATH)
+        with open(_STAMP_PATH + ".part", "w", encoding="ascii") as f:
+            f.write(src_hash)
+        os.replace(_STAMP_PATH + ".part", _STAMP_PATH)
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
+        # no compiler / compile error: callers take their Python paths
         return False
 
 
@@ -62,22 +94,22 @@ def _wire_treeshap(lib) -> None:
 
 
 def get_lib():
+    """The native library, (re)built when its stamp differs from the hash
+    of today's sources; None when it cannot be built (no g++) — callers
+    then take their pure-Python paths."""
     global _LIB, _TRIED
     with _LOCK:
         if _LIB is not None or _TRIED:
             return _LIB
         _TRIED = True
-        if not os.path.exists(_LIB_PATH) and not _build():
+        src_hash = _source_hash()
+        if src_hash is None:
+            return None
+        if not (os.path.exists(_LIB_PATH) and _built_hash() == src_hash) \
+                and not _build(src_hash):
             return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
-            if not hasattr(lib, "h2o_treeshap") and \
-                    os.path.exists(os.path.join(_HERE, "treeshap.cpp")):
-                # stale .so from before treeshap.cpp existed: rebuild once
-                # (the rename in _build gives the new lib a fresh inode, so
-                # this CDLL loads it instead of the deduped old mapping)
-                if _build():
-                    lib = ctypes.CDLL(_LIB_PATH)
             if hasattr(lib, "h2o_treeshap"):
                 _wire_treeshap(lib)
             lib.h2o_parse_csv.restype = ctypes.c_longlong

@@ -15,10 +15,9 @@ Now EVERY explicit XLA compile in the repo routes through here
 ledger row: program family (closed :data:`FAMILIES` enumeration),
 signature hash, wall duration ms, cache disposition
 (compile | memory | disk), device kind, and the optional HBM estimate
-from ``compiled.memory_analysis()`` (via the ``compat.py`` shim — the
-API is version-mobile). Cache HITS are recorded by the same chokepoint
-(:func:`record_hit`), so the per-family table on ``GET /3/Runtime``
-tells hit ratios, not just compile counts.
+from ``compiled.memory_analysis()`` (via ``compat.py``). Cache HITS are
+recorded by the same chokepoint (:func:`record_hit`), so the per-family
+table on ``GET /3/Runtime`` tells hit ratios, not just compile counts.
 
 The legacy ``artifact/compile_cache.note_compile()`` counter is now a
 VIEW over this ledger: the ledger times the compile itself and feeds the

@@ -112,8 +112,7 @@ class TaskProfile:
 @contextlib.contextmanager
 def trace(log_dir: str):
     """Capture an XLA profiler trace around a code block (profiler API
-    routed through compat.py — its kwargs have shifted across jax
-    releases)."""
+    routed through compat.py)."""
     from h2o3_tpu import compat
 
     compat.profiler_start(log_dir)
