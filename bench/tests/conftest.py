@@ -1,0 +1,11 @@
+"""bench/tests run by hand, on the CPU:  python -m pytest bench/tests -q
+(they are outside tier-1's tests/)."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
