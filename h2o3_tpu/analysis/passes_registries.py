@@ -121,7 +121,7 @@ def _declared_kinds(ctx: Context) -> set:
 def run_timeline_kinds(ctx: Context) -> List[Finding]:
     declared = _declared_kinds(ctx)
     call_pat = re.compile(
-        r"\btimeline\.(?:record|task)\(\s*['\"]([^'\"]+)['\"]")
+        r"\btimeline\.record\(\s*['\"]([^'\"]+)['\"]")
     bare_pat = re.compile(r"(?<![\w.])record\(\s*['\"]([^'\"]+)['\"]")
     used = {}
     for mod in _src_texts(ctx):

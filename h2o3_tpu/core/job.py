@@ -25,6 +25,7 @@ import traceback
 from typing import Any, Callable, Optional
 
 from h2o3_tpu.core.dkv import DKV, Key, Keyed
+from h2o3_tpu.obs import tracing
 
 
 class JobCancelled(Exception):
@@ -106,8 +107,19 @@ class Job(Keyed):
         # dead collective when the supervisor failed the job) can never
         # write this job's verdict or result once a resume is in flight
         gen = self.attempt
+        # the caller's trace (a REST POST's ``ingress``) follows the work
+        # onto the worker thread: span ``job`` and whatever `fn` opens
+        # beneath it land in that trace, after the POST has returned
+        ctx = tracing.context()
 
         def run():
+            with tracing.activate(ctx), \
+                    tracing.span("job", job=str(self.key),
+                                 description=self.description) as sp:
+                work()
+                sp.set(status=self.status)
+
+        def work():
             with self._status_lock:
                 if self.status == Job.FAILED or self.attempt != gen:
                     # the supervisor failed this job while still CREATED

@@ -25,7 +25,6 @@ from __future__ import annotations
 
 from h2o3_tpu.compat import shard_map as _compat_shard_map
 import functools
-import time
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -60,29 +59,12 @@ def _build_map_reduce(fn, n_in: int, mesh):
 
 def map_reduce(fn: Callable, cols: Sequence[Column]):
     """doAll-style map/reduce: fn sees this shard's slice of each column and
-    returns a pytree of reduction partials; result is the psum over shards.
-    Under H2O_TPU_PROFILE=1, per-phase timings land in the TimeLine ring
-    (MRTask.profile analog; the sync phase forces a device wait)."""
+    returns a pytree of reduction partials; result is the psum over shards."""
     from h2o3_tpu.core.failure import faultpoint
-    from h2o3_tpu.utils import timeline
 
     faultpoint("mrtask.map_reduce")     # chaos hook (core/failure.py)
     arrays = tuple(c.data for c in cols)
-    if not timeline.profiling_enabled():
-        return _build_map_reduce(fn, len(arrays), _mesh())(*arrays)
-    prof = timeline.TaskProfile(getattr(fn, "__name__", "map_reduce"))
-    t0 = time.perf_counter()
-    run = _build_map_reduce(fn, len(arrays), _mesh())
-    t1 = time.perf_counter()
-    out = run(*arrays)
-    t2 = time.perf_counter()
-    jax.block_until_ready(out)
-    t3 = time.perf_counter()
-    prof.build_ms = (t1 - t0) * 1000
-    prof.run_ms = (t2 - t1) * 1000
-    prof.sync_ms = (t3 - t2) * 1000
-    prof.emit()
-    return out
+    return _build_map_reduce(fn, len(arrays), _mesh())(*arrays)
 
 
 @functools.lru_cache(maxsize=512)

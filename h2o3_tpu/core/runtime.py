@@ -134,12 +134,13 @@ class Cluster:
 
                 self._heartbeat = HeartbeatThread(interval_s=5.0).start()
         place_jax_cache()
+        from h2o3_tpu.obs import compiles
+
+        compiles.watch_backend_compiles()
         with phases.enter("first_compile"):
             # the supervised tiny boot compile: separates "backend up but
             # the first compile hangs" from "backend init hangs"
             import jax.numpy as jnp
-
-            from h2o3_tpu.obs import compiles
 
             exe = compiles.compile_jit(
                 "probe", jax.jit(lambda x: x + jnp.float32(1)),

@@ -22,6 +22,7 @@ from h2o3_tpu.core.frame import Frame, T_CAT
 from h2o3_tpu.core.job import Job
 from h2o3_tpu.models import metrics as M
 from h2o3_tpu.models.model import Model, ModelCategory
+from h2o3_tpu.obs import tracing
 
 
 def random_seed() -> int:
@@ -283,9 +284,14 @@ class ModelBuilder:
             model._output.cross_validation_holdout_predictions = cv_preds
         if fold_digest is not None:
             model._output.fold_assignment_digest = fold_digest
-        model._output.training_metrics = self._score_on(model, train)
+        # stage spans ``metrics``: each ends where the metrics' host values
+        # are read, which is where this pass has always blocked
+        with tracing.span("metrics", frame="train", rows=train.nrows):
+            model._output.training_metrics = self._score_on(model, train)
         if valid is not None:
-            model._output.validation_metrics = self._score_on(model, valid)
+            with tracing.span("metrics", frame="valid", rows=valid.nrows):
+                model._output.validation_metrics = self._score_on(model,
+                                                                  valid)
         if cv_metrics:
             model._output.cv_fold_metrics = cv_metrics
             model._output.cross_validation_metrics = _mean_metrics(cv_metrics)

@@ -243,9 +243,11 @@ def _forest_margins(binned, feat, thresh, na_left, left, right, leaf_val,
     acc0 = node0.astype(jnp.float32)
     if K > 1:
         acc0 = jnp.broadcast_to(acc0[:, None], (N, K))
-    acc, _ = jax.lax.scan(
-        walk_one_tree, acc0,
-        (feat, thresh, na_left, left, right, leaf_val, cat_split, tree_class))
+    with jax.named_scope("walk"):      # metadata: device time by scope
+        acc, _ = jax.lax.scan(
+            walk_one_tree, acc0,
+            (feat, thresh, na_left, left, right, leaf_val, cat_split,
+             tree_class))
     return acc
 
 
@@ -330,7 +332,10 @@ def _fused_margins(X, edges, is_cat, init, feat, thresh, na_left, left,
     (_fused_score_sharded_fn) — every op is row-local, so the two lower to
     bitwise-identical per-row programs. Binning is _bin_features (the
     BinSpec.bin_columns-bitwise core)."""
-    binned = _bin_features(X, edges, is_cat, na_bins)
+    import jax
+
+    with jax.named_scope("bin"):
+        binned = _bin_features(X, edges, is_cat, na_bins)
     acc = _forest_margins(binned, feat, thresh, na_left, left, right,
                           leaf_val, cat_split, cat_table, tree_class,
                           na_bins, max_depth, K)

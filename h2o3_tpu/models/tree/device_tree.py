@@ -337,14 +337,19 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
                 else:
                     hist_fn = (hist_matmul if S <= MATMUL_S_LIMIT
                                else hist_scatter)
-                hist = hist_fn(binned, row_node, live, w, yc, S)
+                # named scopes are metadata on the ops: a profiler trace
+                # can sum device time by level and stage, whatever numbers
+                # XLA gives its fusions
+                with jax.named_scope(f"level{d}/hist"):
+                    hist = hist_fn(binned, row_node, live, w, yc, S)
                 fm = masks[d] if has_masks else None
-                (split_feat, t_star, na_left, gain,
-                 left_table, tot) = _search_level(
-                    hist, nbins=nbins, is_cat=is_cat, maxB=maxB,
-                    min_rows=min_rows,
-                    min_split_improvement=min_split_improvement,
-                    feat_mask=fm)
+                with jax.named_scope(f"level{d}/search"):
+                    (split_feat, t_star, na_left, gain,
+                     left_table, tot) = _search_level(
+                        hist, nbins=nbins, is_cat=is_cat, maxB=maxB,
+                        min_rows=min_rows,
+                        min_split_improvement=min_split_improvement,
+                        feat_mask=fm)
             else:
                 split_feat = jnp.full(S, -1, jnp.int32)
                 t_star = jnp.zeros(S, jnp.int32)
@@ -393,19 +398,21 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
                  right_slot.astype(jnp.float32)[:, None]], axis=1)  # (S, K)
             packed = packed.at[d, :S, :].set(row)
 
-            node = row_node
-            terminal = split_feat[node] < 0
-            gid = offs[d] + node
-            row_leaf = jnp.where(live & terminal, gid, row_leaf)
-            f_sel = jnp.maximum(split_feat[node], 0)
-            b = jnp.take_along_axis(binned, f_sel[:, None], axis=1)[:, 0]
-            gl = left_table[node, jnp.minimum(b, maxB - 1)]
-            row_node = jnp.where(
-                live & ~terminal,
-                jnp.where(gl, left_slot[node], right_slot[node]), 0)
+            with jax.named_scope(f"level{d}/route"):
+                node = row_node
+                terminal = split_feat[node] < 0
+                gid = offs[d] + node
+                row_leaf = jnp.where(live & terminal, gid, row_leaf)
+                f_sel = jnp.maximum(split_feat[node], 0)
+                b = jnp.take_along_axis(binned, f_sel[:, None], axis=1)[:, 0]
+                gl = left_table[node, jnp.minimum(b, maxB - 1)]
+                row_node = jnp.where(
+                    live & ~terminal,
+                    jnp.where(gl, left_slot[node], right_slot[node]), 0)
 
-        cols = jnp.stack([w, w * y, num, den], axis=-1)
-        leaf4 = leaf_sums(row_leaf, cols)
+        with jax.named_scope("leaf_sums"):
+            cols = jnp.stack([w, w * y, num, den], axis=-1)
+            leaf4 = leaf_sums(row_leaf, cols)
         row_leaf = jnp.where(row_leaf >= tot_slots, -1, row_leaf)  # clear pad
         return packed, leaf4, row_leaf[:n]
 

@@ -20,6 +20,7 @@ from h2o3_tpu.models.model import ModelCategory
 from h2o3_tpu.models.model_builder import register
 from h2o3_tpu.models.tree.compressed import CompressedForest
 from h2o3_tpu.models.tree.shared_tree import SharedTree, SharedTreeModel
+from h2o3_tpu.obs import tracing
 
 
 _DRF_STEPS = {}
@@ -261,6 +262,7 @@ class DRF(SharedTree):
         # stopping may truncate) so the summed traversal averages correctly
         from h2o3_tpu.models.tree.device_tree import assemble_trees
 
+        tracing.advance("assemble", trees=len(packs))
         total = t_base + len(packs)
         trees = assemble_trees(packs, leaf_means, leaf_wys, spec, max_depth,
                                scale=1.0 / total)
@@ -361,6 +363,7 @@ class DRF(SharedTree):
                     "rng_state": rng.bit_generator.state})
         from h2o3_tpu.models.tree.device_tree import assemble_trees
 
+        tracing.advance("assemble", trees=len(packs))
         total = t_base + len(packs) // K
         trees = assemble_trees(packs, leaf_means, leaf_wys, spec, max_depth,
                                scale=1.0 / total)

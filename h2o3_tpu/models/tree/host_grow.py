@@ -30,11 +30,7 @@ def grow_tree_host(binned, hist_w, hist_y, spec, *, max_depth: int,
                    rng: Optional[np.random.Generator] = None):
     """Grow one tree level-wise. Returns (HostTree, row_leaf device array)
     with DENSE leaf ids (tree.n_leaves counts them)."""
-    import time as _time
-
     import jax.numpy as jnp
-
-    from h2o3_tpu.utils import timeline
 
     N = binned.shape[0]
     tree = HostTree()
@@ -54,14 +50,9 @@ def grow_tree_host(binned, hist_w, hist_y, spec, *, max_depth: int,
         tree.nodes[0].weight = float(jnp.sum(w32))
         tree.nodes[0].pred = wy / max(tree.nodes[0].weight, 1e-12)
 
-    # per-level timings under H2O_TPU_PROFILE (this grower is the one
-    # place a level boundary exists on the host; the profile-mode sync is
-    # the routing pass the level already blocks on below)
-    profile = timeline.profiling_enabled()
     for depth in range(max_depth + 1):
         if not slots:
             break
-        t_lvl0 = _time.perf_counter()
         S = len(slots)
         # the final level never splits, so it never builds a histogram
         # (the max_depth=0 root stats come from the pre-loop reductions)
@@ -112,9 +103,4 @@ def grow_tree_host(binned, hist_w, hist_y, spec, *, max_depth: int,
             binned, row_node, row_leaf, split_feat=split_feat, left_table=lt,
             left_slot=left_slot, right_slot=right_slot, leaf_id=leaf_id)
         slots = next_slots
-        if profile:
-            row_node.block_until_ready()
-            timeline.record("tree", f"level_{depth}",
-                            ms=(_time.perf_counter() - t_lvl0) * 1000,
-                            active_nodes=S, next_nodes=len(slots))
     return tree, row_leaf

@@ -29,6 +29,7 @@ from h2o3_tpu.models.model import Model, ModelCategory
 from h2o3_tpu.models.model_builder import ModelBuilder, register
 from h2o3_tpu.models.tree.binning import BinSpec
 from h2o3_tpu.models.tree.compressed import CompressedForest
+from h2o3_tpu.obs import tracing
 
 # jitted per-tree glue, cached across train() calls — every eager jnp op in
 # the boosting loop is a separate device dispatch; fusing the
@@ -390,15 +391,17 @@ class SharedTree(ModelBuilder):
                 raise ValueError(
                     "checkpoint: training frame columns/domains differ from "
                     f"the original run ({prev._output.names} vs {out.names})")
-            spec = prev.spec
-        else:
-            spec = BinSpec.build(train, out.names,
-                                 nbins=int(self.params["nbins"]),
-                                 nbins_cats=int(self.params["nbins_cats"]),
-                                 seed=self._seed())
+        # stage span ``bin``: the host waits in it for the quantile edges;
+        # the bin matrix is only dispatched and drains into ``trees``
+        nbins, nbins_cats = (int(self.params["nbins"]),
+                             int(self.params["nbins_cats"]))
+        with tracing.span("bin", rows=train.nrows):
+            spec = prev.spec if prev is not None else BinSpec.build(
+                train, out.names, nbins=nbins, nbins_cats=nbins_cats,
+                seed=self._seed())
+            binned = spec.bin_columns(train)
         self._ckpt = prev
         model.spec = spec
-        binned = spec.bin_columns(train)
         N = binned.shape[0]
 
         w_user = None
@@ -453,12 +456,17 @@ class SharedTree(ModelBuilder):
             }
         t0 = time.time()
         try:
-            if multinomial:
-                forest, f = self._fit_multinomial(model, binned, y, w, offset,
-                                                  spec, nclasses, rng, ntrees)
-            else:
-                forest, f = self._fit_single(model, binned, y, w, offset,
-                                             spec, dist, rng, ntrees)
+            # stage span ``trees``; the fit loop moves it on to ``assemble``
+            # (tracing.advance) once its last tree's metric has been read
+            with tracing.span("trees", ntrees=ntrees, rows=train.nrows,
+                              max_depth=int(self.params["max_depth"])):
+                if multinomial:
+                    forest, f = self._fit_multinomial(
+                        model, binned, y, w, offset, spec, nclasses, rng,
+                        ntrees)
+                else:
+                    forest, f = self._fit_single(model, binned, y, w, offset,
+                                                 spec, dist, rng, ntrees)
         finally:
             self._vstate = None
             self._ckpt = None
@@ -543,12 +551,9 @@ class SharedTree(ModelBuilder):
         from h2o3_tpu.core.failure import faultpoint
 
         from h2o3_tpu.obs import metrics as obs_metrics
-        from h2o3_tpu.utils import timeline
 
-        profile = timeline.profiling_enabled()
         for t in range(t_start, ntrees):
             faultpoint("tree.fit_tree")     # chaos hook (core/failure.py)
-            t_tree0 = time.perf_counter()
             z, w_t, num_r, den_r, _mask = pre(y, f, w, root_key,
                                               np.int32(t), sample_rate)
             feat_mask_fn = self._feat_mask_fn(rng, spec)
@@ -559,14 +564,6 @@ class SharedTree(ModelBuilder):
                 feat_masks=masks)
             gamma, f = post(leaf4, row_leaf, f, self._tree_lr(t))
             obs_metrics.inc("h2o3_tree_trees_built_total")
-            if profile:
-                # per-tree device wall time: the sync is the documented
-                # H2O_TPU_PROFILE trade-off (never paid by default — the
-                # async dispatch pipeline stays sync-free otherwise)
-                f.block_until_ready()
-                timeline.record("tree", f"tree_{t}",
-                                ms=(time.perf_counter() - t_tree0) * 1000,
-                                depth=max_depth, rows=N)
             packs.append(stash_packed(packed, max_depth))
             leaf_vals.append(gamma)
             leaf_wys.append(leaf4[:, :2])
@@ -608,6 +605,7 @@ class SharedTree(ModelBuilder):
         # ONE batched fetch for every tree's tables + leaf values
         from h2o3_tpu.models.tree.device_tree import assemble_trees
 
+        tracing.advance("assemble", trees=len(packs))
         trees = assemble_trees(packs, leaf_vals, leaf_wys, spec, max_depth)
         varimp: Dict[str, float] = self._ckpt_varimp0()
         for tree in trees:
@@ -724,11 +722,8 @@ class SharedTree(ModelBuilder):
                 rng.bit_generator.state = rs["rng_state"]
         jp_every = self._job_ckpt_every()
         from h2o3_tpu.obs import metrics as obs_metrics
-        from h2o3_tpu.utils import timeline
 
-        profile = timeline.profiling_enabled()
         for t in range(t_start, ntrees):
-            t_tree0 = time.perf_counter()
             feat_mask_fn = self._feat_mask_fn(rng, spec)
             masks = build_feat_masks(max_depth, feat_mask_fn, spec.F, maxB)
             for k in range(K):
@@ -752,12 +747,6 @@ class SharedTree(ModelBuilder):
                     f_valid = f_valid.at[:, k].add(
                         apply_packed(vs["binned"], packed, gamma,
                                      max_depth, maxB))
-            if profile:
-                # same H2O_TPU_PROFILE-only sync as the single-class loop
-                f.block_until_ready()
-                timeline.record("tree", f"iter_{t}",
-                                ms=(time.perf_counter() - t_tree0) * 1000,
-                                depth=max_depth, classes=K)
             if self._should_score(t, ntrees):
                 ll = float(jnp.sum(-w * jnp.log(jnp.maximum(
                     jax.nn.softmax(f, axis=-1)[jnp.arange(N), yi], 1e-15))) /
@@ -796,6 +785,7 @@ class SharedTree(ModelBuilder):
 
         from h2o3_tpu.models.tree.device_tree import assemble_trees
 
+        tracing.advance("assemble", trees=len(packs))
         trees = assemble_trees(packs, leaf_vals, leaf_wys, spec, max_depth)
         varimp: Dict[str, float] = self._ckpt_varimp0()
         for tree in trees:

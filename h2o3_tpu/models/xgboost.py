@@ -19,6 +19,7 @@ import numpy as np
 
 from h2o3_tpu.models.model_builder import register
 from h2o3_tpu.models.tree.gbm import GBM, GBMModel
+from h2o3_tpu.obs import tracing
 
 
 _STEP_FNS_DART = {}
@@ -272,6 +273,7 @@ class XGBoost(GBM):
             if self.job:
                 self.job.update(progress=(t + 1) / ntrees, msg=f"tree {t + 1}")
 
+        tracing.advance("assemble", trees=len(packs))
         trees = assemble_trees(packs, leaf_vals, leaf_wys, spec, max_depth)
         varimp = {}
         for tree in trees:

@@ -167,6 +167,48 @@ def record_hit(family: str, signature: Any = None, tier: str = "memory",
 
 
 # ---------------------------------------------------------------------------
+# every backend compile, whoever asked for it
+# ---------------------------------------------------------------------------
+
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_WATCHING = False
+
+
+def _on_backend_compile(event: str, secs: float, **_kw) -> None:
+    """``jax.monitoring`` calls this on the compiling thread, right after
+    the compile: the ledger above sees only its own call sites, this sees
+    eager ops and bare jits too (and a load from JAX's persistent cache,
+    which the event wraps as well: a short one). Under an active span the
+    compile becomes its child ``compile``, so ``/3/Trace/{id}`` names the
+    step that recompiled."""
+    if event != BACKEND_COMPILE_EVENT:
+        return
+    from h2o3_tpu.obs import metrics, tracing
+
+    metrics.inc("h2o3_backend_compiles_total")
+    metrics.inc("h2o3_backend_compile_seconds_total", secs)
+    ctx = tracing.context()
+    if ctx is not None:
+        end = tracing.now_ms()
+        tracing.record_span("compile", ctx, end - secs * 1000.0, end,
+                            seconds=round(secs, 6))
+
+
+def watch_backend_compiles() -> None:
+    """Register the listener above, once per process (the boot calls this
+    before its first compile; JAX has no way to take a listener back)."""
+    global _WATCHING
+    with _LOCK:
+        if _WATCHING:
+            return
+        _WATCHING = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(
+        _on_backend_compile)
+
+
+# ---------------------------------------------------------------------------
 # the chokepoint entries (the ONLY legal spellings of an XLA compile —
 # enforced by the `compile-ledger` analysis pass)
 # ---------------------------------------------------------------------------

@@ -459,6 +459,11 @@ def _install_default_metrics() -> None:
               "cloud health state transitions, by target state")
     r.counter("h2o3_tree_trees_built_total",
               "trees built across all forest trainers")
+    r.counter("h2o3_backend_compiles_total",
+              "XLA backend compiles seen by jax.monitoring: ledgered call "
+              "sites, bare jits and eager ops alike")
+    r.counter("h2o3_backend_compile_seconds_total",
+              "seconds spent in those backend compiles")
     r.counter("h2o3_log_messages_total",
               "framework log records, by level (warning and up)")
 

@@ -9,11 +9,23 @@ replay + ack land as children of the coordinator's publish span — so
 coordinator publish → follower replay → ack form ONE span tree,
 retrievable from ``GET /3/Trace/{trace_id}``.
 
-The scoring fast path emits child spans for queue-wait / pack / dispatch /
-blocking-fetch. None of them adds a device synchronization: span timing is
-host wall-clock around calls the path already makes (the fused-path
-``gathered_rows``/compile counters assert the path itself is unchanged —
-see tests).
+A trace follows its request onto other threads: ``core/job.py`` captures
+the POST's context and runs the job body under it (span ``job``, with the
+builders' stage spans ``bin`` / ``trees`` / ``assemble`` / ``metrics``
+beneath), and the micro-batcher's flush leader records ``queue_wait`` and
+``flush`` into every coalesced request's own trace. The scoring fast path
+emits ``adapt`` / ``pack`` / ``dispatch`` / ``fetch`` / ``metrics`` under
+``flush``. None of them adds a device synchronization: a span is host time
+around calls the path already makes and ends where the host already blocks
+(the fused-path ``gathered_rows``/compile counters assert the path itself
+is unchanged — see tests).
+
+One clock: ``now_ms()`` is ``time.perf_counter_ns()`` laid on the epoch
+once, at import, so timestamps read like wall time and never step. Every
+live span is also a ``jax.profiler.TraceAnnotation`` named
+``h2o3.<span name>``: under a profiler capture (``POST /3/Profiler/start``)
+the program's spans lie on the host planes of the same ``.xplane.pb`` as
+the device ops; with no capture running that is a TraceMe no-op.
 
 Cost model: ``span()`` is a no-op (no allocation, no store write) unless
 the calling thread has an ACTIVE trace — library-mode predict() pays one
@@ -32,11 +44,13 @@ import time
 import uuid
 from typing import Any, Dict, List, Optional
 
+from h2o3_tpu import compat     # jax only inside its functions
+
 _SPAN_CAP = 512                 # spans kept per trace
 _KV_PREFIX = "obs/span/"
 _KV_KEEP = 512                  # remote-published span keys kept in the KV
 
-_TLS = threading.local()        # .stack: list of active span dicts
+_TLS = threading.local()        # .stack: live _SpanCtx / activate frames
 _LOCK = threading.Lock()
 # trace_id -> list of finished span dicts (insertion-ordered eviction)
 _STORE: "collections.OrderedDict[str, List[dict]]" = collections.OrderedDict()
@@ -50,8 +64,15 @@ def trace_cap() -> int:
         return 256
 
 
-def _now_ms() -> float:
-    return time.time() * 1000.0
+# epoch ns at perf_counter 0: taken once, so the clock below never steps
+_EPOCH_NS = time.time_ns() - time.perf_counter_ns()
+
+
+def now_ms() -> float:
+    """Monotonic ms that read like ``time.time() * 1000``: the one clock of
+    every span, and of callers that time a wait themselves for
+    ``record_span``."""
+    return (_EPOCH_NS + time.perf_counter_ns()) / 1e6
 
 
 def _stack() -> list:
@@ -63,7 +84,7 @@ def _stack() -> list:
 
 def current() -> Optional[dict]:
     st = getattr(_TLS, "stack", None)
-    return st[-1] if st else None
+    return st[-1].span if st else None
 
 
 def current_trace_id() -> Optional[str]:
@@ -108,7 +129,7 @@ def _store(span: dict) -> None:
 
 
 def _finish(span: dict) -> None:
-    span["end_ms"] = round(_now_ms(), 3)
+    span["end_ms"] = round(now_ms(), 3)
     span["ms"] = round(span["end_ms"] - span["start_ms"], 3)
     _store(span)
 
@@ -142,7 +163,7 @@ def _new_span(name: str, trace_id: str, parent_id: Optional[str],
               attrs: Dict[str, Any]) -> dict:
     return {"trace_id": trace_id, "span_id": uuid.uuid4().hex[:12],
             "parent_id": parent_id, "name": name,
-            "proc": _proc_index(), "start_ms": round(_now_ms(), 3),
+            "proc": _proc_index(), "start_ms": round(now_ms(), 3),
             "status": "ok",
             "attrs": {k: v for k, v in attrs.items() if v is not None}}
 
@@ -151,10 +172,11 @@ class _SpanCtx:
     """Context manager over one span; ``None``-like when tracing is
     inactive (``bool(span_cm)`` is False and ``ctx()`` returns None)."""
 
-    __slots__ = ("span",)
+    __slots__ = ("span", "_ann")
 
     def __init__(self, span: Optional[dict]):
         self.span = span
+        self._ann = None
 
     def __bool__(self):
         return self.span is not None
@@ -169,22 +191,44 @@ class _SpanCtx:
         if self.span is not None:
             self.span["attrs"].update(attrs)
 
+    def _open(self) -> None:
+        _stack().append(self)
+        self._ann = compat.profiler_annotation("h2o3." + self.span["name"])
+        self._ann.__enter__()
+
+    def _close(self) -> None:
+        self._ann.__exit__(None, None, None)
+        st = _stack()
+        if st and st[-1] is self:
+            st.pop()
+        _finish(self.span)
+
     def __enter__(self):
         if self.span is not None:
-            _stack().append(self.span)
+            self._open()
         return self
 
     def __exit__(self, et, ev, tb):
         if self.span is None:
             return False
-        st = _stack()
-        if st and st[-1] is self.span:
-            st.pop()
         if et is not None:
             self.span["status"] = "error"
             self.span["attrs"]["error"] = f"{et.__name__}: {ev}"[:500]
-        _finish(self.span)
+        self._close()
         return False
+
+    def advance(self, name: str, **attrs) -> None:
+        """End this span now and go on as its sibling `name`, which the
+        enclosing ``with`` then closes: one stage handing over to the next
+        at a point inside a callee (a fit loop's last tree read)."""
+        if self.span is None:
+            return
+        done = self.span
+        self._close()
+        self.span = _new_span(name, done["trace_id"], done["parent_id"],
+                              attrs)
+        self.span["start_ms"] = done["end_ms"]
+        self._open()
 
 
 def root_span(name: str, **attrs) -> _SpanCtx:
@@ -201,6 +245,14 @@ def span(name: str, **attrs) -> _SpanCtx:
     return _SpanCtx(_new_span(name, cur["trace_id"], cur["span_id"], attrs))
 
 
+def advance(name: str, **attrs) -> None:
+    """``_SpanCtx.advance`` on the calling thread's innermost live span;
+    nothing when there is none (or only an adopted context)."""
+    st = getattr(_TLS, "stack", None)
+    if st and isinstance(st[-1], _SpanCtx):
+        st[-1].advance(name, **attrs)
+
+
 class activate:
     """Adopt a propagation context on THIS thread (the micro-batcher's
     flush leader runs on a different thread than the submitting request):
@@ -208,18 +260,18 @@ class activate:
 
     def __init__(self, ctx: Optional[Dict[str, str]]):
         self._ok = isinstance(ctx, dict) and bool(ctx.get("trace_id"))
-        self._frame = ({"trace_id": str(ctx["trace_id"]),
-                        "span_id": ctx.get("span_id")} if self._ok else None)
+        self.span = ({"trace_id": str(ctx["trace_id"]),
+                      "span_id": ctx.get("span_id")} if self._ok else None)
 
     def __enter__(self):
         if self._ok:
-            _stack().append(self._frame)
+            _stack().append(self)
         return self
 
     def __exit__(self, et, ev, tb):
         if self._ok:
             st = _stack()
-            if st and st[-1] is self._frame:
+            if st and st[-1] is self:
                 st.pop()
         return False
 
@@ -227,7 +279,7 @@ class activate:
 def record_span(name: str, ctx: Optional[Dict[str, str]], start_ms: float,
                 end_ms: Optional[float] = None, publish: bool = False,
                 status: str = "ok", **attrs) -> Optional[dict]:
-    """Append an already-timed span (explicit wall-clock ms timestamps)
+    """Append an already-timed span (explicit ``now_ms()`` timestamps)
     under `ctx`, returning it — the queue-wait span is recorded by the
     flush leader on behalf of each waiting request's trace, and the
     follower's replay/ack spans are recorded AFTER the ack (with
@@ -238,7 +290,7 @@ def record_span(name: str, ctx: Optional[Dict[str, str]], start_ms: float,
     sp["status"] = status
     sp["start_ms"] = round(float(start_ms), 3)
     sp["end_ms"] = round(float(end_ms if end_ms is not None
-                               else _now_ms()), 3)
+                               else now_ms()), 3)
     sp["ms"] = round(sp["end_ms"] - sp["start_ms"], 3)
     _store(sp)
     if publish:
@@ -298,7 +350,7 @@ def open_spans() -> List[dict]:
     """The calling thread's active (unfinished) spans — flight-recorder
     fodder. Cross-thread open spans are not visible by design (no global
     registry of live stacks; the store holds everything finished)."""
-    return [dict(s) for s in getattr(_TLS, "stack", [])]
+    return [dict(f.span) for f in getattr(_TLS, "stack", [])]
 
 
 def clear() -> None:

@@ -870,7 +870,7 @@ def follower_loop(idle_timeout_s: float = 120.0,
         # ingress) parents this replay — and the ack nests under the
         # replay — so /3/Trace/{id} shows publish -> replay -> ack
         tctx = op.get("trace")
-        t_replay0 = time.time() * 1000.0
+        t_replay0 = tracing.now_ms()
         try:
             failure.faultpoint("oplog.replay")
             _apply(op["kind"], op["payload"])
@@ -886,7 +886,7 @@ def follower_loop(idle_timeout_s: float = 120.0,
                                 publish=True, status="error",
                                 kind=op["kind"], seq=i)
             raise
-        t_ack0 = time.time() * 1000.0
+        t_ack0 = tracing.now_ms()
         _ack(i, op.get("op_id"))
         # span KV writes happen AFTER the ack landed: tracing must never
         # add latency to the coordinator's wait_acks path

@@ -678,6 +678,22 @@ class ScoringSession:
         f = buf if local else self._cl.put_rows(buf)
         return self.model._margin_to_raw(jnp.asarray(f))
 
+    def _assemble_result(self, frame, raw, n: int, dest, with_metrics: bool,
+                         path: str):
+        """(prediction frame installed under `dest`, metrics or None) of one
+        entry. Result assembly is where a request first blocks on the
+        device: whichever of the two spans first reads a host value waits
+        out the traversal — ``fetch`` builds and installs the frame,
+        ``metrics`` reads the metrics' host values. No sync is ADDED: these
+        calls block with or without tracing."""
+        with tracing.span("fetch", rows=n, path=path):
+            pred = self.model._raw_to_frame(raw, n, key=dest)
+            pred.install()
+        if not with_metrics:
+            return pred, None
+        with tracing.span("metrics", rows=n, path=path):
+            return pred, self.model._make_metrics(frame, raw)
+
     def predict_batch(self, entries: List[Tuple[Any, Optional[str], bool]],
                       local_only: bool = False):
         """Score a coalesced batch: entries = [(frame, dest_key,
@@ -755,7 +771,8 @@ class ScoringSession:
                         pipe_entries.append((i, frame, n, dest,
                                              with_metrics, cap))
                         continue
-            adapted = self.model.adapt_test(frame)
+            with tracing.span("adapt", rows=n):
+                adapted = self.model.adapt_test(frame)
             sf = None if local_mp else self._sharded_view(adapted)
             if sf is not None:
                 sharded_entries.append((i, frame, n, dest, with_metrics,
@@ -788,7 +805,8 @@ class ScoringSession:
                     mg, nd = pipeline.execute_margins(self, cap)
                 except Exception:   # noqa: BLE001 — abandon to staged
                     pipeline.note_fallback(cap)
-                    adapted = self.model.adapt_test(frame)
+                    with tracing.span("adapt", rows=n):
+                        adapted = self.model.adapt_test(frame)
                     sf = None if local_mp else self._sharded_view(adapted)
                     if sf is not None:
                         sharded_entries.append((i, frame, n, dest,
@@ -801,12 +819,8 @@ class ScoringSession:
                 sharded_frame.note_packed(n)
                 raw = self.model._margin_to_raw(
                     self._lift_entry_margins(mg, n, cap.padded))
-                with tracing.span("fetch", rows=n, path="pipeline"):
-                    pred = self.model._raw_to_frame(raw, n, key=dest)
-                    pred.install()
-                    mm = self.model._make_metrics(frame, raw) \
-                        if with_metrics else None
-                results[i] = (pred, mm)
+                results[i] = self._assemble_result(frame, raw, n, dest,
+                                                   with_metrics, "pipeline")
         if sharded_entries:
             from h2o3_tpu.core import sharded_frame
 
@@ -820,16 +834,8 @@ class ScoringSession:
                 sharded_frame.note_packed(n)
                 raw = self.model._margin_to_raw(
                     self._lift_entry_margins(mg, n, sf.padded_rows))
-                # result assembly is where this path first blocks on the
-                # device (frame install / metrics read host values) — the
-                # "fetch" phase of the request's span tree. No sync is
-                # ADDED: these calls block with or without tracing.
-                with tracing.span("fetch", rows=n, path="sharded"):
-                    pred = self.model._raw_to_frame(raw, n, key=dest)
-                    pred.install()
-                    mm = self.model._make_metrics(frame, raw) \
-                        if with_metrics else None
-                results[i] = (pred, mm)
+                results[i] = self._assemble_result(frame, raw, n, dest,
+                                                   with_metrics, "sharded")
         if host_entries:
             X = np.concatenate([self._features(a, n)
                                 for _, _, a, n, _, _ in host_entries])
@@ -845,11 +851,8 @@ class ScoringSession:
                 raw = self._raw_for_slice(margins[off: off + n], n,
                                           local=local_mp)
                 off += n
-                pred = self.model._raw_to_frame(raw, n, key=dest)
-                pred.install()
-                mm = self.model._make_metrics(frame, raw) if with_metrics \
-                    else None
-                results[i] = (pred, mm)
+                results[i] = self._assemble_result(frame, raw, n, dest,
+                                                   with_metrics, "host")
         total_rows = sum(frame.nrows for frame, _, _ in entries)
         ms = (time.perf_counter() - t0) * 1000
         self.stats.record_batch(len(entries), total_rows, ms,
@@ -942,7 +945,7 @@ class _Pending:
         # (a different thread) records each request's queue-wait span into
         # ITS trace, and adopts the lead context for the batch phases
         self.trace_ctx = tracing.context()
-        self.enq_ms = time.time() * 1000.0
+        self.enq_ms = tracing.now_ms()
 
 
 def execute_batch(model, entries: List[Tuple[Any, Optional[str], bool]],
@@ -1054,9 +1057,10 @@ class ScoreBatcher:
         from h2o3_tpu.parallel import oplog, retry, supervisor
 
         # queue-wait: submit -> flush start, one span per request in that
-        # request's OWN trace; the batch's shared phases (publish, pack,
-        # dispatch, fetch) then run under the lead (oldest) context
-        now_ms = time.time() * 1000.0
+        # request's OWN trace; the batch's shared phases (publish, then
+        # span ``flush`` over adapt, pack, dispatch, fetch, metrics) run
+        # under the lead (oldest) context
+        now_ms = tracing.now_ms()
         for e in batch:
             tracing.record_span("queue_wait", e.trace_ctx, e.enq_ms, now_ms,
                                 batched_with=len(batch) - 1)
@@ -1104,11 +1108,21 @@ class ScoreBatcher:
                         "coordinator-local scoring served while degraded: "
                         "follower DKV state is behind; restart the cloud "
                         "to re-sync", hold_s=float("inf"))
-                with oplog.turn(op_seq):
+                with tracing.span("flush", requests=len(batch)) as fl, \
+                        oplog.turn(op_seq):
                     results = execute_batch(
                         model, [(e.frame, e.dest, e.with_metrics)
                                 for e in batch],
                         local_only=local_only)
+            # the requests coalesced behind the lead waited out the same
+            # interval: each gets it in its OWN trace, naming the lead's
+            if fl:
+                for e in batch:
+                    if e.trace_ctx is not lead_ctx:
+                        tracing.record_span(
+                            "flush", e.trace_ctx, fl.span["start_ms"],
+                            fl.span["end_ms"], lead=lead_ctx["trace_id"],
+                            requests=len(batch))
             for e, (pred, mm) in zip(batch, results):
                 e.pred, e.mm = pred, mm
         except BaseException as ex:   # noqa: BLE001 — propagate per-request

@@ -1,21 +1,18 @@
-"""TimeLine event ring + task profiling + jax.profiler wiring.
+"""TimeLine event ring + jax.profiler wiring.
 
 Reference: water/TimeLine.java:22 — a per-node lock-free ring of wire events
-snapshotted over REST; water/MRTask.java:188-192,314-376 — opt-in `.profile()`
-phase timings (setup/map/reduce/remote-block) per distributed task.
+snapshotted over REST.
 
 TPU-native mapping: the interesting events are no longer UDP packets but XLA
-dispatches — per-task host-side phases (build/trace lookup, device run,
-blocking fetch) — plus HBM gauges and the XLA profiler's own trace files.
-The ring is process-wide and cheap enough to stay always-on; per-phase task
-timing is opt-in via H2O_TPU_PROFILE=1 (it forces a device sync per task,
-which the async dispatch pipeline must not pay by default)."""
+dispatches, plus HBM gauges and the XLA profiler's own trace files. The ring
+is process-wide and cheap enough to stay always-on. Where the time of one
+request or job goes is the span tree's business (obs/tracing.py), which
+needs no device sync and mirrors its spans into a profiler capture."""
 
 from __future__ import annotations
 
 import collections
 import contextlib
-import os
 import threading
 import time
 from typing import Dict, List, Optional
@@ -25,7 +22,7 @@ _LOCK = threading.Lock()
 
 # the closed enumeration of event kinds h2o3_tpu/ may record: free-form
 # kind drift makes the ring un-queryable (and un-documentable), so
-# tests/test_consistency.py pins every record()/task() call-site literal
+# tests/test_consistency.py pins every record() call-site literal
 # to this set (mirroring the faultpoint-name guard). "rest" is emitted by
 # the API layer's request ring merge, not by record().
 KINDS = frozenset({
@@ -41,8 +38,6 @@ KINDS = frozenset({
     "scoring",          # fused serving dispatches
     "search",           # durable AutoML/grid search-state saves + resumes
     "self_benchmark",   # mesh boot probes
-    "task_profile",     # opt-in per-task phase timings (H2O_TPU_PROFILE)
-    "tree",             # per-tree / per-level trainer timings
     "xla_trace",        # XLA profiler captures
 })
 
@@ -73,39 +68,6 @@ def clear() -> None:
         _RING.clear()
 
 
-def profiling_enabled() -> bool:
-    return bool(os.environ.get("H2O_TPU_PROFILE", ""))
-
-
-@contextlib.contextmanager
-def task(kind: str, what: str, **meta):
-    """Time a host-side phase into the ring (always-on; no device sync)."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        record(kind, what, ms=(time.perf_counter() - t0) * 1000, **meta)
-
-
-class TaskProfile:
-    """MRTask.profile() analog: per-phase wall times of one distributed task.
-    Collected only under H2O_TPU_PROFILE=1 (the fetch phase forces a device
-    sync)."""
-
-    __slots__ = ("what", "build_ms", "run_ms", "sync_ms")
-
-    def __init__(self, what: str):
-        self.what = what
-        self.build_ms = 0.0   # program lookup/trace (compile on cache miss)
-        self.run_ms = 0.0     # dispatch
-        self.sync_ms = 0.0    # block_until_ready
-
-    def emit(self):
-        record("task_profile", self.what, ms=self.build_ms + self.run_ms + self.sync_ms,
-               build_ms=round(self.build_ms, 3), run_ms=round(self.run_ms, 3),
-               sync_ms=round(self.sync_ms, 3))
-
-
 # -- XLA profiler wiring (reference: opt-in MRTask profiling; here the real
 #    hardware story is the XLA trace, viewable in xprof/tensorboard) ---------
 
@@ -122,13 +84,6 @@ def trace(log_dir: str):
     finally:
         compat.profiler_stop()
         record("xla_trace", log_dir, ms=(time.perf_counter() - t0) * 1000)
-
-
-def annotate(name: str):
-    """Named region inside a captured trace (TraceAnnotation)."""
-    from h2o3_tpu import compat
-
-    return compat.profiler_annotation(name)
 
 
 def device_memory() -> List[Dict]:

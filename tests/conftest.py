@@ -94,7 +94,7 @@ _HEAVY_MODULES = [
     # (test_sharded_frame/test_serving_qps train small GBMs, so they ride
     # the head of the heavy tail: the pure-host cheap modules still bank
     # their dots first)
-    "test_sharded_frame", "test_serving_qps",
+    "test_sharded_frame", "test_serving_qps", "test_trace_tree",
     "test_job_resume", "test_trees", "test_checkpoint", "test_genmodel",
     "test_artifact", "test_mojo",
     "test_mojo_families", "test_explain", "test_ensemble",

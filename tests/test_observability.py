@@ -1,7 +1,6 @@
-"""Observability: TimeLine ring, MRTask phase profiling, boot probes,
-profiler REST surfaces.
+"""Observability: TimeLine ring, boot probes, profiler REST surfaces.
 
-Reference: water/TimeLine.java:22, water/MRTask.java:188-192 (.profile),
+Reference: water/TimeLine.java:22,
 water/init/Linpack.java / MemoryBandwidth.java / NetworkBench.java,
 water/api/TimelineHandler + ProfilerHandler.
 """
@@ -20,31 +19,6 @@ class TestRing:
         timeline.record("test", "hello", ms=1.5, extra=7)
         evs = timeline.events()
         assert evs[-1]["kind"] == "test" and evs[-1]["extra"] == 7
-
-    def test_task_context(self):
-        timeline.clear()
-        with timeline.task("phase", "work"):
-            pass
-        ev = timeline.events()[-1]
-        assert ev["what"] == "work" and ev["ms"] >= 0
-
-
-class TestTaskProfiling:
-    def test_map_reduce_phases(self, cl, monkeypatch):
-        monkeypatch.setenv("H2O_TPU_PROFILE", "1")
-        timeline.clear()
-        import jax.numpy as jnp
-
-        from h2o3_tpu.core.frame import Column
-        from h2o3_tpu.core.mrtask import map_reduce
-
-        c = Column.from_numpy(np.arange(64, dtype=np.float64))
-        total = map_reduce(lambda x: jnp.nansum(x), [c])
-        assert float(total) == float(np.arange(64).sum())
-        profs = [e for e in timeline.events() if e["kind"] == "task_profile"]
-        assert profs, timeline.events()
-        p = profs[-1]
-        assert {"build_ms", "run_ms", "sync_ms"} <= set(p)
 
 
 class TestBootProbes:

@@ -359,7 +359,8 @@ class TestScoringMetricsRest:
 
         ISSUE-8 extension, same request: (a) the response's trace id
         resolves on GET /3/Trace/{id} to the COMPLETE fused-path span
-        tree — ingress -> queue_wait -> pack -> dispatch -> fetch — and
+        tree — ingress -> queue_wait, flush -> adapt, pack, dispatch,
+        fetch, metrics — and
         the unchanged gathered_rows / fused-compile counters are the
         proof that tracing added no device sync or path change; (b)
         GET /3/Metrics serves the cluster-aggregated
@@ -406,13 +407,14 @@ class TestScoringMetricsRest:
                                         timeout=30) as r:
                 tr = json.loads(r.read())
             names = {s["name"] for s in tr["spans"]}
-            assert {"ingress", "queue_wait", "pack", "dispatch",
-                    "fetch"} <= names, names
+            assert {"ingress", "queue_wait", "flush", "adapt", "pack",
+                    "dispatch", "fetch", "metrics"} <= names, names
             roots = tr["tree"]
             assert roots[0]["name"] == "ingress"
-            child_names = {c["name"] for c in roots[0]["children"]}
-            assert {"queue_wait", "pack", "dispatch",
-                    "fetch"} <= child_names
+            children = {c["name"]: c for c in roots[0]["children"]}
+            assert {"queue_wait", "flush"} <= set(children)
+            assert {"adapt", "pack", "dispatch", "fetch", "metrics"} <= {
+                c["name"] for c in children["flush"]["children"]}
             # -- cluster /3/Metrics agrees with the data_plane block
             with urllib.request.urlopen(base + "/3/Metrics",
                                         timeout=30) as r:
