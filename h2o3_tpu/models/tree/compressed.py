@@ -179,6 +179,23 @@ class CompressedForest:
             self.nclasses == 2
             and int(np.asarray(self.tree_class).max(initial=0)) > 0)
 
+    @functools.cached_property
+    def walk_form(self) -> str:
+        """Which form of the walk this forest's tables select (the label of
+        h2o3_forest_walk_total): the row's bin is always read by select;
+        `select` / `gather` says how a TPU reads the node tables (_at_node's
+        rule from M), `+cat` that some tree takes the categorical branch of
+        _walk_tree's cond."""
+        form = ("select" if self.feat.shape[1] <= _SELECT_MAX_NODES
+                else "gather")
+        return form + ("+cat" if (np.asarray(self.cat_split) >= 0).any()
+                       else "")
+
+    def count_walk(self) -> None:
+        from h2o3_tpu.obs import metrics
+
+        metrics.inc("h2o3_forest_walk_total", form=self.walk_form)
+
     def predict_binned(self, binned):
         """binned (N, F) integer bins (any width) → (N,) sums (regression/binomial margin) or
         (N, K) per-class margins (multinomial / double-trees binomial)."""
@@ -187,6 +204,7 @@ class CompressedForest:
         fn = _traverse_fn(self.max_depth, self.nclasses,
                           self.per_class_trees)
         out = fn(binned, *self.arrays())
+        self.count_walk()
         if self.init_class is not None:
             return out + jnp.asarray(self.init_class)[None, :]
         return out + self.init_f
@@ -194,7 +212,113 @@ class CompressedForest:
     def leaf_index(self, binned):
         """(N, T) leaf node id per tree (used by RuleFit/TreeSHAP/partial)."""
         fn = _leaf_fn(self.max_depth)
+        self.count_walk()
         return fn(binned, *self.arrays())
+
+
+# node tables up to this many entries are read by compare-and-select over
+# the table axis (one fused reduce a table a level, cost by the row and the
+# entry); wider ones (DRF at depth 20: 10^4-10^5 nodes a tree) by a gather
+# from the (M,) table (cost by the row, ~6 ns a row a table). Measured on a
+# v5e, 1M rows (PERF.md §6, PR 27): select ahead 8x at 127 and 255 entries,
+# 6.5x at 1,023, 1.9x at 3,997, the largest measured; the lines would cross
+# near 8,000
+_SELECT_MAX_NODES = 4096
+
+
+def _bin_at(binned, fi, na_bins):
+    """(binned[n, fi[n]], is it fi[n]'s NA bin) for every row n, with no
+    gather: compare-and-select over the feature axis, then a sum in which
+    one term is not zero. Exact on integers. A per-row gather has no
+    hardware on a TPU (19 ns a row); this reads the F columns of the matrix
+    once, rows on the lanes, and fuses into reduces that materialise no
+    (N, F) intermediate."""
+    import jax.numpy as jnp
+
+    hit = jnp.arange(binned.shape[1], dtype=jnp.int32)[None, :] == fi[:, None]
+    b = jnp.sum(jnp.where(hit, binned, 0), axis=1, dtype=jnp.int32)
+    return b, jnp.any(hit & (binned == na_bins[None, :]), axis=1)
+
+
+def _tables_by_gather(node, *tables):
+    return tuple(t[node] for t in tables)
+
+
+def _tables_by_select(node, *tables):
+    import jax.numpy as jnp
+
+    M = tables[0].shape[0]
+    hit = jnp.arange(M, dtype=jnp.int32)[None, :] == node[:, None]
+    return tuple(
+        jnp.any(hit & t[None, :], axis=1) if t.dtype == jnp.bool_
+        else jnp.sum(jnp.where(hit, t[None, :], 0), axis=1, dtype=t.dtype)
+        for t in tables)
+
+
+def _at_node(tables, node):
+    """t[node] for each (M,) node table t. On a TPU, by compare-and-select
+    over the table axis while it is narrow, by gather from the table beyond
+    that (the operand of that gather is the table: it never carries the
+    rows). XLA:CPU has a gather of its own and pays M times for the select
+    (6.6x slower at M = 63, and its parallel reduces starve the 8-device
+    test mesh's collectives under load), so there the tables are always
+    gathered: chosen when the program is lowered, so one compiled for a TPU
+    on a CPU host (AOT export) gets the TPU's form. Integers and bools
+    only, so either form returns the same bits."""
+    import jax
+
+    if tables[0].shape[0] > _SELECT_MAX_NODES:
+        return _tables_by_gather(node, *tables)
+    return jax.lax.platform_dependent(node, *tables, cpu=_tables_by_gather,
+                                      default=_tables_by_select)
+
+
+def _step(node, tree, binned, cat_table, na_bins, with_cat: bool):
+    """One level of the lockstep walk: every row moves from `node` to its
+    child (a leaf stays). The ONE step margins, leaf ids and everything
+    built on them share. No operand of a gather here carries the row axis.
+    `with_cat` is static: the categorical lookup is traced only into the
+    walk of a tree that has a categorical split."""
+    import jax.numpy as jnp
+
+    at = _at_node(tree if with_cat else tree[:5], node)
+    f, t, na_goes_left, lft, rgt = at[:5]
+    b, is_na = _bin_at(binned, jnp.maximum(f, 0), na_bins)
+    go_left = b <= t
+    if with_cat:
+        csid = at[5]
+        cat_left = cat_table[jnp.maximum(csid, 0),
+                             jnp.minimum(b, cat_table.shape[1] - 1)]
+        go_left = jnp.where(csid >= 0, cat_left, go_left)
+    go_left = jnp.where(is_na, na_goes_left, go_left)
+    return jnp.where(f < 0, node, jnp.where(go_left, lft, rgt))
+
+
+def _walk_tree(binned, tree, cat_table, na_bins, max_depth: int):
+    """(N,) node id each row ends in after max_depth + 1 steps of one tree
+    (tree = its feat, thresh, na_left, left, right, cat_split rows). Which
+    of the two step forms runs is read from the tree itself, on the device:
+    a tree with no categorical split (every tree of a numeric forest) never
+    executes the (C, maxB) lookup it would only discard."""
+    import jax
+    import jax.numpy as jnp
+
+    def walk(with_cat: bool):
+        def run(node):
+            return jax.lax.fori_loop(
+                0, max_depth + 1,
+                lambda _, n: _step(n, tree, binned, cat_table, na_bins,
+                                   with_cat), node)
+        return run
+
+    # the carry is derived from `binned` so it carries its type: under
+    # shard_map the rows vary over the mesh axis and a fresh jnp.zeros
+    # would not, which the loop carry check rejects; under plain jit this
+    # is the same zeros
+    node0 = jnp.zeros_like(binned[:, 0], dtype=jnp.int32)
+    *_, cat_split = tree
+    return jax.lax.cond(jnp.any(cat_split >= 0), walk(True), walk(False),
+                        node0)
 
 
 def _forest_margins(binned, feat, thresh, na_left, left, right, leaf_val,
@@ -209,25 +333,10 @@ def _forest_margins(binned, feat, thresh, na_left, left, right, leaf_val,
 
     N = binned.shape[0]
 
-    def walk_one_tree(carry, tree):
-        acc = carry
+    def walk_one_tree(acc, tree):
         tf, tt, tnl, tl, tr, tlv, tcs, tcls = tree
-
-        def step(_, node):
-            f = tf[node]
-            leaf = f < 0
-            fi = jnp.maximum(f, 0)
-            b = jnp.take_along_axis(binned, fi[:, None], axis=1)[:, 0]
-            is_na = b == na_bins[fi]
-            csid = tcs[node]
-            cat_left = cat_table[jnp.maximum(csid, 0),
-                                 jnp.minimum(b, cat_table.shape[1] - 1)]
-            go_left = jnp.where(csid >= 0, cat_left, b <= tt[node])
-            go_left = jnp.where(is_na, tnl[node], go_left)
-            nxt = jnp.where(go_left, tl[node], tr[node])
-            return jnp.where(leaf, node, nxt)
-
-        node = jax.lax.fori_loop(0, max_depth + 1, step, node0)
+        node = _walk_tree(binned, (tf, tt, tnl, tl, tr, tcs), cat_table,
+                          na_bins, max_depth)
         contrib = tlv[node]
         if K > 1:
             acc = acc.at[:, tcls].add(contrib)
@@ -235,12 +344,8 @@ def _forest_margins(binned, feat, thresh, na_left, left, right, leaf_val,
             acc = acc + contrib
         return acc, None
 
-    # loop carries are derived from `binned` so they carry its type: under
-    # shard_map the rows vary over the mesh axis and a fresh jnp.zeros
-    # would not, which the scan carry check rejects; under plain jit this
-    # is the same zeros
-    node0 = jnp.zeros_like(binned[:, 0], dtype=jnp.int32)
-    acc0 = node0.astype(jnp.float32)
+    # typed like the rows it walks (see _walk_tree)
+    acc0 = jnp.zeros_like(binned[:, 0], dtype=jnp.float32)
     if K > 1:
         acc0 = jnp.broadcast_to(acc0[:, None], (N, K))
     with jax.named_scope("walk"):      # metadata: device time by scope
@@ -290,34 +395,15 @@ def _bin_features(X, edges, is_cat, na_bins):
 def _forest_leaves(binned, feat, thresh, na_left, left, right, cat_split,
                    cat_table, na_bins, max_depth: int):
     """Traceable leaf-walk core: (N, F) integer bins → (N, T) leaf node
-    ids. The SAME step ops as _forest_margins' walk (so the leaf a row
-    lands in is by construction the leaf whose value the margin summed) —
+    ids. The walk is _forest_margins' own (_walk_tree), so the leaf a row
+    lands in is by construction the leaf whose value the margin summed —
     shared by the per-request _leaf_fn and the fused leaf programs."""
     import jax
     import jax.numpy as jnp
 
     def walk(carry, tree):
-        tf, tt, tnl, tl, tr, tcs = tree
+        return carry, _walk_tree(binned, tree, cat_table, na_bins, max_depth)
 
-        def step(_, node):
-            f = tf[node]
-            leaf = f < 0
-            fi = jnp.maximum(f, 0)
-            b = jnp.take_along_axis(binned, fi[:, None], axis=1)[:, 0]
-            is_na = b == na_bins[fi]
-            csid = tcs[node]
-            cat_left = cat_table[jnp.maximum(csid, 0),
-                                 jnp.minimum(b, cat_table.shape[1] - 1)]
-            go_left = jnp.where(csid >= 0, cat_left, b <= tt[node])
-            go_left = jnp.where(is_na, tnl[node], go_left)
-            return jnp.where(leaf, node,
-                             jnp.where(go_left, tl[node], tr[node]))
-
-        node = jax.lax.fori_loop(0, max_depth + 1, step, node0)
-        return carry, node
-
-    # typed like the rows it walks (see _forest_margins)
-    node0 = jnp.zeros_like(binned[:, 0], dtype=jnp.int32)
     _, leaves = jax.lax.scan(
         walk, None, (feat, thresh, na_left, left, right, cat_split))
     return jnp.transpose(leaves)       # (N, T)

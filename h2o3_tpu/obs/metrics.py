@@ -459,6 +459,11 @@ def _install_default_metrics() -> None:
               "cloud health state transitions, by target state")
     r.counter("h2o3_tree_trees_built_total",
               "trees built across all forest trainers")
+    r.counter("h2o3_forest_walk_total",
+              "dispatches of a forest-walk program (predict_binned, "
+              "leaf_index, the scoring session's fused programs), by form: "
+              "select | gather = how a TPU reads the node tables, +cat = some tree "
+              "takes the categorical branch")
     r.counter("h2o3_backend_compiles_total",
               "XLA backend compiles seen by jax.monitoring: ledgered call "
               "sites, bare jits and eager ops alike")

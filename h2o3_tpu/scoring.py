@@ -231,6 +231,12 @@ class ScoringSession:
 
         return ShardedFrame.of(adapted, self.spec.names)
 
+    def _note_dispatch(self, path: str) -> None:
+        """One dispatch of a fused program: its path, and the form of the
+        forest walk it ran (host-side counts, no sync)."""
+        note_dispatch(path)
+        self.forest.count_walk()
+
     def _bucket_for(self, m: int) -> int:
         for b in self.buckets:
             if b >= m:
@@ -414,7 +420,7 @@ class ScoringSession:
             with tracing.span("dispatch", bucket=bucket, rows=m,
                               path="host"):
                 out = exe(*call_args)
-            note_dispatch("local" if local else "host")
+            self._note_dispatch("local" if local else "host")
             if dispatched is not None:
                 dispatched.append(bucket)
             return out
@@ -499,7 +505,7 @@ class ScoringSession:
                               path="sharded"):
                 out = exe(*call_args)
             n_disp += 1
-            note_dispatch("sharded")
+            self._note_dispatch("sharded")
             return out
 
         outs: List[Any] = []
@@ -616,7 +622,7 @@ class ScoringSession:
                 with tracing.span("dispatch", bucket=bucket, rows=m,
                                   path="leaf_sharded"):
                     out = exe(*call_args)
-                note_dispatch("leaf_sharded")
+                self._note_dispatch("leaf_sharded")
                 return out[:m]
 
             outs = stream.run_windows(
@@ -640,7 +646,7 @@ class ScoringSession:
                 with tracing.span("dispatch", bucket=bucket, rows=m,
                                   path="leaf_host"):
                     out = exe(*call_args)
-                note_dispatch("leaf_host")
+                self._note_dispatch("leaf_host")
                 return out[:m]
 
             outs = stream.run_windows(
