@@ -161,7 +161,7 @@ class BinSpec:
             else:
                 x = c.data
                 e = jnp.asarray(self.edges[i])
-                b = jnp.searchsorted(e, x, side="left").astype(jnp.int32)
+                b = _sfmod.edges_below(e, x)
                 b = jnp.where(jnp.isnan(x), na_bin, b)
             parts.append(b.astype(dtype))
         binned = jnp.stack(parts, axis=-1)          # (N, F)

@@ -227,6 +227,7 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
     Smax = max(widths)
     K = pack_width(maxB)
     TB = F * maxB
+    lanes = sum(nbins)          # the bins that exist
 
     def hist_gather_pl(binned, row_node, live, w, y, S):
         """(S, F, maxB, 3) via the fused Pallas gather→accumulate kernel
@@ -248,7 +249,12 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
 
     def hist_matmul(binned, row_node, live, w, y, S):
         """(S, F, maxB, 3) via blocked bf16 one-hot matmul + psum — the
-        MXU lowering; O(N·F·maxB·S·3) FLOPs, almost all on zeros."""
+        MXU lowering; O(N·lanes·S·3) FLOPs, almost all on zeros. The
+        one-hot carries the bins that exist, nbins[f] lanes a feature
+        (BinSpec.offsets' layout), not maxB: with a 300-level enum beside a
+        7-level one two thirds of F·maxB lanes would be bins no row can
+        fall in. The sums are laid out to (F, maxB) afterwards, zeros in
+        the lanes a feature does not have."""
         def body(i, acc):
             sl = lambda a: jax.lax.dynamic_slice_in_dim(a, i * blk, blk, 0)
             bb = sl(binned)
@@ -257,18 +263,22 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
             wb = jnp.where(liveb, sl(w), 0.0)
             yb = sl(y)
             Ob = jnp.concatenate(
-                [jax.nn.one_hot(bb[:, f], maxB, dtype=jnp.bfloat16)
-                 for f in range(F)], axis=1)                     # (blk, F*maxB)
+                [jax.nn.one_hot(bb[:, f], nbins[f], dtype=jnp.bfloat16)
+                 for f in range(F)], axis=1)                     # (blk, lanes)
             node_oh = jax.nn.one_hot(nodeb, S, dtype=jnp.float32)
             vals = jnp.stack([wb, wb * yb, wb * yb * yb], axis=-1)
             V = (node_oh[:, :, None] * vals[:, None, :]).reshape(blk, S * 3)
             return acc + jnp.dot(Ob.T, V.astype(jnp.bfloat16),
                                  preferred_element_type=jnp.float32)
 
-        acc0 = _compat_pcast(jnp.zeros((F * maxB, S * 3), jnp.float32),
+        acc0 = _compat_pcast(jnp.zeros((lanes, S * 3), jnp.float32),
                              ("rows",), to="varying")
         acc = jax.lax.fori_loop(0, nblk, body, acc0)
         acc = jax.lax.psum(acc, "rows")
+        if lanes != TB:
+            acc = jnp.concatenate(
+                [jnp.pad(acc[o:o + nb], ((0, maxB - nb), (0, 0)))
+                 for o, nb in zip(np.cumsum((0,) + nbins[:-1]), nbins)])
         return acc.reshape(F, maxB, S, 3).transpose(2, 0, 1, 3)
 
     def hist_scatter(binned, row_node, live, w, y, S):
@@ -430,9 +440,10 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
         "tree", fn, program=f"tree_grow_d{max_depth}_{lowering}")
 
 
-def _pick_blk(n_shard: int, F: int, maxB: int) -> int:
-    """Row-block size: keep the per-block one-hot under ~64 MB."""
-    budget = 64 * 1024 * 1024 // (2 * F * maxB)
+def _pick_blk(n_shard: int, lanes: int) -> int:
+    """Row-block size: keep the per-block (blk, lanes) bf16 one-hot under
+    ~64 MB."""
+    budget = 64 * 1024 * 1024 // (2 * lanes)
     blk = 1 << max(int(np.floor(np.log2(max(budget, 1)))), 10)
     return int(min(blk, max(n_shard, 1)))
 
@@ -468,7 +479,7 @@ def grow_tree_device(binned, w, y, spec, *, max_depth: int, min_rows: float,
     N, F = binned.shape
     n_shard = N // _mesh_size(mesh)
     maxB = int(spec.nbins.max())
-    blk = _pick_blk(n_shard, F, maxB)
+    blk = _pick_blk(n_shard, int(spec.nbins.sum()))
     has_masks = feat_masks is not None
     from h2o3_tpu.models.tree import pallas_hist
 
@@ -576,9 +587,29 @@ def assemble_trees(packs, leaf_vals, leaf_wys, spec, max_depth: int,
         packs_np = np.asarray(jnp.stack(packs))
     vals_np = np.asarray(jnp.stack(leaf_vals), np.float64) * scale
     wys_np = np.asarray(jnp.stack(leaf_wys), np.float64)
+    _count_splits(packs_np, spec, max_depth)
     return [host_tree_from_packed(packs_np[i], wys_np[i], spec, max_depth,
                                   leaf_values=vals_np[i])
             for i in range(len(packs))]
+
+
+def _count_splits(packs_np: np.ndarray, spec, max_depth: int) -> None:
+    """h2o3_tree_splits_total{kind} and the `assemble` span's `nodes` /
+    `enum_splits`, from the fetched tables: a slot inside its level's width
+    whose split feature is >= 0 is a split (slots beyond the width are the
+    table's zero fill), and its kind is its feature's."""
+    from h2o3_tpu.obs import metrics, tracing
+
+    widths = level_widths(max_depth,
+                          frontier_cap(spec.F, int(spec.nbins.max())))
+    feats = np.concatenate(
+        [packs_np[:, d, :S, 0].reshape(-1) for d, S in enumerate(widths)])
+    feats = feats[feats >= 0].astype(np.int64)
+    enum = int(np.count_nonzero(np.asarray(spec.is_cat)[feats]))
+    metrics.inc("h2o3_tree_splits_total", enum, kind="enum")
+    metrics.inc("h2o3_tree_splits_total", len(feats) - enum, kind="numeric")
+    tracing.set_attrs(nodes=2 * len(feats) + int(packs_np.shape[0]),
+                      enum_splits=enum)
 
 
 # ---------------------------------------------------------------------------

@@ -395,11 +395,13 @@ class SharedTree(ModelBuilder):
         # the bin matrix is only dispatched and drains into ``trees``
         nbins, nbins_cats = (int(self.params["nbins"]),
                              int(self.params["nbins_cats"]))
-        with tracing.span("bin", rows=train.nrows):
+        with tracing.span("bin", rows=train.nrows) as bin_span:
             spec = prev.spec if prev is not None else BinSpec.build(
                 train, out.names, nbins=nbins, nbins_cats=nbins_cats,
                 seed=self._seed())
             binned = spec.bin_columns(train)
+            bin_span.set(bin_dtype=str(binned.dtype),
+                         max_bins=int(spec.nbins.max()))
         self._ckpt = prev
         model.spec = spec
         N = binned.shape[0]

@@ -459,6 +459,10 @@ def _install_default_metrics() -> None:
               "cloud health state transitions, by target state")
     r.counter("h2o3_tree_trees_built_total",
               "trees built across all forest trainers")
+    r.counter("h2o3_tree_splits_total",
+              "splits of the trees a fit loop assembled, counted from the "
+              "tables its one batched fetch brought, by kind: enum (a subset "
+              "of levels) | numeric (a threshold)")
     r.counter("h2o3_forest_walk_total",
               "dispatches of a forest-walk program (predict_binned, "
               "leaf_index, the scoring session's fused programs), by form: "

@@ -253,6 +253,14 @@ def advance(name: str, **attrs) -> None:
         st[-1].advance(name, **attrs)
 
 
+def set_attrs(**attrs) -> None:
+    """``_SpanCtx.set`` on the calling thread's innermost live span (one
+    that a callee opened or advanced to); nothing when there is none."""
+    st = getattr(_TLS, "stack", None)
+    if st and isinstance(st[-1], _SpanCtx):
+        st[-1].set(**attrs)
+
+
 class activate:
     """Adopt a propagation context on THIS thread (the micro-batcher's
     flush leader runs on a different thread than the submitting request):
