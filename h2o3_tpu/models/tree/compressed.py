@@ -186,10 +186,8 @@ class CompressedForest:
         `select` / `gather` says how a TPU reads the node tables (_at_node's
         rule from M), `+cat` that some tree takes the categorical branch of
         _walk_tree's cond."""
-        form = ("select" if self.feat.shape[1] <= _SELECT_MAX_NODES
-                else "gather")
-        return form + ("+cat" if (np.asarray(self.cat_split) >= 0).any()
-                       else "")
+        return table_form(self.feat.shape[1]) + (
+            "+cat" if (np.asarray(self.cat_split) >= 0).any() else "")
 
     def count_walk(self) -> None:
         from h2o3_tpu.obs import metrics
@@ -226,17 +224,26 @@ class CompressedForest:
 _SELECT_MAX_NODES = 4096
 
 
-def _bin_at(binned, fi, na_bins):
+def table_form(entries: int) -> str:
+    """How a TPU reads a table of `entries` at a per-row index (_at_node's
+    one rule, and the label the walk's and the route's counters carry)."""
+    return "select" if entries <= _SELECT_MAX_NODES else "gather"
+
+
+def _bin_at(binned, fi, na_bins=None):
     """(binned[n, fi[n]], is it fi[n]'s NA bin) for every row n, with no
     gather: compare-and-select over the feature axis, then a sum in which
     one term is not zero. Exact on integers. A per-row gather has no
     hardware on a TPU (19 ns a row); this reads the F columns of the matrix
     once, rows on the lanes, and fuses into reduces that materialise no
-    (N, F) intermediate."""
+    (N, F) intermediate. Without `na_bins` (the tree program's routing,
+    whose tables hold the NA bin's side) the flag is None."""
     import jax.numpy as jnp
 
     hit = jnp.arange(binned.shape[1], dtype=jnp.int32)[None, :] == fi[:, None]
     b = jnp.sum(jnp.where(hit, binned, 0), axis=1, dtype=jnp.int32)
+    if na_bins is None:
+        return b, None
     return b, jnp.any(hit & (binned == na_bins[None, :]), axis=1)
 
 
@@ -267,7 +274,7 @@ def _at_node(tables, node):
     only, so either form returns the same bits."""
     import jax
 
-    if tables[0].shape[0] > _SELECT_MAX_NODES:
+    if table_form(tables[0].shape[0]) == "gather":
         return _tables_by_gather(node, *tables)
     return jax.lax.platform_dependent(node, *tables, cpu=_tables_by_gather,
                                       default=_tables_by_select)

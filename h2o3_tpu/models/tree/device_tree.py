@@ -33,6 +33,18 @@ TPU-native design (round 3 + the round-4 deep-tree unification):
   a blocked scatter-add: O(N·F) work per level — the matmul's O(N·F·B·S)
   FLOPs stop being free once the node one-hot is thousands wide. Shallow
   levels (where the flagship bench lives) keep the matmul path untouched.
+- Routing (`_route`, the one step tree_program and apply_packed share)
+  gathers from no operand that carries the rows: a per-row gather has no
+  hardware on a TPU (19 ns a row a level, 70% of a depth-5 job when the
+  level read `binned` and `left_table` that way). A level reads the row's
+  bin at its slot's split feature by compare-and-select over the feature
+  axis (compressed._bin_at), the slot's split_feat / left_slot / right_slot
+  by compressed._at_node (select over the slot axis up to
+  _SELECT_MAX_NODES entries, a gather from the (S,) table beyond), and
+  left_table[slot, bin] as one bit of one uint32 word: the (S, maxB) bool
+  table is packed into ceil(maxB / 32) words a slot and the word read
+  through _at_node too, so thresholds and enum subsets take one form. The
+  last level reads nothing: every slot there is terminal.
 - The GammaPass inputs (num, den) are computed BEFORE the tree from
   (w, y, z, f) and segment-summed per leaf inside the same program, so leaf
   Newton steps need no extra dispatch.
@@ -201,6 +213,64 @@ def _search_level(hist, *, nbins, is_cat, maxB, min_rows, min_split_improvement,
     return (split_feat, t_star, na_left,
             jnp.where(valid, bg, 0.0).astype(jnp.float32),
             left_table, tot0)
+
+
+# ---------------------------------------------------------------------------
+# routing: one level of one tree, shared by tree_program and apply_packed
+# ---------------------------------------------------------------------------
+
+def _route(binned, row_node, row_leaf, gid0: int, split):
+    """Move every live row (row_leaf < 0) one level down: a row whose slot
+    is terminal gets its global leaf id gid0 + slot, the others the child
+    slot `left_table[slot, bin at the slot's split feature]` sends them to.
+    `split` = this level's (split_feat, left_slot, right_slot) (S,) int32
+    and left_table (S, maxB) bool; None at the last level, where every slot
+    is terminal and nothing is read. -> (row_node, row_leaf).
+
+    No operand of a gather here carries the row axis (module docstring):
+    the bin by compressed._bin_at, the slot's scalars by
+    compressed._at_node, and left_table[slot, b] as bit b & 31 of the
+    uint32 word at slot * W + (b >> 5) of the table packed W words a slot
+    (on S x maxB elements, once a level), read through _at_node too. One
+    form for thresholds and subsets of levels: left_table keeps its
+    meaning."""
+    import jax.numpy as jnp
+
+    from h2o3_tpu.models.tree.compressed import _at_node, _bin_at
+
+    live = row_leaf < 0
+    if split is None:
+        return row_node, jnp.where(live, gid0 + row_node, row_leaf)
+    split_feat, left_slot, right_slot, left_table = split
+    S, maxB = left_table.shape
+    W = route_words(maxB)
+    bits = jnp.pad(left_table, ((0, 0), (0, W * 32 - maxB))).astype(jnp.uint32)
+    words = jnp.sum(bits.reshape(S * W, 32) << jnp.arange(32, dtype=jnp.uint32),
+                    axis=1, dtype=jnp.uint32)
+    f, lft, rgt = _at_node((split_feat, left_slot, right_slot), row_node)
+    terminal = f < 0
+    row_leaf = jnp.where(live & terminal, gid0 + row_node, row_leaf)
+    b = jnp.minimum(_bin_at(binned, jnp.maximum(f, 0))[0], maxB - 1)
+    word, = _at_node((words,), row_node * W + (b >> 5))
+    go_left = (word >> (b & 31).astype(jnp.uint32)) & 1 == 1
+    return (jnp.where(live & ~terminal, jnp.where(go_left, lft, rgt), 0),
+            row_leaf)
+
+
+def route_words(maxB: int) -> int:
+    """uint32 words a slot's row of left_table packs into."""
+    return -(-maxB // 32)
+
+
+def route_forms(max_depth: int, F: int, maxB: int) -> Tuple[str, ...]:
+    """How each routing level of a tree reads its widest table, the packed
+    words (`select` | `gather`: _at_node's rule, from S x W); the last
+    level reads none and is not listed."""
+    from h2o3_tpu.models.tree.compressed import table_form
+
+    widths = level_widths(max_depth, frontier_cap(F, maxB))
+    return tuple(table_form(S * route_words(maxB))
+                 for S in widths[:max_depth])
 
 
 # ---------------------------------------------------------------------------
@@ -409,16 +479,10 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
             packed = packed.at[d, :S, :].set(row)
 
             with jax.named_scope(f"level{d}/route"):
-                node = row_node
-                terminal = split_feat[node] < 0
-                gid = offs[d] + node
-                row_leaf = jnp.where(live & terminal, gid, row_leaf)
-                f_sel = jnp.maximum(split_feat[node], 0)
-                b = jnp.take_along_axis(binned, f_sel[:, None], axis=1)[:, 0]
-                gl = left_table[node, jnp.minimum(b, maxB - 1)]
-                row_node = jnp.where(
-                    live & ~terminal,
-                    jnp.where(gl, left_slot[node], right_slot[node]), 0)
+                row_node, row_leaf = _route(
+                    binned, row_node, row_leaf, offs[d],
+                    None if d == max_depth else
+                    (split_feat, left_slot, right_slot, left_table))
 
         with jax.named_scope("leaf_sums"):
             cols = jnp.stack([w, w * y, num, den], axis=-1)
@@ -438,6 +502,19 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
                        check_vma=check_vma)
     return compiles.ledgered_jit(
         "tree", fn, program=f"tree_grow_d{max_depth}_{lowering}")
+
+
+def _count_route(forms: Tuple[str, ...]) -> None:
+    """h2o3_tree_route_levels_total{form} and the `trees` span's
+    `route_levels` / `route_gather_levels`, from the static level widths of
+    the tree being dispatched: host arithmetic, no device op."""
+    from h2o3_tpu.obs import metrics, tracing
+
+    gathered = forms.count("gather")
+    metrics.inc("h2o3_tree_route_levels_total", len(forms) - gathered,
+                form="select")
+    metrics.inc("h2o3_tree_route_levels_total", gathered, form="gather")
+    tracing.add_attrs(route_levels=len(forms), route_gather_levels=gathered)
 
 
 def _pick_blk(n_shard: int, lanes: int) -> int:
@@ -502,6 +579,7 @@ def grow_tree_device(binned, w, y, spec, *, max_depth: int, min_rows: float,
                   tuple(bool(c) for c in spec.is_cat), float(min_rows),
                   float(min_split_improvement), has_masks, mesh, n_shard, blk,
                   cap_v, lowering=lowering)
+    _count_route(route_forms(int(max_depth), F, maxB))
     w = w.astype(jnp.float32)
     y = y.astype(jnp.float32)
     if num is None:
@@ -536,20 +614,12 @@ def _apply_fn(max_depth: int, maxB: int, mesh, cap: int):
         row_node = jnp.zeros(n, jnp.int32)
         row_leaf = jnp.full(n, -1, jnp.int32)
         for d in range(max_depth + 1):
-            S = widths[d]
-            split_feat = packed[d, :S, 0].astype(jnp.int32)
-            left_table = packed[d, :S, 4:4 + maxB] > 0.5
-            ls = packed[d, :S, K - 2].astype(jnp.int32)
-            rs = packed[d, :S, K - 1].astype(jnp.int32)
-            live = row_leaf < 0
-            node = row_node
-            terminal = split_feat[node] < 0
-            row_leaf = jnp.where(live & terminal, offs[d] + node, row_leaf)
-            f_sel = jnp.maximum(split_feat[node], 0)
-            b = jnp.take_along_axis(binned, f_sel[:, None], axis=1)[:, 0]
-            gl = left_table[node, jnp.minimum(b, maxB - 1)]
-            row_node = jnp.where(live & ~terminal,
-                                 jnp.where(gl, ls[node], rs[node]), 0)
+            lv = packed[d, :widths[d]]
+            row_node, row_leaf = _route(
+                binned, row_node, row_leaf, offs[d],
+                None if d == max_depth else
+                (lv[:, 0].astype(jnp.int32), lv[:, K - 2].astype(jnp.int32),
+                 lv[:, K - 1].astype(jnp.int32), lv[:, 4:4 + maxB] > 0.5))
         return values[jnp.maximum(row_leaf, 0)]
 
     fn = _compat_shard_map(apply, mesh=mesh,

@@ -463,6 +463,11 @@ def _install_default_metrics() -> None:
               "splits of the trees a fit loop assembled, counted from the "
               "tables its one batched fetch brought, by kind: enum (a subset "
               "of levels) | numeric (a threshold)")
+    r.counter("h2o3_tree_route_levels_total",
+              "routing levels of the trees dispatched to the tree program "
+              "(max_depth a tree: the last level reads no table), by form: "
+              "select | gather = how a TPU reads the level's packed "
+              "left_table words; the row's bin is always by select")
     r.counter("h2o3_forest_walk_total",
               "dispatches of a forest-walk program (predict_binned, "
               "leaf_index, the scoring session's fused programs), by form: "

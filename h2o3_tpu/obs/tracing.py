@@ -261,6 +261,17 @@ def set_attrs(**attrs) -> None:
         st[-1].set(**attrs)
 
 
+def add_attrs(**counts) -> None:
+    """Add each count to the attribute of that name (0 where there is none
+    yet) on the calling thread's innermost live span: a sum over what a
+    stage dispatched, kept on the host."""
+    st = getattr(_TLS, "stack", None)
+    if st and isinstance(st[-1], _SpanCtx) and st[-1].span is not None:
+        attrs = st[-1].span["attrs"]
+        for k, n in counts.items():
+            attrs[k] = attrs.get(k, 0) + n
+
+
 class activate:
     """Adopt a propagation context on THIS thread (the micro-batcher's
     flush leader runs on a different thread than the submitting request):

@@ -403,3 +403,69 @@ def test_training_metrics_walk_compiles_for_the_chip_without_a_row_gather(
     assert gathered                      # cat_table's, in the cat branch
     assert not [shapes[op] for op in gathered
                 if shapes[op].split(",")[0] == str(rows)]
+
+
+@pytest.mark.parametrize("config", ["higgs_gbm_d5", "airline_gbm_d10"])
+def test_tree_program_lowers_for_the_chip_without_a_row_gather(config,
+                                                               one_chip):
+    """The twin of the test above for training (ISSUE 29): `jit_tree_program`
+    lowered for a v5e at both configurations' real shapes holds no gather
+    whose operand carries the 16M rows (what is gathered: histogram and
+    search tables, and level 9's 5,120 packed words in `airline_gbm_d10`);
+    and the route step alone, compiled for the chip at the widest level of
+    either form, keeps its scratch to a few (N,) vectors: no (N, S),
+    (N, S x W) or (N, F) intermediate, and again no row gather."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from h2o3_tpu.models.tree import device_tree
+
+    rows = 16_000_000
+    depth, nbins, is_cat, dtype, levels = {
+        "higgs_gbm_d5": (5, (21,) * 28, (False,) * 28, jnp.uint8, (16,)),
+        "airline_gbm_d10": (10, (13, 32, 8, 23, 301, 301, 101, 101),
+                            (True,) * 6 + (False,) * 2, jnp.int16,
+                            (256, 512)),
+    }[config]
+    F, maxB = len(nbins), max(nbins)
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("rows",))
+
+    def sharded(shape, dt, *axes):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, P(*axes)))
+
+    grow = device_tree._grow_fn(
+        depth, F, maxB, nbins, is_cat, 10.0, 1e-5, False, mesh, rows,
+        device_tree._pick_blk(rows, sum(nbins)),
+        device_tree.frontier_cap(F, maxB))
+    f32 = sharded((rows,), jnp.float32, "rows")
+    text = grow.lower(sharded((rows, F), dtype, "rows", None), f32, f32, f32,
+                      f32, np.zeros(0, np.float32)).as_text()
+    assert "module @jit_tree_program" in text
+    gathered = re.findall(r"stablehlo\.gather.*?:\s*\(tensor<([^>]*)>", text)
+    # the program pads the rows to whole histogram blocks: nothing gathered
+    # from has a leading dimension anywhere near them
+    assert gathered and max(int(t.split("x")[0]) for t in gathered) < 10 ** 5
+    assert (f"{512 * 10}xui32" in gathered) == (config == "airline_gbm_d10")
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    i32 = jnp.int32
+    for S in levels:
+        compiled = jax.jit(lambda b, n, l, *split: device_tree._route(
+            b, n, l, 3, split)).lower(
+                arg((rows, F), dtype), arg((rows,), i32), arg((rows,), i32),
+                arg((S,), i32), arg((S,), i32), arg((S,), i32),
+                arg((S, maxB), jnp.bool_)).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 16 * rows
+        hlo = compiled.as_text()
+        shapes = dict(re.findall(
+            r"^\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]", hlo, re.M))
+        assert not [shapes[op] for op in re.findall(
+            r" gather\((%[\w.\-]+),", hlo)
+            if shapes[op].split(",")[0] == str(rows)]
