@@ -16,8 +16,7 @@ reachable function:
   flagged on sight: divergent entropy shapes data and shapes, not just
   branches — the unpinned-wildcard-seed class;
 - **raw env reads** (``os.environ`` / ``os.getenv``) outside the declared
-  knob helpers, when they feed control flow — the
-  ``H2O_TPU_PALLAS_HIST=auto`` class;
+  knob helpers, when they feed control flow;
 - **process-local topology** (``jax.process_index()``,
   ``local_device_count()``, ``local_devices()``) feeding control flow.
 

@@ -67,10 +67,6 @@ KNOB_HELPERS = frozenset({
     "h2o3_tpu.ingest.chunked.chunk_bytes",         # H2O_TPU_INGEST_CHUNK_BYTES
     "h2o3_tpu.ingest.chunked.ingest_workers",      # H2O_TPU_INGEST_WORKERS
     "h2o3_tpu.ingest.chunked.parquet_batch",       # lazy-parquet batch width
-    "h2o3_tpu.models.tree.pallas_hist.hist_budget_bytes",
-    # — H2O_TPU_HIST_VMEM_MB: the frontier-tile budget is a pure function
-    # of (env, geometry); the ops contract pins the env uniform, so every
-    # process plans the same tiling and lowers the same program
     "h2o3_tpu.automl.search.search_concurrency",
     # — H2O_TPU_SEARCH_CONCURRENCY: deterministically 1 when oplog is
     # active (every process walks the identical member sequence); the
@@ -101,15 +97,6 @@ GUARDED = {
         "the ONE seed-derivation policy: REST pins wildcard seeds before "
         "any broadcast (_pin_seed_and_wire), so this fresh entropy only "
         "runs library-mode (single process)",
-    "h2o3_tpu.models.tree.pallas_hist.decide_lowering":
-        "H2O_TPU_PALLAS_HIST read is env-contract-pinned; the auto-mode "
-        "branch is wall-clock but multi-process clouds deterministically "
-        "keep the matmul lowering (PR-7 hardening) — the timing path is "
-        "single-process only",
-    "h2o3_tpu.models.tree.pallas_hist.auto_decide":
-        "three-way microbenchmark: wall-clock timing + cache-dir verdict "
-        "reads, reachable only through decide_lowering's single-process "
-        "auto branch (multi-process clouds never call it)",
     "h2o3_tpu.core.dkv.Key.make":
         "random key suffixes are process-local DKV names; cross-process "
         "keys always ride op payloads, never shape device programs",
@@ -164,8 +151,6 @@ HOST_SIDE_MODULES = {
     "h2o3_tpu/core/job.py": "job lifecycle metadata (timestamps/status); "
                             "the device work lives in the builders",
     "h2o3_tpu/persist/": "storage backends (host I/O)",
-    "h2o3_tpu/bench.py": "bench harness is operator-invoked, not "
-                         "oplog-mirrored",
 }
 
 # ---------------------------------------------------------------------------

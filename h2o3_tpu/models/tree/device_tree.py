@@ -3,14 +3,13 @@ statistics in ONE compiled program per tree, at ANY depth.
 
 Reference: hex/tree/ScoreBuildHistogram2.java:60 (per-row histogram build,
 CAS adds into DHistogram._vals, DHistogram.java:62-90) + DTree.decideBestSplit
-+ GBM.java:416 GammaPass. The round-2 implementation kept the reference's
-host/device split: a device scatter-add per level, then host numpy split
-search, then a device routing pass — 2 dispatches + a blocking transfer per
-level. Profiled on a v5e chip, the scatter-add alone was 57% of training
-time (scatter serializes on TPU), and every per-level device→host fetch
-is a sync the device waits behind.
++ GBM.java:416 GammaPass. The reference alternates a distributed histogram
+build with a host-side split search a level; here nothing of a tree leaves
+the device: a per-level device->host fetch is a sync the device waits
+behind, and a scatter-add a level serializes on a TPU (57% of training
+time when every level was built that way, profiled on a v5e).
 
-TPU-native design (round 3 + the round-4 deep-tree unification):
+TPU-native design:
 - Histograms are MXU matmuls, not scatters:  hist = Oᵀ·V  with
   O (rows, F·maxB) the per-feature bin one-hot and V (rows, 3·S) the
   (w, w·y, w·y²) triples crossed with the node one-hot. Operands are cast
@@ -19,20 +18,21 @@ TPU-native design (round 3 + the round-4 deep-tree unification):
   FLOPs, is the roofline here. Blocked over row chunks.
 - The split search runs on device, vectorized over (node, feature, bin):
   categorical bins are ordered by per-node mean response (argsort) — the
-  same sorted-subset optimum the host search computed — numeric bins keep
+  sorted-subset optimum for squared loss — numeric bins keep
   natural order via an iota sort key. NA direction is tried both ways.
-- DENSE-FRONTIER slots, not heap positions (round 4): level d holds
+- DENSE-FRONTIER slots, not heap positions: level d holds
   S_d = min(2^d, frontier_cap) slots; nodes that split are renumbered by a
   device prefix-sum and record explicit child-slot links in their packed
   row. Memory is O(depth · frontier_cap) instead of O(2^depth), so DRF's
-  default depth 20 runs in the SAME one-dispatch program — no host
-  fallback. When a level wants more than S_{d+1}/2 splits, the lowest-gain
-  candidates terminalize (greedy-best under a width budget; cap via
-  H2O_TPU_FRONTIER_CAP, default 4096).
-- Levels wider than the MXU sweet spot (S > 1024) switch the histogram to
-  a blocked scatter-add: O(N·F) work per level — the matmul's O(N·F·B·S)
-  FLOPs stop being free once the node one-hot is thousands wide. Shallow
-  levels (where the flagship bench lives) keep the matmul path untouched.
+  default depth 20 runs in the SAME one-dispatch program. When a level
+  wants more than S_{d+1}/2 splits, the lowest-gain candidates terminalize
+  (greedy-best under a width budget, frontier_cap()).
+- Two histogram lowerings, chosen a level from its static width alone
+  (hist_lowering): the matmul up to MATMUL_S_LIMIT slots, a scatter-add
+  beyond — O(N·F) work per level where the matmul's O(N·lanes·S) FLOPs
+  stop being free once the node one-hot is thousands wide. Both benchmark
+  configurations (depth 5 and depth 10) stay on the matmul at every level;
+  only forests deeper than 10 reach the scatter.
 - Routing (`_route`, the one step tree_program and apply_packed share)
   gathers from no operand that carries the rows: a per-row gather has no
   hardware on a TPU (19 ns a row a level, 70% of a depth-5 job when the
@@ -59,7 +59,6 @@ from __future__ import annotations
 from h2o3_tpu.compat import pcast as _compat_pcast
 from h2o3_tpu.compat import shard_map as _compat_shard_map
 import functools
-import os
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -78,8 +77,8 @@ def _mesh():
 def frontier_cap(F: Optional[int] = None, maxB: Optional[int] = None) -> int:
     """Frontier width budget. With feature geometry given, the cap shrinks
     so the scatter histogram buffer (S·F·maxB·3 f32) stays under ~512 MB —
-    a >=1024-level enum would otherwise blow HBM at the env default."""
-    cap = int(os.environ.get("H2O_TPU_FRONTIER_CAP", DEFAULT_FRONTIER_CAP))
+    a >=1024-level enum would otherwise blow HBM at the default."""
+    cap = DEFAULT_FRONTIER_CAP
     if F and maxB:
         budget_slots = (512 * 1024 * 1024) // (F * maxB * 12)
         mem_cap = 1 << max(int(budget_slots).bit_length() - 1, 8)
@@ -274,19 +273,98 @@ def route_forms(max_depth: int, F: int, maxB: int) -> Tuple[str, ...]:
 
 
 # ---------------------------------------------------------------------------
+# the level histogram: two lowerings of one signature, one rule from shape
+# ---------------------------------------------------------------------------
+# Both run inside a shard_map over the mesh's "rows" axis on one shard's
+# rows, padded to a multiple of blk with dead rows (tree_program does
+# that), and return the psum'd (S, F, maxB, 3) sums of (w, w·y, w·y²) over
+# the live rows of each (slot, feature, bin). nbins (F,) are the bins each
+# feature has, maxB their maximum.
+
+def hist_matmul(binned, row_node, live, w, y, S: int, *, nbins: tuple,
+                maxB: int, blk: int):
+    """(S, F, maxB, 3) via blocked bf16 one-hot matmul + psum — the
+    MXU lowering; O(N·lanes·S·3) FLOPs, almost all on zeros. The
+    one-hot carries the bins that exist, nbins[f] lanes a feature
+    (BinSpec.offsets' layout), not maxB: with a 300-level enum beside a
+    7-level one two thirds of F·maxB lanes would be bins no row can
+    fall in. The sums are laid out to (F, maxB) afterwards, zeros in
+    the lanes a feature does not have."""
+    import jax
+    import jax.numpy as jnp
+
+    F = len(nbins)
+    lanes = sum(nbins)          # the bins that exist
+
+    def body(i, acc):
+        sl = lambda a: jax.lax.dynamic_slice_in_dim(a, i * blk, blk, 0)
+        bb = sl(binned)
+        nodeb = sl(row_node)
+        liveb = sl(live)
+        wb = jnp.where(liveb, sl(w), 0.0)
+        yb = sl(y)
+        Ob = jnp.concatenate(
+            [jax.nn.one_hot(bb[:, f], nbins[f], dtype=jnp.bfloat16)
+             for f in range(F)], axis=1)                     # (blk, lanes)
+        node_oh = jax.nn.one_hot(nodeb, S, dtype=jnp.float32)
+        vals = jnp.stack([wb, wb * yb, wb * yb * yb], axis=-1)
+        V = (node_oh[:, :, None] * vals[:, None, :]).reshape(blk, S * 3)
+        return acc + jnp.dot(Ob.T, V.astype(jnp.bfloat16),
+                             preferred_element_type=jnp.float32)
+
+    acc0 = _compat_pcast(jnp.zeros((lanes, S * 3), jnp.float32),
+                         ("rows",), to="varying")
+    acc = jax.lax.fori_loop(0, binned.shape[0] // blk, body, acc0)
+    acc = jax.lax.psum(acc, "rows")
+    if lanes != F * maxB:
+        acc = jnp.concatenate(
+            [jnp.pad(acc[o:o + nb], ((0, maxB - nb), (0, 0)))
+             for o, nb in zip(np.cumsum((0,) + nbins[:-1]), nbins)])
+    return acc.reshape(F, maxB, S, 3).transpose(2, 0, 1, 3)
+
+
+def hist_scatter(binned, row_node, live, w, y, S: int, *, nbins: tuple,
+                 maxB: int, blk: int):
+    """(S, F, maxB, 3) via scatter-add — O(N·F) per level, the right
+    asymptotics once the frontier is thousands wide (deep DRF levels);
+    the matmul path's O(N·F·B·S) FLOPs stop being free there. One
+    scatter over the shard's rows: blk is not used."""
+    import jax
+    import jax.numpy as jnp
+
+    F = len(nbins)
+    node = jnp.where(live, row_node, S)               # dead rows → pad slot
+    base = (node[:, None] * F + jnp.arange(F)[None, :]) * maxB + binned
+    w_live = jnp.where(live, w, 0.0)
+    vals = jnp.stack([w_live, w_live * y, w_live * y * y], -1)  # (n, 3)
+    acc0 = _compat_pcast(jnp.zeros(((S + 1) * F * maxB, 3), jnp.float32),
+                         ("rows",), to="varying")
+    acc = acc0.at[base.reshape(-1)].add(
+        jnp.broadcast_to(vals[:, None, :],
+                         (vals.shape[0], F, 3)).reshape(-1, 3))
+    acc = jax.lax.psum(acc, "rows")
+    return acc[: S * F * maxB].reshape(S, F, maxB, 3)
+
+
+def hist_lowering(S: int):
+    """The histogram lowering of a level, from its static width alone:
+    the matmul while the node one-hot is at most MATMUL_S_LIMIT wide, the
+    scatter-add beyond (module docstring)."""
+    return hist_matmul if S <= MATMUL_S_LIMIT else hist_scatter
+
+
+# ---------------------------------------------------------------------------
 # the per-tree program
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=32)
 def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
              min_rows: float, min_split_improvement: float,
-             has_masks: bool, mesh, n_shard: int, blk: int, cap: int,
-             lowering: str = "matmul"):
+             has_masks: bool, mesh, n_shard: int, blk: int, cap: int):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from h2o3_tpu.models.tree import pallas_hist
     from h2o3_tpu.obs import compiles
 
     nblk = -(-n_shard // blk)
@@ -296,76 +374,6 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
     tot_slots = sum(widths)
     Smax = max(widths)
     K = pack_width(maxB)
-    TB = F * maxB
-    lanes = sum(nbins)          # the bins that exist
-
-    def hist_gather_pl(binned, row_node, live, w, y, S):
-        """(S, F, maxB, 3) via the fused Pallas gather→accumulate kernel
-        (pallas_hist.py): flat node·TB + offset[f] + bin indices
-        scatter-added into a VMEM-resident accumulator — no one-hot ever
-        materializes, all features in one grid pass. Dead rows encode as
-        node = -1 / w = 0 (no tile owns them). The frontier tile plan is
-        static per level; `lowering` is part of the _grow_fn cache key
-        (the env/auto decision is taken at CALL time in
-        grow_tree_device), so toggling the flag mid-process picks the
-        right compiled program instead of a stale cache entry."""
-        node = jnp.where(live, row_node, -1)
-        w_live = jnp.where(live, w, 0.0)
-        acc = pallas_hist.hist_gather(
-            binned, node, w_live, y,
-            offsets=np.arange(F, dtype=np.int32) * maxB, TB=TB, S=S)
-        acc = jax.lax.psum(acc, "rows")
-        return acc.reshape(S, F, maxB, 3)
-
-    def hist_matmul(binned, row_node, live, w, y, S):
-        """(S, F, maxB, 3) via blocked bf16 one-hot matmul + psum — the
-        MXU lowering; O(N·lanes·S·3) FLOPs, almost all on zeros. The
-        one-hot carries the bins that exist, nbins[f] lanes a feature
-        (BinSpec.offsets' layout), not maxB: with a 300-level enum beside a
-        7-level one two thirds of F·maxB lanes would be bins no row can
-        fall in. The sums are laid out to (F, maxB) afterwards, zeros in
-        the lanes a feature does not have."""
-        def body(i, acc):
-            sl = lambda a: jax.lax.dynamic_slice_in_dim(a, i * blk, blk, 0)
-            bb = sl(binned)
-            nodeb = sl(row_node)
-            liveb = sl(live)
-            wb = jnp.where(liveb, sl(w), 0.0)
-            yb = sl(y)
-            Ob = jnp.concatenate(
-                [jax.nn.one_hot(bb[:, f], nbins[f], dtype=jnp.bfloat16)
-                 for f in range(F)], axis=1)                     # (blk, lanes)
-            node_oh = jax.nn.one_hot(nodeb, S, dtype=jnp.float32)
-            vals = jnp.stack([wb, wb * yb, wb * yb * yb], axis=-1)
-            V = (node_oh[:, :, None] * vals[:, None, :]).reshape(blk, S * 3)
-            return acc + jnp.dot(Ob.T, V.astype(jnp.bfloat16),
-                                 preferred_element_type=jnp.float32)
-
-        acc0 = _compat_pcast(jnp.zeros((lanes, S * 3), jnp.float32),
-                             ("rows",), to="varying")
-        acc = jax.lax.fori_loop(0, nblk, body, acc0)
-        acc = jax.lax.psum(acc, "rows")
-        if lanes != TB:
-            acc = jnp.concatenate(
-                [jnp.pad(acc[o:o + nb], ((0, maxB - nb), (0, 0)))
-                 for o, nb in zip(np.cumsum((0,) + nbins[:-1]), nbins)])
-        return acc.reshape(F, maxB, S, 3).transpose(2, 0, 1, 3)
-
-    def hist_scatter(binned, row_node, live, w, y, S):
-        """(S, F, maxB, 3) via scatter-add — O(N·F) per level, the right
-        asymptotics once the frontier is thousands wide (deep DRF levels);
-        the matmul path's O(N·F·B·S) FLOPs stop being free there."""
-        node = jnp.where(live, row_node, S)               # dead rows → pad slot
-        base = (node[:, None] * F + jnp.arange(F)[None, :]) * maxB + binned
-        w_live = jnp.where(live, w, 0.0)
-        vals = jnp.stack([w_live, w_live * y, w_live * y * y], -1)  # (n, 3)
-        acc0 = _compat_pcast(jnp.zeros(((S + 1) * F * maxB, 3), jnp.float32),
-                             ("rows",), to="varying")
-        acc = acc0.at[base.reshape(-1)].add(
-            jnp.broadcast_to(vals[:, None, :],
-                             (vals.shape[0], F, 3)).reshape(-1, 3))
-        acc = jax.lax.psum(acc, "rows")
-        return acc[: S * F * maxB].reshape(S, F, maxB, 3)
 
     def leaf_sums(row_leaf, cols):
         """(tot_slots, C) per-leaf sums (scatter; O(N) at any tree size)."""
@@ -405,23 +413,13 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
             S = widths[d]
             live = row_leaf < 0
             if d < max_depth:
-                if lowering == "pallas":
-                    # per-level static fallback: when even a one-slot
-                    # frontier tile busts the VMEM budget, this level
-                    # takes the scatter lowering (the planner's contract)
-                    hist_fn = (hist_gather_pl
-                               if pallas_hist.plan_tiles(TB, S) is not None
-                               else hist_scatter)
-                elif lowering == "scatter":
-                    hist_fn = hist_scatter
-                else:
-                    hist_fn = (hist_matmul if S <= MATMUL_S_LIMIT
-                               else hist_scatter)
                 # named scopes are metadata on the ops: a profiler trace
                 # can sum device time by level and stage, whatever numbers
                 # XLA gives its fusions
                 with jax.named_scope(f"level{d}/hist"):
-                    hist = hist_fn(binned, row_node, live, w, yc, S)
+                    hist = hist_lowering(S)(binned, row_node, live, w, yc,
+                                            S, nbins=nbins, maxB=maxB,
+                                            blk=blk)
                 fm = masks[d] if has_masks else None
                 with jax.named_scope(f"level{d}/search"):
                     (split_feat, t_star, na_left, gain,
@@ -492,16 +490,11 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
 
     in_specs = (P("rows", None), P("rows"), P("rows"), P("rows"), P("rows"),
                 tuple(P() for _ in range(max_depth)) if has_masks else P())
-    # pallas interpret mode (CPU tests) lowers pallas_call to slices whose
-    # internal index constants carry empty vma sets, tripping check_vma;
-    # compiled TPU lowering annotates properly, so only interpret relaxes it
-    check_vma = not (lowering == "pallas" and jax.default_backend() != "tpu")
     fn = _compat_shard_map(tree_program, mesh=mesh,
                        in_specs=in_specs,
-                       out_specs=(P(), P(), P("rows")),
-                       check_vma=check_vma)
+                       out_specs=(P(), P(), P("rows")))
     return compiles.ledgered_jit(
-        "tree", fn, program=f"tree_grow_d{max_depth}_{lowering}")
+        "tree", fn, program=f"tree_grow_d{max_depth}")
 
 
 def _count_route(forms: Tuple[str, ...]) -> None:
@@ -558,27 +551,10 @@ def grow_tree_device(binned, w, y, spec, *, max_depth: int, min_rows: float,
     maxB = int(spec.nbins.max())
     blk = _pick_blk(n_shard, int(spec.nbins.sum()))
     has_masks = feat_masks is not None
-    from h2o3_tpu.models.tree import pallas_hist
-
-    # lowering decision at the widest matmul-comparable level of this
-    # tree's program (that level dominates the histogram cost; wider
-    # frontiers tile or scatter either way): forced by
-    # H2O_TPU_PALLAS_HIST=1/scatter, measured once per
-    # (F, maxB, S, backend) under =auto, one-hot matmul by default
-    cap_v = frontier_cap(F, maxB)
-    widths = level_widths(int(max_depth), cap_v)
-    s_widest = max([wd for wd in widths[: int(max_depth)]
-                    if wd <= MATMUL_S_LIMIT], default=1)
-    lowering = pallas_hist.decide_lowering(F, maxB, s_widest)
-    if lowering == "pallas":
-        # record the tile plan at the WIDEST level of this tree — the
-        # frontier the budget planner actually has to fit (bench aux)
-        pallas_hist.note_plan(F * maxB, max(widths[: int(max_depth)],
-                                            default=1))
     fn = _grow_fn(int(max_depth), F, maxB, tuple(int(b) for b in spec.nbins),
                   tuple(bool(c) for c in spec.is_cat), float(min_rows),
                   float(min_split_improvement), has_masks, mesh, n_shard, blk,
-                  cap_v, lowering=lowering)
+                  frontier_cap(F, maxB))
     _count_route(route_forms(int(max_depth), F, maxB))
     w = w.astype(jnp.float32)
     y = y.astype(jnp.float32)

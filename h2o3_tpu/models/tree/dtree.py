@@ -1,28 +1,20 @@
-"""Host-side tree structure + vectorized best-split search.
+"""Host-side tree structure: what a grown tree is assembled into.
 
-Reference: hex/tree/DTree.java (decideBestSplit per leaf) and
-hex/tree/DHistogram.java scoring math — split gain is the squared-error
-reduction  SE(parent) - SE(left) - SE(right)  with SE = wyy - wy²/w,
-computed from the (w, wy, wyy) histogram triples; NA rows are assigned to
-whichever side improves the gain (DHistogram NA-vs-rest handling);
-categorical splits are subset splits.
-
-TPU-split-of-work: the device produces the (nodes, tot_bins, 3) histogram
-(histogram.py); everything here is microseconds of numpy on (nodes, B)
-arrays — the same host/device split the reference's XGBoost GPU path uses
-(histograms on GPU, tree bookkeeping on CPU). Categorical subsets use the
-sorted-by-mean prefix trick (optimal for squared loss — the reference
-reaches the same splits through its sorted categorical histograms).
+Reference: hex/tree/DTree.java. GBM and DRF grow a tree in one device
+program (device_tree.py: histogram, split search with the squared-error
+gain SE(parent) - SE(left) - SE(right), SE = wyy - wy²/w, routing) and
+build a HostTree from its fetched tables (host_tree_from_packed);
+IsolationForest builds one a level at a time from random splits
+(isofor.py). Numeric thresholds, categorical subsets and the side of the
+NA bin unify into one routing table a node (left_table_for).
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import List, Optional
 
 import numpy as np
-
-EPS_W = 1e-12
 
 
 @dataclass
@@ -50,85 +42,6 @@ class TreeNode:
     leaf_id: int = -1             # dense leaf numbering for GammaPass
     weight: float = 0.0
     pred: float = 0.0             # node mean (wy/w) — DRF leaf / pruning
-
-
-def _se(w, wy, wyy):
-    """Squared error within a bucket set; 0 where empty."""
-    return wyy - np.where(w > EPS_W, wy * wy / np.maximum(w, EPS_W), 0.0)
-
-
-def find_best_splits(hist: np.ndarray, spec, *, min_rows: float,
-                     min_split_improvement: float,
-                     feat_mask: Optional[np.ndarray] = None) -> List[Optional[Split]]:
-    """Best split per active node from the level histogram.
-
-    hist: (S, tot_bins, 3) w/wy/wyy. feat_mask: optional (S, F) bool of
-    features allowed per node (DRF mtries). Returns one Split or None per
-    node slot.
-    """
-    S = hist.shape[0]
-    F = spec.F
-    best_gain = np.full(S, 0.0)
-    best = [None] * S
-
-    for f in range(F):
-        o, B = int(spec.offsets[f]), int(spec.nbins[f])
-        H = hist[:, o:o + B, :]               # (S, B, 3)
-        na = H[:, -1, :]                      # (S, 3) NA bucket
-        V = H[:, :-1, :]                      # value buckets
-        nb = V.shape[1]
-        if nb < 2:
-            continue
-        tot = V.sum(axis=1) + na              # (S, 3)
-        se_parent = _se(tot[:, 0], tot[:, 1], tot[:, 2])
-
-        if spec.is_cat[f]:
-            # order categories by per-node mean response; prefix over the
-            # sorted order yields the optimal subset for squared loss
-            mean = np.where(V[:, :, 0] > EPS_W,
-                            V[:, :, 1] / np.maximum(V[:, :, 0], EPS_W), np.inf)
-            order = np.argsort(mean, axis=1)                  # (S, nb)
-            Vs = np.take_along_axis(V, order[:, :, None], axis=1)
-        else:
-            order = None
-            Vs = V
-
-        prefix = np.cumsum(Vs, axis=1)        # (S, nb, 3)
-        cand = prefix[:, :-1, :]              # split after position t (S, nb-1, 3)
-
-        gains = np.full((S, nb - 1, 2), -np.inf)
-        for na_dir in (0, 1):                 # 0: NA right, 1: NA left
-            L = cand + (na[:, None, :] if na_dir else 0)
-            R = tot[:, None, :] - L
-            ok = (L[:, :, 0] >= min_rows) & (R[:, :, 0] >= min_rows)
-            g = (se_parent[:, None]
-                 - _se(L[:, :, 0], L[:, :, 1], L[:, :, 2])
-                 - _se(R[:, :, 0], R[:, :, 1], R[:, :, 2]))
-            gains[:, :, na_dir] = np.where(ok, g, -np.inf)
-
-        flat = gains.reshape(S, -1)
-        bi = np.argmax(flat, axis=1)
-        bg = flat[np.arange(S), bi]
-        t, na_dir = bi // 2, bi % 2
-
-        improve = bg > np.maximum(best_gain, min_split_improvement)
-        if feat_mask is not None:
-            improve &= feat_mask[:, f]
-        for s in np.nonzero(improve)[0]:
-            ts = int(t[s])
-            Lst = cand[s, ts] + (na[s] if na_dir[s] else 0)
-            Rst = tot[s] - Lst
-            if spec.is_cat[f]:
-                left_bins = np.zeros(nb, bool)
-                left_bins[order[s, :ts + 1]] = True
-                split = Split(f, True, -1, left_bins, bool(na_dir[s]),
-                              float(bg[s]), (Lst[0], Lst[1]), (Rst[0], Rst[1]))
-            else:
-                split = Split(f, False, ts, None, bool(na_dir[s]),
-                              float(bg[s]), (Lst[0], Lst[1]), (Rst[0], Rst[1]))
-            best_gain[s] = bg[s]
-            best[s] = split
-    return best
 
 
 def left_table_for(splits: List[Optional[Split]], spec, maxB: int) -> np.ndarray:
@@ -167,40 +80,3 @@ class HostTree:
         n.pred = pred
         self.n_leaves += 1
         return n.leaf_id
-
-    def set_leaf_values(self, values: np.ndarray):
-        for n in self.nodes:
-            if n.leaf_id >= 0:
-                n.leaf_value = float(values[n.leaf_id])
-
-    def apply_binned(self, binned: np.ndarray, spec) -> np.ndarray:
-        """Vectorized host traversal: per-row leaf value for a (n, F) binned
-        matrix — used for in-training validation scoring, where the valid
-        margin is maintained incrementally one tree at a time."""
-        n_nodes = len(self.nodes)
-        feat = np.full(n_nodes, -1, np.int32)
-        left = np.zeros(n_nodes, np.int32)
-        right = np.zeros(n_nodes, np.int32)
-        value = np.zeros(n_nodes, np.float64)
-        maxB = int(spec.nbins.max())
-        splits = [nd.split for nd in self.nodes]
-        lt = left_table_for(splits, spec, maxB)   # one routing convention
-        for nd in self.nodes:
-            if nd.split is None:
-                value[nd.nid] = nd.leaf_value
-                continue
-            feat[nd.nid] = nd.split.feat
-            left[nd.nid] = nd.left
-            right[nd.nid] = nd.right
-        n = len(binned)
-        node = np.zeros(n, np.int32)
-        rows = np.arange(n)
-        while True:
-            f = feat[node]
-            live = f >= 0
-            if not live.any():
-                break
-            b = binned[rows, np.maximum(f, 0)]
-            gl = lt[node, np.minimum(b, maxB - 1)]
-            node = np.where(live, np.where(gl, left[node], right[node]), node)
-        return value[node]

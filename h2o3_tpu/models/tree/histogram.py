@@ -1,4 +1,7 @@
-"""Distributed histogram build — the hot kernel of tree training.
+"""The level-wise histogram build and row router of the host-driven tree
+loop, which IsolationForest (isofor.py) runs: its random splits need a
+count histogram a level and no split search. GBM and DRF grow a tree in
+one device program (device_tree.py) and use nothing here.
 
 Reference: hex/tree/ScoreBuildHistogram2.java:60 — per-row bin increments
 into DHistogram _vals[] (w/wY/wYY triples, DHistogram.java:62-90) with
@@ -31,34 +34,18 @@ def _mesh():
 
 
 @functools.lru_cache(maxsize=64)
-def _build_hist_fn(n_nodes: int, tot_bins: int, F: int, mesh,
-                   lowering: str = "scatter"):
+def _build_hist_fn(n_nodes: int, tot_bins: int, F: int, mesh):
     """Jitted (binned, row_node, w, y, offsets) -> (n_nodes, tot_bins, 3).
 
     Cache key includes the padded node count, so only O(log depth) distinct
-    programs compile per (dataset, depth) family — and the lowering, so
-    flipping H2O_TPU_PALLAS_HIST mid-process never serves a stale program.
-    `lowering` here is binary: the fused Pallas gather→accumulate kernel
-    (pallas_hist.hist_gather, frontier-tiled under the VMEM budget) or the
-    XLA scatter-add below.
+    programs compile per (dataset, depth) family.
     """
-    from h2o3_tpu.models.tree import pallas_hist
     from h2o3_tpu.obs import compiles
 
     def local_hist(binned, row_node, w, y, offsets):
         # binned (n, F) integer bins (narrowest dtype that fits nbins);
         # row_node (n,) int32 (-1 = finalized row)
         valid = row_node >= 0
-        if lowering == "pallas":
-            # dead rows encode as node = -1 / w = 0: no frontier tile
-            # owns them, so they contribute nothing (same semantics as
-            # the scatter path's mode="drop" sentinel index)
-            node = jnp.where(valid, row_node, -1)
-            wv = jnp.where(valid, w, 0.0)
-            acc = pallas_hist.hist_gather(binned, node, wv, y,
-                                          offsets=offsets, TB=tot_bins,
-                                          S=n_nodes)
-            return jax.lax.psum(acc, "rows")
         node = jnp.maximum(row_node, 0)
         idx = node[:, None] * tot_bins + offsets[None, :] + binned   # (n, F)
         idx = jnp.where(valid[:, None], idx, n_nodes * tot_bins)     # dropped
@@ -69,34 +56,23 @@ def _build_hist_fn(n_nodes: int, tot_bins: int, F: int, mesh,
         acc = acc.at[idx.reshape(-1)].add(upd.reshape(-1, 3), mode="drop")
         return jax.lax.psum(acc, "rows")
 
-    # interpret-mode pallas (CPU) lowers to slices whose index constants
-    # carry empty replication sets, tripping the shard_map check
-    check_vma = not (lowering == "pallas" and jax.default_backend() != "tpu")
     fn = _compat_shard_map(
         local_hist, mesh=mesh,
         in_specs=(P("rows", None), P("rows"), P("rows"), P("rows"), P()),
         out_specs=P(),
-        check_vma=check_vma,
     )
 
     def run(binned, row_node, w, y, offsets):
         return fn(binned, row_node, w, y, offsets).reshape(n_nodes, tot_bins, 3)
 
     return compiles.ledgered_jit(
-        "tree", run, program=f"hist_level_S{n_nodes}_{lowering}")
+        "tree", run, program=f"hist_level_S{n_nodes}")
 
 
 def build_histogram(binned, row_node, w, y, spec, n_nodes: int) -> np.ndarray:
     """-> host (n_nodes, tot_bins, 3) float64 histogram (w, wy, wyy)."""
-    from h2o3_tpu.models.tree import pallas_hist
-
     n_pad = max(1 << (n_nodes - 1).bit_length(), 1)
-    # the level-wise grower has no matmul path: anything short of a
-    # pallas verdict (with a feasible tile plan) takes the scatter-add
-    lw = pallas_hist.decide_lowering(spec.F, int(spec.nbins.max()), n_pad)
-    if lw != "pallas" or pallas_hist.plan_tiles(spec.tot_bins, n_pad) is None:
-        lw = "scatter"
-    fn = _build_hist_fn(n_pad, spec.tot_bins, spec.F, _mesh(), lowering=lw)
+    fn = _build_hist_fn(n_pad, spec.tot_bins, spec.F, _mesh())
     offsets = jnp.asarray(spec.offsets[:-1], jnp.int32)
     out = fn(binned, row_node, w.astype(jnp.float32), y.astype(jnp.float32), offsets)
     return np.asarray(out, np.float64)[:n_nodes]
@@ -154,30 +130,3 @@ def route_rows(binned, row_node, row_leaf, *, split_feat, left_table,
               jnp.asarray(pad1(np.asarray(left_slot, np.int32), -1)),
               jnp.asarray(pad1(np.asarray(right_slot, np.int32), -1)),
               jnp.asarray(pad1(np.asarray(leaf_id, np.int32), -1)))
-
-
-@functools.lru_cache(maxsize=16)
-def _build_leaf_stats_fn(L: int, mesh):
-    def stats(row_leaf, num, den):
-        valid = row_leaf >= 0
-        leaf = jnp.maximum(row_leaf, 0)
-        nz = jnp.zeros(L, jnp.float32)
-        n = nz.at[leaf].add(jnp.where(valid, num, 0.0), mode="drop")
-        d = nz.at[leaf].add(jnp.where(valid, den, 0.0), mode="drop")
-        return jax.lax.psum(n, "rows"), jax.lax.psum(d, "rows")
-
-    fn = _compat_shard_map(stats, mesh=mesh,
-                       in_specs=(P("rows"), P("rows"), P("rows")),
-                       out_specs=(P(), P()))
-    from h2o3_tpu.obs import compiles
-
-    return compiles.ledgered_jit("tree", fn, program=f"tree_leaf_stats_L{L}")
-
-
-def leaf_stats(row_leaf, num, den, n_leaves: int):
-    """Per-leaf segment sums of (num, den) — the GammaPass
-    (tree/gbm/GBM.java:416) as one scatter-add + psum."""
-    L = max(1 << (n_leaves - 1).bit_length(), 1)
-    fn = _build_leaf_stats_fn(L, _mesh())
-    n, d = fn(row_leaf, num.astype(jnp.float32), den.astype(jnp.float32))
-    return np.asarray(n, np.float64)[:n_leaves], np.asarray(d, np.float64)[:n_leaves]

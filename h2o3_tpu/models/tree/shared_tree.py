@@ -5,14 +5,16 @@ scoreAndBuildTrees (:439): per tree-level a distributed histogram build
 (ScoreBuildHistogram2) then host-side best-split decisions (DTree), with
 early stopping via ScoreKeeper.
 
-TPU-native design: the per-level loop alternates ONE device program
-(scatter-add histogram + psum, histogram.py) with microseconds of host
-numpy (split search, dtree.py), then ONE device program routing every row
-to its next node (route_rows). Active nodes are renumbered densely per
-level (padded to powers of two so only O(log depth) programs compile).
-Row→leaf assignments stay on device for the whole tree; the GammaPass leaf
-Newton step is a segment-sum (leaf_stats). Sampled-out rows carry w=0 in
+TPU-native design: one tree is ONE device program
+(device_tree.grow_tree_device: histogram, split search, routing and the
+GammaPass leaf sums, at any depth), with one jitted step before it
+(gradients, row sampling) and one after (leaf Newton steps, margin
+update). Row→leaf assignments stay on device for the whole tree; every
+tree's tables are fetched once, after the last tree (deeper than 10, a
+tree at a time: device_tree.stash_packed). Sampled-out rows carry w=0 in
 the histogram but keep routing (OOB scoring reads their leaves for free).
+IsolationForest alone keeps a host loop a level (isofor.py over
+histogram.py): its splits are random and need no search.
 """
 
 from __future__ import annotations
@@ -99,22 +101,6 @@ def _post_fn(builder, clip: float):
         fn = compiles.ledgered_jit("tree", post, program="tree_post")
         _STEP_FNS[k] = fn
     return fn
-
-
-def grow_tree(binned, hist_w, hist_y, spec, *, max_depth: int, min_rows: float,
-              min_split_improvement: float, row_active=None,
-              feat_mask_fn=None, rng: Optional[np.random.Generator] = None):
-    """Public single-tree API (old contract: HostTree with DENSE leaf ids).
-    Delegates to the host-orchestrated level-wise grower — safe at any
-    depth. The fit loops below use the faster single-dispatch device grower
-    (device_tree.grow_tree_device) directly."""
-    from h2o3_tpu.models.tree.host_grow import grow_tree_host
-
-    return grow_tree_host(binned, hist_w, hist_y, spec, max_depth=max_depth,
-                          min_rows=min_rows,
-                          min_split_improvement=min_split_improvement,
-                          row_active=row_active, feat_mask_fn=feat_mask_fn,
-                          rng=rng)
 
 
 class SharedTreeModel(Model):

@@ -6,7 +6,7 @@ and rebuilds the Rabit all-reduce tracker in Java (RabitTrackerH2O.java:14);
 the GPU path is CUDA grow_gpu_hist (XGBoostModel.java:384-389).
 
 TPU-native design (SURVEY.md §2.10 item 1): no external native library at
-all — the SAME Pallas/XLA histogram tree kernel family as GBM IS the
+all — the SAME one-tree device program as GBM (device_tree.py) IS the
 booster (hist == gpu_hist == our device histogram build), and the gradient
 all-reduce is the mesh psum the histogram already performs. This class maps
 the XGBoost parameter vocabulary (eta, colsample_*, reg_lambda, ...) onto

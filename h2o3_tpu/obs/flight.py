@@ -2,14 +2,14 @@
 
 A process that dies used to leave nothing to autopsy but "timeout after
 120s". This module makes every abnormal exit leave a corpse: on a fatal signal, a watchdog recovery action, a cloud FAILURE, or
-a bench-stage timeout, the timeline ring + this thread's open spans + a
+a lifecycle-phase deadline (obs/phases.py), the timeline ring + this thread's open spans + a
 metrics snapshot persist ATOMICALLY (tmp + rename) to
 ``$H2O_TPU_OBS_FLIGHT_DIR`` (default ``$H2O_TPU_ICE_ROOT/flight``),
 size-capped and self-GCing (``H2O_TPU_OBS_FLIGHT_KEEP`` newest kept).
 ``GET /3/FlightRecords`` lists and fetches them.
 
 Import cost: stdlib only — a process whose backend init hangs can still
-dump (the bench autopsy path depends on this)."""
+dump (the phase-deadline dump depends on this)."""
 
 from __future__ import annotations
 

@@ -20,8 +20,7 @@ answer for the TPU engine:
 - **Hard deadlines** (``H2O_TPU_PHASE_DEADLINE_S``, a map like
   ``"backend_init=45,first_compile=90"`` or one number for every phase):
   a daemon timer dumps a flight record NAMING the wedged phase on expiry,
-  emits the ``H2O3_FLIGHT_JSON`` corpse line in bench contexts, invokes
-  the caller's ``fallback`` action, and — for ``backend_init`` /
+  invokes the caller's ``fallback`` action, and — for ``backend_init`` /
   ``first_compile`` with ``H2O_TPU_PHASE_DEADLINE_EXIT=1`` —
   hard-exits with :data:`DEADLINE_EXIT_RC` so a supervising parent sees
   the failure at once instead of waiting out its own timeout.
@@ -74,8 +73,7 @@ _LATEST: Dict[str, dict] = {}
 def deadlines() -> Dict[str, float]:
     """Per-phase hard deadlines from ``H2O_TPU_PHASE_DEADLINE_S`` — either
     one number (every phase) or a ``name=secs`` comma map. Unset/0 =
-    unsupervised (library mode default; the bench driver arms the map in
-    every child)."""
+    unsupervised (the library mode default)."""
     raw = os.environ.get("H2O_TPU_PHASE_DEADLINE_S", "").strip()
     if not raw:
         return {}
@@ -101,8 +99,8 @@ def deadlines() -> Dict[str, float]:
 
 def deadline_exit_enabled() -> bool:
     """``H2O_TPU_PHASE_DEADLINE_EXIT=1``: a backend_init/first_compile
-    expiry hard-exits the process with :data:`DEADLINE_EXIT_RC` (set by
-    the bench driver for its children; never on in library mode)."""
+    expiry hard-exits the process with :data:`DEADLINE_EXIT_RC` (for a
+    supervised child process; never on in library mode)."""
     return os.environ.get("H2O_TPU_PHASE_DEADLINE_EXIT", "").lower() in (
         "1", "true", "on")
 
@@ -130,32 +128,9 @@ def _metric(kind: str, name: str, *args, **labels) -> None:
         pass
 
 
-def _bench_corpse(rec: dict, flight_path: Optional[str]) -> None:
-    """One ``H2O3_FLIGHT_JSON`` line to stderr in bench contexts so the
-    parent folds the wedged phase into the failing BENCH_STAGE record."""
-    if not os.environ.get("H2O3_BENCH_STAGE_TIMEOUT_S"):
-        return
-    try:
-        import json
-
-        tail: List[dict] = []
-        try:
-            from h2o3_tpu.utils import timeline
-
-            tail = timeline.events(20)
-        except Exception:   # noqa: BLE001
-            pass
-        print("H2O3_FLIGHT_JSON " + json.dumps(
-            {"flight_record": flight_path, "timeline_tail": tail,
-             "phase": rec["phase"], "phase_report": phase_report()},
-            default=str), file=sys.stderr, flush=True)
-    except Exception:   # noqa: BLE001
-        pass
-
-
 def _on_deadline(rec: dict, fallback: Optional[Callable]) -> None:
     """Deadline expiry (timer thread): flight record naming the phase,
-    metrics, the bench corpse line, the caller's fallback action, and —
+    metrics, the caller's fallback action, and —
     under ``H2O_TPU_PHASE_DEADLINE_EXIT=1`` only — the fast process
     exit."""
     with _LOCK:
@@ -176,7 +151,6 @@ def _on_deadline(rec: dict, fallback: Optional[Callable]) -> None:
         rec["flight_record"] = path
     except Exception:   # noqa: BLE001
         pass
-    _bench_corpse(rec, path)
     if fallback is not None:
         try:
             _metric("inc", "h2o3_phase_cpu_fallbacks_total", phase=name)
@@ -275,7 +249,7 @@ def history() -> List[dict]:
 
 def phase_report() -> Dict[str, float]:
     """{phase: wall ms} of the most recent COMPLETED entry per phase, in
-    lifecycle order — the bench aux-line / flight-record summary shape.
+    lifecycle order — the flight-record / ``/3/Runtime`` summary shape.
     Read from the per-phase latest store (not the bounded ring), so the
     boot durations survive long-lived processes."""
     with _LOCK:
@@ -287,12 +261,11 @@ def phase_report() -> Dict[str, float]:
 def wedged_phase(grace_s: float = 120.0) -> Optional[str]:
     """Name of the oldest phase that never completed — deadline-expired
     with no completion time, or running PAST its deadline (or past
-    `grace_s` when unsupervised). What a bench autopsy names as 'the
+    `grace_s` when unsupervised). What an autopsy names as 'the
     phase that never completed'. A phase that is merely in progress is
     NOT wedged: a live /3/Runtime query racing a healthy boot must not
     report a wedge, so the unsupervised grace sits beyond the slowest
-    healthy boot step (the bench deadline map tops out at
-    first_compile=90 s); and one that blew its deadline but DID
+    healthy boot step; and one that blew its deadline but DID
     eventually finish keeps its 'deadline' verdict in history without
     reading as wedged forever."""
     now = time.time()

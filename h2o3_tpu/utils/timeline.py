@@ -31,7 +31,6 @@ KINDS = frozenset({
     "flight",           # flight-recorder dumps (obs/flight.py)
     "job",              # durable job-progress saves
     "oplog",            # control-plane checkpoints
-    "pallas_auto",      # pallas-vs-XLA microbenchmark verdicts
     "phase",            # lifecycle phase begin/end (obs/phases.py)
     "profiler",         # /3/Profiler start/stop captures
     "rest",             # REST request ring (api/server.py merge)

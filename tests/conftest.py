@@ -9,26 +9,20 @@ import os
 
 # set the flag env AND update jax.config (effective until backend init, which
 # is lazy).
-# H2O_TPU_TEST_REAL=1 keeps the real accelerator backend instead — the
-# opt-in for the real-silicon test tiers (test_pallas_hist
-# TestRealTpuLowering), which are unreachable under the forced-CPU mesh.
-_REAL = bool(os.environ.get("H2O_TPU_TEST_REAL"))
-if not _REAL:
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8")
-    os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
 
-if not _REAL:
-    jax.config.update("jax_platforms", "cpu")
-    # init() points JAX's persistent compile cache at <checkout>/.jax_cache
-    # for chip runs. This long-lived CPU harness stays off it: it buys no
-    # time here (measured), and executables read back from it take
-    # XLA:CPU's AOT loader instead of the JIT every earlier run of this
-    # suite used. test_chip_smoke.py covers the cache in processes of its
-    # own.
-    jax.config.update("jax_enable_compilation_cache", False)
+jax.config.update("jax_platforms", "cpu")
+# init() points JAX's persistent compile cache at <checkout>/.jax_cache
+# for chip runs. This long-lived CPU harness stays off it: it buys no
+# time here (measured), and executables read back from it take
+# XLA:CPU's AOT loader instead of the JIT every earlier run of this
+# suite used. test_chip_smoke.py covers the cache in processes of its
+# own.
+jax.config.update("jax_enable_compilation_cache", False)
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
@@ -95,13 +89,14 @@ _HEAVY_MODULES = [
     # the head of the heavy tail: the pure-host cheap modules still bank
     # their dots first)
     "test_sharded_frame", "test_serving_qps", "test_trace_tree",
-    "test_job_resume", "test_trees", "test_checkpoint", "test_genmodel",
+    "test_job_resume", "test_trees", "test_tree_hist", "test_checkpoint",
+    "test_genmodel",
     "test_artifact", "test_mojo",
     "test_mojo_families", "test_explain", "test_ensemble",
     "test_survival_gam_rulefit", "test_grid", "test_search_resume",
     # long single fits / many submodels
     "test_automl", "test_automl_bindings", "test_deep_trees",
-    "test_deeplearning", "test_pallas_hist",
+    "test_deeplearning",
     # 2-process localhost clouds: minutes per test, run dead last
     "test_multiprocess",
 ]

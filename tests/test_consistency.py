@@ -408,45 +408,3 @@ def test_pipeline_splice_is_one_program_per_bucket_with_zero_gathers():
     assert sharded_frame.counters()["gathered_rows"] == gath_before, (
         "the fused munge→score path gathered columns to the coordinator")
     model.delete()
-
-
-def test_hist_lowering_enum_matches_bench_wire_encoding(monkeypatch):
-    """ISSUE-17 guard: the histogram lowering enumeration is CLOSED and
-    its tuple order is the bench wire encoding — dashboards float the
-    ``H2O3_BENCH hist_lowering <index>`` aux line, so reordering or
-    widening ``LOWERINGS`` silently re-labels historical numbers. Pins:
-    (1) the enum's exact content+order, (2) lowering_code == the index
-    and rejects non-members, (3) the bench aux printer actually reports
-    through lowering_code(hist_report()['lowering']) from BOTH timed
-    chains, (4) every env-forced decision lands inside the enum."""
-    from h2o3_tpu.models.tree import pallas_hist
-
-    assert pallas_hist.LOWERINGS == ("matmul", "scatter", "pallas")
-    for i, name in enumerate(pallas_hist.LOWERINGS):
-        assert pallas_hist.lowering_code(name) == i
-    with pytest.raises(ValueError):
-        pallas_hist.lowering_code("onehot")   # not a lowering
-
-    rep = pallas_hist.hist_report()
-    assert {"lowering", "tile_S"} <= set(rep)
-    assert rep["lowering"] in pallas_hist.LOWERINGS
-
-    bench_src = (SRC / "bench.py").read_text(encoding="utf-8")
-    assert "hist_lowering" in bench_src and "hist_tile_S" in bench_src, \
-        "bench chains must emit the hist aux lines"
-    assert "lowering_code(rep['lowering'])" in bench_src, \
-        "the aux line must go through the wire encoding, not a raw name"
-    # both timed train stages report which lowering actually ran
-    for stage in ("run_flagship", "run_drf_deep"):
-        body = bench_src.split(f"def {stage}(")[1].split("\ndef ")[0]
-        assert "_print_hist_aux()" in body, \
-            f"{stage} must print the hist aux lines next to its metric"
-
-    for mode, want in [("1", "pallas"), ("pallas", "pallas"),
-                       ("scatter", "scatter"), ("", "matmul")]:
-        if mode:
-            monkeypatch.setenv("H2O_TPU_PALLAS_HIST", mode)
-        else:
-            monkeypatch.delenv("H2O_TPU_PALLAS_HIST", raising=False)
-        got = pallas_hist.decide_lowering(8, 16, 32)
-        assert got == want and got in pallas_hist.LOWERINGS

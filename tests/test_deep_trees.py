@@ -1,5 +1,5 @@
-"""Deep-tree device grower (round-4): depth>10 trains in the SAME
-one-dispatch dense-frontier program — no host-orchestrated fallback.
+"""Deep-tree device grower: depth>10 trains in the SAME one-dispatch
+dense-frontier program.
 
 Reference shape: hex/tree/DHistogram.java:33-44 level-wise growth at DRF's
 default depth 20; VERDICT r3 #4 acceptance: depth-20 DRF with no per-level
@@ -27,17 +27,11 @@ def _data(n=2500, seed=9):
     return fr
 
 
-def test_depth20_drf_no_host_fallback(cl, monkeypatch):
-    """DRF at its default depth 20 must use the device grower exclusively:
-    the host-orchestrated level loop (host_grow) is poisoned to prove no
-    per-level host sync remains."""
-    from h2o3_tpu.models.tree import host_grow
+def test_depth20_drf_no_host_fallback(cl):
+    """DRF at its default depth 20 trains in the device grower (there is
+    no other)."""
     from h2o3_tpu.models.tree.drf import DRF
 
-    def boom(*a, **k):
-        raise AssertionError("host_grow called: deep path fell off device")
-
-    monkeypatch.setattr(host_grow, "grow_tree_host", boom)
     fr = _data()
     m = DRF(ntrees=8, max_depth=20, seed=1).train(
         x=["x1", "x2", "g"], y="y", training_frame=fr)
@@ -47,13 +41,9 @@ def test_depth20_drf_no_host_fallback(cl, monkeypatch):
     assert np.all((p >= 0) & (p <= 1))
 
 
-def test_depth20_drf_multinomial_device(cl, monkeypatch):
-    from h2o3_tpu.models.tree import host_grow
+def test_depth20_drf_multinomial_device(cl):
     from h2o3_tpu.models.tree.drf import DRF
 
-    monkeypatch.setattr(host_grow, "grow_tree_host",
-                        lambda *a, **k: (_ for _ in ()).throw(
-                            AssertionError("host fallback")))
     rng = np.random.default_rng(2)
     n = 1200
     x1, x2 = rng.normal(size=n), rng.normal(size=n)
@@ -87,9 +77,9 @@ def test_deep_gbm_beats_shallow_underfit(cl):
 def test_frontier_cap_binds_gracefully(cl, monkeypatch):
     """With a tiny frontier cap the grower keeps the best-gain splits and
     still produces a working model (greedy-best under the width budget)."""
-    monkeypatch.setenv("H2O_TPU_FRONTIER_CAP", "16")
     from h2o3_tpu.models.tree import device_tree
 
+    monkeypatch.setattr(device_tree, "DEFAULT_FRONTIER_CAP", 16)
     device_tree._grow_fn.cache_clear()
     device_tree._apply_fn.cache_clear()
     try:
@@ -99,7 +89,7 @@ def test_frontier_cap_binds_gracefully(cl, monkeypatch):
         m = GBM(ntrees=5, max_depth=8, seed=1).train(
             x=["x1", "x2", "g"], y="y", training_frame=fr)
         assert m._output.training_metrics.auc > 0.7
-        widths = device_tree.level_widths(8, 16)
+        widths = device_tree.level_widths(8)
         assert max(widths) == 16                   # cap actually bound
     finally:
         device_tree._grow_fn.cache_clear()

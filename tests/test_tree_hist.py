@@ -1,0 +1,266 @@
+"""The level histogram of the one-tree program (device_tree.py): each
+lowering alone against a float64 loop, the rule that picks one from a
+level's width, and a forest grown under either.
+
+A third lowering is a third function of the same signature: add it to
+LOWERINGS with the rounding its operands allow and the same cases judge it.
+"""
+
+import json
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from h2o3_tpu.core.frame import Column, Frame
+from h2o3_tpu.models.tree import device_tree
+
+ROOT = Path(__file__).resolve().parent.parent
+
+# lowering -> the share of a term's magnitude its sum may be off by.
+# hist_matmul rounds each (w, w·y, w·y²) term to bf16 (8 significand bits,
+# to nearest: 2^-8 of the term; the bin one-hot is exact) and accumulates in
+# f32;
+# hist_scatter makes and adds the terms in f32 (2^-24 a product and an add,
+# over at most a thousand rows a cell: 2^-13 is loose, and the matmul gets
+# it on top for the same f32 steps). _truth returns the sums of the terms'
+# magnitudes beside the sums.
+LOWERINGS = {"matmul": (device_tree.hist_matmul, 2.0 ** -8 + 2.0 ** -13),
+             "scatter": (device_tree.hist_scatter, 2.0 ** -13)}
+
+
+def _case(seed, n, F, maxB, S, *, dead_frac=0.15, zero_w_frac=0.1,
+          ragged_bins=False):
+    """Synthetic rows mixing the grower's edge shapes: a reserved NA bin
+    (the last bin of every feature, overweighted), dead rows (node = -1:
+    routed to a leaf), zero-weight live rows (sampled out), and optionally
+    ragged per-feature bin counts (categorical cardinalities)."""
+    rng = np.random.default_rng(seed)
+    if ragged_bins:
+        nbins = rng.integers(2, maxB + 1, F).astype(np.int64)
+    else:
+        nbins = np.full(F, maxB, np.int64)
+    binned = np.stack([rng.integers(0, nbins[f], n) for f in range(F)],
+                      axis=1).astype(np.int32)
+    na_rows = rng.random(n) < 0.2
+    binned[na_rows] = (nbins - 1)[None, :]
+    node = rng.integers(0, S, n).astype(np.int32)
+    node[rng.random(n) < dead_frac] = -1
+    w = rng.random(n).astype(np.float32) + 0.25
+    w[rng.random(n) < zero_w_frac] = 0.0
+    y = rng.standard_normal(n).astype(np.float32)
+    return binned, node, w, y, tuple(int(b) for b in nbins)
+
+
+def _truth(binned, node, w, y, S, maxB):
+    """(S, F, maxB, 3) float64 sums of (w, w·y, w·y²) over live rows, one
+    row and feature at a time, and the sums of the terms' magnitudes."""
+    F = binned.shape[1]
+    out = np.zeros((S, F, maxB, 3), np.float64)
+    mag = np.zeros_like(out)
+    for r in range(binned.shape[0]):
+        if node[r] < 0 or w[r] == 0.0:
+            continue
+        wr, yr = float(w[r]), float(y[r])
+        t = np.array([wr, wr * yr, wr * yr * yr])
+        for f in range(F):
+            out[node[r], f, binned[r, f]] += t
+            mag[node[r], f, binned[r, f]] += np.abs(t)
+    return out, mag
+
+
+def _run(cl, lowering, binned, node, w, y, S, nbins, blk):
+    """One lowering alone under shard_map over the cluster's mesh, the
+    rows laid out as tree_program lays them: every shard a multiple of blk
+    rows, the padding dead."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from h2o3_tpu.compat import shard_map
+
+    fn, _ = LOWERINGS[lowering]
+    n_dev = cl.mesh.devices.size
+    blocks = -(-len(node) // (n_dev * blk))       # row blocks a shard
+    pad = n_dev * blocks * blk - len(node)
+    live = np.pad(node >= 0, (0, pad))
+    args = (np.pad(binned, ((0, pad), (0, 0))),
+            np.pad(np.maximum(node, 0), (0, pad)), live,
+            np.pad(w, (0, pad)), np.pad(y, (0, pad)))
+
+    def local(binned, row_node, live, w, y):
+        return fn(binned, row_node, live, w, y, S, nbins=nbins,
+                  maxB=max(nbins), blk=blk)
+
+    run = jax.jit(shard_map(
+        local, mesh=cl.mesh,
+        in_specs=(P("rows", None), P("rows"), P("rows"), P("rows"),
+                  P("rows")),
+        out_specs=P()))
+    return np.asarray(run(*(jnp.asarray(a) for a in args)), np.float64)
+
+
+@pytest.mark.parametrize("lowering", sorted(LOWERINGS))
+@pytest.mark.parametrize("seed,n,F,maxB,S,blk,ragged", [
+    (0, 1000, 5, 8, 12, 64, False),    # ragged rows: 125 a shard, blocks of 64
+    (1, 512, 3, 6, 7, 32, True),       # ragged bins, two blocks a shard
+    (2, 768, 8, 16, 16, 32, False),    # three blocks a shard, aligned
+    (3, 300, 2, 4, 3, 16, True),       # rows that do not divide by the mesh
+    (4, 256, 1, 32, 5, 32, False),     # single feature, wide bins
+])
+def test_hist_against_f64_truth(cl, lowering, seed, n, F, maxB, S, blk,
+                                ragged):
+    binned, node, w, y, nbins = _case(seed, n, F, maxB, S,
+                                      ragged_bins=ragged)
+    got = _run(cl, lowering, binned, node, w, y, S, nbins, blk)
+    want, mag = _truth(binned, node, w, y, S, max(nbins))
+    assert got.shape == want.shape
+    slack = LOWERINGS[lowering][1] * mag + 1e-6
+    worst = np.abs(got - want) - slack
+    assert np.all(worst <= 0), \
+        f"{lowering}: off by {worst.max():.3g} beyond its rounding at " \
+        f"{np.argwhere(worst > 0)[:5]}"
+    # a lane no row can fall in (bin >= nbins[f]) holds an exact zero
+    for f, nb in enumerate(nbins):
+        assert np.all(got[:, f, nb:] == 0)
+
+
+@pytest.mark.parametrize("lowering", sorted(LOWERINGS))
+def test_dead_and_zero_weight_rows_drop(cl, lowering):
+    n, F, maxB, S, blk = 256, 3, 8, 4, 16
+    binned, node, w, y, nbins = _case(12, n, F, maxB, S)
+    dead = (node < 0) | (w == 0.0)
+    out = _run(cl, lowering, binned, node, w, y, S, nbins, blk)
+    # every live row lands once a feature, with its weight: the total is
+    # the live rows' alone
+    live_w = float(w[~dead].astype(np.float64).sum())
+    assert out[..., 0].sum() == pytest.approx(
+        F * live_w, rel=LOWERINGS[lowering][1])
+    # all rows dead -> an all-zero histogram
+    out0 = _run(cl, lowering, binned, np.full(n, -1, np.int32), w, y, S,
+                nbins, blk)
+    assert np.all(out0 == 0)
+
+
+def _train_frame(seed=7, n=600):
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal(n)
+    g = np.array(["a", "b", "c"], object)[rng.integers(0, 3, n)]
+    yv = np.where(rng.random(n) < 1 / (1 + np.exp(-(2 * x + (g == "a")))),
+                  "Y", "N")
+    fr = Frame()
+    fr.add("x", Column.from_numpy(x))
+    fr.add("g", Column.from_numpy(g, ctype="enum"))
+    fr.add("y", Column.from_numpy(yv, ctype="enum"))
+    return fr
+
+
+def _train_predict(fr, **gbm_kw):
+    from h2o3_tpu.models.tree.gbm import GBM
+
+    kw = dict(ntrees=4, max_depth=3, seed=3)
+    kw.update(gbm_kw)
+    m = GBM(**kw).train(y="y", training_frame=fr)
+    return (m.predict(fr).col("Y").to_numpy(),
+            float(m._output.training_metrics.auc))
+
+
+def test_gbm_forest_same_under_either_lowering(cl, monkeypatch):
+    """The two lowerings are interchangeable: a forest grown with every
+    level on the scatter-add predicts what the matmul's does, to
+    accumulation-order tolerance. The scatter is forced where the rule
+    reads it, and the compiled programs of either side are dropped so that
+    neither serves the other."""
+    fr = _train_frame()
+    device_tree._grow_fn.cache_clear()
+    try:
+        assert device_tree.hist_lowering(8) is device_tree.hist_matmul
+        p_mm, auc_mm = _train_predict(fr)
+        monkeypatch.setattr(device_tree, "MATMUL_S_LIMIT", 0)
+        device_tree._grow_fn.cache_clear()
+        assert device_tree.hist_lowering(1) is device_tree.hist_scatter
+        p_sc, auc_sc = _train_predict(fr)
+    finally:
+        device_tree._grow_fn.cache_clear()
+    assert auc_sc == pytest.approx(auc_mm, abs=1e-6)
+    np.testing.assert_allclose(p_sc, p_mm, atol=1e-6)
+
+
+def test_cold_train_lands_tree_rows_warm_is_free(cl):
+    """Every train-triggered compile lands under family `tree`; a warm
+    re-train with identical params compiles ZERO new programs."""
+    from h2o3_tpu.obs import compiles
+
+    # unique geometry so this test always starts cold in-process:
+    # depth 4 + n=731 is used nowhere else in the suite
+    fr = _train_frame(seed=41, n=731)
+
+    def tree_rows():
+        return [r for r in compiles.ledger_rows()
+                if r.get("family") == "tree" and r["cache"] == "compile"]
+
+    def fresh(prior):
+        # the ledger deque is bounded (maxlen=512): under saturation
+        # appends drop rows off the FRONT, so a count-based slice
+        # would miss new rows — detect them by object identity
+        prior_ids = {id(r) for r in prior}
+        return [r for r in tree_rows() if id(r) not in prior_ids]
+
+    before = tree_rows()
+    _train_predict(fr, ntrees=2, max_depth=4, seed=5)
+    cold = fresh(before)
+    assert cold, "a cold train must compile tree-family programs"
+    programs = {r.get("program") for r in cold}
+    assert any(p and p.startswith("tree_grow") for p in programs), programs
+
+    hits_before = compiles.family_table().get("tree", {}) \
+                                         .get("hits_memory", 0)
+    mid = tree_rows()
+    _train_predict(fr, ntrees=2, max_depth=4, seed=5)   # identical
+    assert not fresh(mid), \
+        "warm identical re-train must compile nothing"
+    hits_after = compiles.family_table()["tree"]["hits_memory"]
+    assert hits_after > hits_before, \
+        "warm re-train must serve from the memory tier"
+
+
+def test_tree_family_is_declared():
+    from h2o3_tpu.obs import compiles
+
+    assert "tree" in compiles.FAMILIES
+
+
+def _config_shape(name):
+    """(max_depth, F, maxB) of a benchmark configuration, read from its
+    file: a numeric column has nbins bins, an enum column its levels up to
+    nbins_cats, and every column one more for the missing."""
+    cfg = json.loads((ROOT / "bench" / "configs" / f"{name}.json")
+                     .read_text(encoding="utf-8"))
+    p = cfg["params"]
+    cols = cfg.get("columns") or [{"type": "real"}] * cfg["features"]
+    maxB = 1 + max(p["nbins"] if c["type"] != "enum"
+                   else min(c["levels"], p.get("nbins_cats", 1024))
+                   for c in cols)
+    return p["max_depth"], len(cols), maxB
+
+
+@pytest.mark.parametrize("name,scatter_from", [
+    ("higgs_gbm_d5", None),
+    ("airline_gbm_d10", None),
+    ("drf_d20", 11),
+])
+def test_lowering_rule_from_shape(name, scatter_from):
+    """Both benchmark configurations build every level's histogram with
+    the matmul; only a forest deeper than 10 reaches the scatter-add, from
+    the first level wider than MATMUL_S_LIMIT on."""
+    # drf_d20: H2O-3's DRF defaults (max_depth=20, nbins=20) at HIGGS width
+    depth, F, maxB = (20, 28, 21) if name == "drf_d20" \
+        else _config_shape(name)
+    widths = device_tree.level_widths(depth,
+                                      device_tree.frontier_cap(F, maxB))
+    split = depth if scatter_from is None else scatter_from
+    assert [device_tree.hist_lowering(S) for S in widths[:depth]] == \
+        [device_tree.hist_matmul] * split \
+        + [device_tree.hist_scatter] * (depth - split)
+    assert widths[split - 1] <= device_tree.MATMUL_S_LIMIT
+    assert split == depth or widths[split] > device_tree.MATMUL_S_LIMIT
