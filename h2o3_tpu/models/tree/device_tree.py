@@ -16,6 +16,18 @@ TPU-native design:
   to bf16 (the one-hot is exact in bf16; the MXU accumulates in f32 via
   preferred_element_type), halving HBM traffic — the bandwidth, not the
   FLOPs, is the roofline here. Blocked over row chunks.
+  Both operands are built with the rows on lanes and nothing placed at an
+  offset inside a tile: a TPU keeps the (N, F) bin matrix rows-minor, so a
+  (blk, lanes) one-hot of 21-lane pieces laid side by side was a
+  concatenation at sublane offsets 21, 42, … of 28 materialised pieces —
+  257 µs a block of 32,768 rows x 588 lanes, 79% of the histogram and 57%
+  of a depth-5 job on a v5e. hist_matmul now broadcasts a feature's row of
+  bins over that feature's lanes, padded to whole 8-sublane tiles, against
+  a static per-lane bin, and builds the values by one compare over 3S
+  lanes in (3, S) order: a whole block, operands and dot, fell from 327 to
+  47 µs there and from about 250 to 53 µs on airline's 880 ragged lanes at
+  S <= 16, about half of what is left the MXU's own pass over blk/128 x
+  lanes tiles; the sums are bit for bit what they were.
 - The split search runs on device, vectorized over (node, feature, bin):
   categorical bins are ordered by per-node mean response (argsort) — the
   sorted-subset optimum for squared loss — numeric bins keep
@@ -281,6 +293,9 @@ def route_forms(max_depth: int, F: int, maxB: int) -> Tuple[str, ...]:
 # the live rows of each (slot, feature, bin). nbins (F,) are the bins each
 # feature has, maxB their maximum.
 
+_SUBLANES = 8               # sublanes of a 32-bit TPU tile
+
+
 def hist_matmul(binned, row_node, live, w, y, S: int, *, nbins: tuple,
                 maxB: int, blk: int):
     """(S, F, maxB, 3) via blocked bf16 one-hot matmul + psum — the
@@ -288,39 +303,58 @@ def hist_matmul(binned, row_node, live, w, y, S: int, *, nbins: tuple,
     one-hot carries the bins that exist, nbins[f] lanes a feature
     (BinSpec.offsets' layout), not maxB: with a 300-level enum beside a
     7-level one two thirds of F·maxB lanes would be bins no row can
-    fall in. The sums are laid out to (F, maxB) afterwards, zeros in
-    the lanes a feature does not have."""
+    fall in.
+
+    Both operands are built with the rows on the lane axis (the layout
+    the bin matrix has on a TPU) and nothing placed at an offset inside a
+    tile (module docstring): the bin one-hot (L, blk) is a feature's row of
+    bins broadcast over that feature's lanes, each feature's lanes rounded
+    up to whole sublane tiles, against the static bin of each lane (-1 in
+    the padding: no row has it); the values (3S, blk) are one compare of
+    the row's slot against the static slot of each of 3S lanes, column
+    order (3, S). The zeros and ones are the same as any other build's, so
+    the sums are bit for bit what the concatenation of jax.nn.one_hot a
+    feature gave (tests/test_tree_hist.py holds that build). The sums are
+    laid out to (S, F, maxB, 3) afterwards, zeros in the lanes a feature
+    does not have."""
     import jax
     import jax.numpy as jnp
 
     F = len(nbins)
-    lanes = sum(nbins)          # the bins that exist
+    widths = [-(-nb // _SUBLANES) * _SUBLANES for nb in nbins]
+    offs = np.cumsum([0] + widths[:-1])
+    lane_bin = [np.where(np.arange(wd) < nb, np.arange(wd), -1)[:, None]
+                .astype(np.int32) for nb, wd in zip(nbins, widths)]
+    lane_slot = np.tile(np.arange(S, dtype=np.int32), 3)[:, None]
+    lane_val = np.repeat(np.arange(3), S)[:, None]
+    binsT = binned.T                                         # (F, n)
 
     def body(i, acc):
-        sl = lambda a: jax.lax.dynamic_slice_in_dim(a, i * blk, blk, 0)
-        bb = sl(binned)
+        sl = lambda a: jax.lax.dynamic_slice_in_dim(a, i * blk, blk,
+                                                    a.ndim - 1)
+        bb = sl(binsT).astype(jnp.int32)
         nodeb = sl(row_node)
-        liveb = sl(live)
-        wb = jnp.where(liveb, sl(w), 0.0)
+        wb = jnp.where(sl(live), sl(w), 0.0)
         yb = sl(y)
-        Ob = jnp.concatenate(
-            [jax.nn.one_hot(bb[:, f], nbins[f], dtype=jnp.bfloat16)
-             for f in range(F)], axis=1)                     # (blk, lanes)
-        node_oh = jax.nn.one_hot(nodeb, S, dtype=jnp.float32)
-        vals = jnp.stack([wb, wb * yb, wb * yb * yb], axis=-1)
-        V = (node_oh[:, :, None] * vals[:, None, :]).reshape(blk, S * 3)
-        return acc + jnp.dot(Ob.T, V.astype(jnp.bfloat16),
-                             preferred_element_type=jnp.float32)
+        wyb = wb * yb
+        Ob = jnp.concatenate([bb[f][None, :] == lane_bin[f]
+                              for f in range(F)])            # (L, blk)
+        val = jnp.where(lane_val == 0, wb[None, :],
+                        jnp.where(lane_val == 1, wyb[None, :],
+                                  (wyb * yb)[None, :]))
+        V = jnp.where(nodeb[None, :] == lane_slot, val, 0.0)  # (3S, blk)
+        return acc + jax.lax.dot_general(
+            Ob.astype(jnp.bfloat16), V.astype(jnp.bfloat16),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
-    acc0 = _compat_pcast(jnp.zeros((lanes, S * 3), jnp.float32),
+    acc0 = _compat_pcast(jnp.zeros((sum(widths), 3 * S), jnp.float32),
                          ("rows",), to="varying")
     acc = jax.lax.fori_loop(0, binned.shape[0] // blk, body, acc0)
     acc = jax.lax.psum(acc, "rows")
-    if lanes != F * maxB:
-        acc = jnp.concatenate(
-            [jnp.pad(acc[o:o + nb], ((0, maxB - nb), (0, 0)))
-             for o, nb in zip(np.cumsum((0,) + nbins[:-1]), nbins)])
-    return acc.reshape(F, maxB, S, 3).transpose(2, 0, 1, 3)
+    acc = jnp.concatenate(
+        [jnp.pad(acc[o:o + nb], ((0, maxB - nb), (0, 0)))
+         for o, nb in zip(offs, nbins)])
+    return acc.reshape(F, maxB, 3, S).transpose(3, 0, 1, 2)
 
 
 def hist_scatter(binned, row_node, live, w, y, S: int, *, nbins: tuple,
@@ -510,6 +544,29 @@ def _count_route(forms: Tuple[str, ...]) -> None:
     tracing.add_attrs(route_levels=len(forms), route_gather_levels=gathered)
 
 
+def hist_forms(max_depth: int, F: int, maxB: int) -> Tuple[str, ...]:
+    """The lowering of each histogram level of a tree (`matmul` |
+    `scatter`: hist_lowering's rule, from the level's width); the last
+    level builds none and is not listed."""
+    widths = level_widths(max_depth, frontier_cap(F, maxB))
+    return tuple("matmul" if hist_lowering(S) is hist_matmul else "scatter"
+                 for S in widths[:max_depth])
+
+
+def _count_hist(forms: Tuple[str, ...]) -> None:
+    """h2o3_tree_hist_levels_total{lowering} and the `trees` span's
+    `hist_matmul_levels` / `hist_scatter_levels`, counted like
+    _count_route: host arithmetic on static widths, no device op."""
+    from h2o3_tpu.obs import metrics, tracing
+
+    scattered = forms.count("scatter")
+    metrics.inc("h2o3_tree_hist_levels_total", len(forms) - scattered,
+                lowering="matmul")
+    metrics.inc("h2o3_tree_hist_levels_total", scattered, lowering="scatter")
+    tracing.add_attrs(hist_matmul_levels=len(forms) - scattered,
+                      hist_scatter_levels=scattered)
+
+
 def _pick_blk(n_shard: int, lanes: int) -> int:
     """Row-block size: keep the per-block (blk, lanes) bf16 one-hot under
     ~64 MB."""
@@ -556,6 +613,7 @@ def grow_tree_device(binned, w, y, spec, *, max_depth: int, min_rows: float,
                   float(min_split_improvement), has_masks, mesh, n_shard, blk,
                   frontier_cap(F, maxB))
     _count_route(route_forms(int(max_depth), F, maxB))
+    _count_hist(hist_forms(int(max_depth), F, maxB))
     w = w.astype(jnp.float32)
     y = y.astype(jnp.float32)
     if num is None:

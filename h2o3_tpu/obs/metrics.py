@@ -468,6 +468,11 @@ def _install_default_metrics() -> None:
               "(max_depth a tree: the last level reads no table), by form: "
               "select | gather = how a TPU reads the level's packed "
               "left_table words; the row's bin is always by select")
+    r.counter("h2o3_tree_hist_levels_total",
+              "histogram levels of the trees dispatched to the tree program "
+              "(max_depth a tree: the last level builds none), by lowering: "
+              "matmul | scatter = hist_lowering's rule from the level's "
+              "width")
     r.counter("h2o3_forest_walk_total",
               "dispatches of a forest-walk program (predict_binned, "
               "leaf_index, the scoring session's fused programs), by form: "

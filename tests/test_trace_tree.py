@@ -123,7 +123,9 @@ def test_gbm_train_trace_holds_the_stages(rest):
         ["bin", "trees", "assemble", "metrics", "metrics"]
     assert [c["attrs"].get("frame") for c in stages[3:]] == ["train", "valid"]
     assert stages[1]["attrs"] == {"ntrees": 3, "rows": 1200, "max_depth": 3,
-                                  "route_levels": 9, "route_gather_levels": 0}
+                                  "route_levels": 9, "route_gather_levels": 0,
+                                  "hist_matmul_levels": 9,
+                                  "hist_scatter_levels": 0}
     for c in stages:
         assert c["parent_id"] == job["span_id"]
         assert job["start_ms"] <= c["start_ms"] <= c["end_ms"] \

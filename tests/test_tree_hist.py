@@ -29,15 +29,26 @@ LOWERINGS = {"matmul": (device_tree.hist_matmul, 2.0 ** -8 + 2.0 ** -13),
              "scatter": (device_tree.hist_scatter, 2.0 ** -13)}
 
 
+# the two benchmark configurations' lane layouts: higgs_gbm_d5 is 28
+# features of 21 bins; airline_gbm_d10 is 6 enum + 2 numeric columns, every
+# count one more than its levels or nbins for the missing
+AIRLINE_NBINS = (13, 32, 8, 23, 301, 301, 101, 101)
+
+
 def _case(seed, n, F, maxB, S, *, dead_frac=0.15, zero_w_frac=0.1,
           ragged_bins=False):
     """Synthetic rows mixing the grower's edge shapes: a reserved NA bin
     (the last bin of every feature, overweighted), dead rows (node = -1:
     routed to a leaf), zero-weight live rows (sampled out), and optionally
-    ragged per-feature bin counts (categorical cardinalities)."""
+    ragged per-feature bin counts (categorical cardinalities): drawn, or
+    the tuple given."""
     rng = np.random.default_rng(seed)
     if ragged_bins:
-        nbins = rng.integers(2, maxB + 1, F).astype(np.int64)
+        if ragged_bins is True:
+            nbins = rng.integers(2, maxB + 1, F).astype(np.int64)
+        else:
+            nbins = np.asarray(ragged_bins, np.int64)
+            assert len(nbins) == F and nbins.max() == maxB
     else:
         nbins = np.full(F, maxB, np.int64)
     binned = np.stack([rng.integers(0, nbins[f], n) for f in range(F)],
@@ -107,11 +118,16 @@ def _run(cl, lowering, binned, node, w, y, S, nbins, blk):
     (2, 768, 8, 16, 16, 32, False),    # three blocks a shard, aligned
     (3, 300, 2, 4, 3, 16, True),       # rows that do not divide by the mesh
     (4, 256, 1, 32, 5, 32, False),     # single feature, wide bins
+    (5, 640, 28, 21, 16, 32, False),   # higgs_gbm_d5's lanes, its widest level
+    (6, 512, 8, 301, 1, 32, AIRLINE_NBINS),    # airline_gbm_d10's lanes, bins
+    (7, 512, 8, 301, 16, 32, AIRLINE_NBINS),   # past 255 in the data, from the
+    (8, 512, 8, 301, 512, 32, AIRLINE_NBINS),  # root to its widest level
 ])
 def test_hist_against_f64_truth(cl, lowering, seed, n, F, maxB, S, blk,
                                 ragged):
     binned, node, w, y, nbins = _case(seed, n, F, maxB, S,
                                       ragged_bins=ragged)
+    assert maxB <= 256 or (binned >= 256).any()
     got = _run(cl, lowering, binned, node, w, y, S, nbins, blk)
     want, mag = _truth(binned, node, w, y, S, max(nbins))
     assert got.shape == want.shape
@@ -123,6 +139,62 @@ def test_hist_against_f64_truth(cl, lowering, seed, n, F, maxB, S, blk,
     # a lane no row can fall in (bin >= nbins[f]) holds an exact zero
     for f, nb in enumerate(nbins):
         assert np.all(got[:, f, nb:] == 0)
+
+
+def _matmul_on_concatenated_one_hots(binned, row_node, live, w, y, S, *,
+                                     nbins, maxB, blk):
+    """hist_matmul as it built its operands until PR 31, kept here as the
+    plain reference: a jax.nn.one_hot a feature laid side by side into
+    (blk, lanes), the (w, w·y, w·y²) triples crossed with the slot one-hot
+    and interleaved into (blk, 3S), one dot a block. Slow on a TPU (every
+    piece lands at a lane offset inside a tile); exact anywhere."""
+    import jax
+    import jax.numpy as jnp
+
+    F = len(nbins)
+    acc = jnp.zeros((sum(nbins), S * 3), jnp.float32)
+    for i in range(binned.shape[0] // blk):
+        rows = slice(i * blk, (i + 1) * blk)
+        wb = jnp.where(live[rows], w[rows], 0.0)
+        yb = y[rows]
+        Ob = jnp.concatenate(
+            [jax.nn.one_hot(binned[rows, f], nbins[f], dtype=jnp.bfloat16)
+             for f in range(F)], axis=1)
+        node_oh = jax.nn.one_hot(row_node[rows], S, dtype=jnp.float32)
+        vals = jnp.stack([wb, wb * yb, wb * yb * yb], axis=-1)
+        V = (node_oh[:, :, None] * vals[:, None, :]).reshape(blk, S * 3)
+        acc = acc + jnp.dot(Ob.T, V.astype(jnp.bfloat16),
+                            preferred_element_type=jnp.float32)
+    acc = jax.lax.psum(acc, "rows")
+    acc = jnp.concatenate(
+        [jnp.pad(acc[o:o + nb], ((0, maxB - nb), (0, 0)))
+         for o, nb in zip(np.cumsum((0,) + nbins[:-1]), nbins)])
+    return acc.reshape(F, maxB, S, 3).transpose(2, 0, 1, 3)
+
+
+@pytest.mark.parametrize("seed,n,F,maxB,S,blk,ragged,dtype", [
+    (20, 768, 28, 21, 16, 32, False, "uint8"),     # higgs_gbm_d5's lanes
+    (20, 768, 28, 21, 1, 64, False, "int32"),
+    (21, 512, 6, 40, 5, 32, True, "uint8"),        # ragged, odd widths
+    (21, 512, 6, 40, 5, 32, True, "int16"),
+    (22, 512, 8, 301, 16, 32, AIRLINE_NBINS, "int16"),  # airline_gbm_d10's
+    (22, 512, 8, 301, 3, 32, AIRLINE_NBINS, "int32"),
+])
+def test_matmul_is_bit_for_bit_the_concatenated_one_hots(
+        cl, monkeypatch, seed, n, F, maxB, S, blk, ragged, dtype):
+    """However hist_matmul lays zeros and ones into lanes, they are the
+    zeros and ones of the one-hot a feature: on the same rows it returns
+    the very array a dot on the concatenated one-hots returns, in every
+    dtype bin_columns packs bins in."""
+    binned, node, w, y, nbins = _case(seed, n, F, maxB, S,
+                                      ragged_bins=ragged)
+    binned = binned.astype(dtype)
+    got = _run(cl, "matmul", binned, node, w, y, S, nbins, blk)
+    monkeypatch.setitem(LOWERINGS, "matmul",
+                        (_matmul_on_concatenated_one_hots, 0.0))
+    want = _run(cl, "matmul", binned, node, w, y, S, nbins, blk)
+    assert got.shape == want.shape and got.any()
+    assert np.array_equal(got, want)
 
 
 @pytest.mark.parametrize("lowering", sorted(LOWERINGS))

@@ -224,10 +224,14 @@ def test_a_whole_tree_is_the_tree_the_row_gather_form_grows(name, cl,
     assert np.array_equal(applied(), values[row_leaf])
 
 
+def _by_label(counter, label):
+    return {s["labels"].get(label): s["value"] for s in
+            metrics.REGISTRY.get(counter).snapshot()["samples"]
+            if s["labels"]}
+
+
 def _routed():
-    return {s["labels"].get("form"): s["value"] for s in
-            metrics.REGISTRY.get("h2o3_tree_route_levels_total").snapshot()[
-                "samples"] if s["labels"]}
+    return _by_label("h2o3_tree_route_levels_total", "form")
 
 
 def _moved(before):
@@ -275,6 +279,48 @@ def test_counter_and_trees_span_name_the_forms_on_the_host():
     assert trees["attrs"]["route_gather_levels"] == 2
 
 
+def _hist_levels():
+    return _by_label("h2o3_tree_hist_levels_total", "lowering")
+
+
+@pytest.mark.parametrize("depth,F,maxB,matmul,scatter", [
+    (5, 28, 21, 5, 0),       # higgs_gbm_d5: widest level 16 slots
+    (10, 8, 301, 10, 0),     # airline_gbm_d10: widest level 512 slots
+    (20, 28, 21, 11, 9),     # DRF's default depth: level 11 is 2,048 wide
+])
+def test_hist_levels_are_counted_by_lowering_on_the_host(depth, F, maxB,
+                                                         matmul, scatter):
+    """h2o3_tree_hist_levels_total{lowering} and the `trees` span's
+    hist_matmul_levels / hist_scatter_levels split a tree's levels where
+    hist_lowering does, at MATMUL_S_LIMIT slots, from static widths:
+    nothing crosses to or from a device, nothing compiles."""
+    import jax
+
+    forms = device_tree.hist_forms(depth, F, maxB)
+    assert forms == ("matmul",) * matmul + ("scatter",) * scatter
+    widths = device_tree.level_widths(depth,
+                                      device_tree.frontier_cap(F, maxB))
+    assert [S <= device_tree.MATMUL_S_LIMIT for S in widths[:depth]] == \
+        [f == "matmul" for f in forms]
+    before = _hist_levels()
+    compiles = metrics.REGISTRY.get("h2o3_backend_compiles_total").snapshot()
+    with tracing.root_span("ingress", path="/3/ModelBuilders/gbm") as root:
+        with tracing.span("trees"):
+            with jax.transfer_guard("disallow"):
+                for _ in range(2):                   # two trees of a job
+                    device_tree._count_hist(forms)
+    now = _hist_levels()
+    assert {k: now[k] - before.get(k, 0) for k in now} == \
+        {"matmul": 2 * matmul, "scatter": 2 * scatter}
+    assert metrics.REGISTRY.get(
+        "h2o3_backend_compiles_total").snapshot() == compiles
+    trees, = [s for s in tracing.get_trace(root.span["trace_id"],
+                                           include_remote=False)
+              if s["name"] == "trees"]
+    assert trees["attrs"]["hist_matmul_levels"] == 2 * matmul
+    assert trees["attrs"]["hist_scatter_levels"] == 2 * scatter
+
+
 def test_a_fit_counts_its_trees_levels_and_adds_no_compile_or_change(cl):
     """A depth-3 GBM of 3 trees: 9 routing levels by select on the `trees`
     span and the counter; the same fit again compiles nothing, and the
@@ -300,15 +346,19 @@ def test_a_fit_counts_its_trees_levels_and_adds_no_compile_or_change(cl):
             "h2o3_backend_compiles_total").snapshot()["samples"])
 
     first = fit()
-    before, compiled = _routed(), compiles()
+    before, hist_before, compiled = _routed(), _hist_levels(), compiles()
     with tracing.root_span("ingress", path="/3/ModelBuilders/gbm") as root:
         second = fit()
     assert _moved(before) == {"select": 9}
+    assert {k: v - hist_before.get(k, 0)
+            for k, v in _hist_levels().items()} == {"matmul": 9, "scatter": 0}
     assert compiles() == compiled
     trees, = [s for s in tracing.get_trace(root.span["trace_id"],
                                            include_remote=False)
               if s["name"] == "trees"]
     assert trees["attrs"]["route_levels"] == 9
     assert trees["attrs"]["route_gather_levels"] == 0
+    assert trees["attrs"]["hist_matmul_levels"] == 9
+    assert trees["attrs"]["hist_scatter_levels"] == 0
     for a, b in zip(first.forest.arrays(), second.forest.arrays()):
         assert np.array_equal(np.asarray(a), np.asarray(b), equal_nan=True)
