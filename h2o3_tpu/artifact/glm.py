@@ -93,7 +93,7 @@ def lower_glm_bucket(bucket: int, model):
     """Lowered (not yet compiled) GLM scoring program for one row bucket.
 
     This lowers ``models/glm._glm_predict`` ITSELF — the exact jit
-    program in-process serving runs (expand + intercept matmul + linkinv,
+    program in-process serving runs (eta from codes and coefficients + linkinv,
     with the DataInfo moments closed over as program constants) — so the
     artifact's outputs are bitwise-identical to ``GLMModel.predict`` by
     construction, not by re-implementation (the program is batch-size
@@ -113,7 +113,7 @@ def lower_glm_bucket(bucket: int, model):
               for _ in d.num_names)
     beta_s = jax.ShapeDtypeStruct(np.asarray(model.beta).shape, np.float32)
     # offset rides as the same concrete 0.0 scalar _predict_raw passes
-    return _glm_predict.lower(structs, beta_s, 0.0, expand=d.expand,
+    return _glm_predict.lower(structs, beta_s, 0.0, dinfo=d,
                               linkname=model.linkname,
                               link_power=model.link_power,
                               nclasses=K if K > 2 else 1)

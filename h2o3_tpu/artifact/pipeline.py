@@ -258,7 +258,7 @@ def _scorer_fn(cap, inner: str, model):
             else:
                 arrs.append(cols[f[1]] if f[0] == "L" else ev(f))
         return _glm_predict(
-            tuple(arrs), beta_c, offset, expand=d.expand,
+            tuple(arrs), beta_c, offset, dinfo=d,
             linkname=model.linkname,
             link_power=(model.link_power if K <= 2 else 0.0),
             nclasses=K if K > 2 else 1)

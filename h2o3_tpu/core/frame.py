@@ -109,7 +109,8 @@ class Column:
     """
 
     __slots__ = ("_data", "_evicted", "_loader", "_touch", "ctype", "domain",
-                 "host_data", "nrows", "_rollups", "_chunks", "_token")
+                 "host_data", "nrows", "_rollups", "_mode", "_chunks",
+                 "_token")
 
     def __init__(self, data, ctype: str, nrows: int,
                  domain: Optional[List[str]] = None,
@@ -123,6 +124,7 @@ class Column:
         self.host_data = host_data
         self.nrows = int(nrows)
         self._rollups = None
+        self._mode = None
         # minted eagerly: a lazy check-then-set would race under the
         # threaded REST server and hand two threads different tokens
         self._token = next(_COLUMN_TOKENS)
@@ -341,6 +343,16 @@ class Column:
 
             self._rollups = compute_rollups(self)
         return self._rollups
+
+    @property
+    def mode(self) -> int:
+        """Most frequent level of a categorical column (what mode imputation
+        fills with), computed once a column like the rollups."""
+        if self._mode is None:
+            from h2o3_tpu.ops.rollups import compute_mode
+
+            self._mode = compute_mode(self)
+        return self._mode
 
     def min(self):
         return self.rollups.min

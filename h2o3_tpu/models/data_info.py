@@ -11,36 +11,144 @@ raw column slices into a dense (rows, p) float32 block: one-hot via
 jax.nn.one_hot (fused into the following matmul by XLA; the MXU eats dense
 one-hots far better than a CPU eats sparse rows), standardized numerics,
 mean/mode-imputed NAs, pad rows zero-weighted via the returned weight vector.
+
+At a few hundred levels that block is the wall (2,676 B a row at 668
+columns), so GLM's IRLS and its scoring do not call `expand`: `design_rows`
+builds the same zeros and ones for a block of rows with the rows on lanes,
+straight from the codes, and `linear_predictor` reads a row's coefficients
+without any design at all. `DesignLayout` is the static shape they are
+compiled for; the moments ride as arrays.
 """
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
 from h2o3_tpu.core.frame import Column, Frame, T_CAT
 
 
-def _device_mode(col: Column) -> int:
-    """Most frequent level of a categorical column, via a device bincount
-    (DataInfo.imputeMissing mode imputation)."""
-    import functools
+_SUBLANES = 8               # sublanes of a 32-bit TPU tile
 
-    import jax
 
-    card = max(col.cardinality, 1)
+class DesignLayout(NamedTuple):
+    """The static shape of a design: what a jitted program is specialised
+    on. Everything that moves with the data (modes, means, sigmas) rides as
+    arrays (`DataInfo.moments`), so two jobs on frames of one shape share
+    one compiled program.
 
-    @functools.partial(jax.jit, static_argnames=("k",))
-    def _mode(codes, k):
-        import jax.numpy as jnp
+    A categorical column's levels lie on `width` lanes rounded up to whole
+    sublane tiles (`padded`), as models/tree/device_tree.hist_matmul lays a
+    feature's bins: nothing is placed at an offset inside a tile."""
+    cards: Tuple[int, ...]      # levels a categorical column has
+    base: int                   # 1 = the first level is dropped, 0 = kept
+    n_num: int
+    standardize: bool
 
-        valid = codes >= 0
-        counts = jnp.zeros(k, jnp.int32).at[jnp.maximum(codes, 0)].add(
-            valid.astype(jnp.int32))
-        return jnp.argmax(counts)
+    @property
+    def widths(self) -> Tuple[int, ...]:
+        return tuple(max(c - self.base, 1) for c in self.cards)
 
-    return int(_mode(col.data, card))
+    @property
+    def padded(self) -> Tuple[int, ...]:
+        return tuple(-(-w // _SUBLANES) * _SUBLANES for w in self.widths)
+
+    @property
+    def n_cat_coefs(self) -> int:
+        return int(sum(self.widths))
+
+    @property
+    def n_coefs(self) -> int:
+        return self.n_cat_coefs + self.n_num
+
+    def lane_levels(self) -> List[np.ndarray]:
+        """Per categorical column the (padded, 1) level each lane stands
+        for, -1 in the padding: no code has it."""
+        return [np.where(np.arange(pd) < wd, np.arange(pd) + self.base, -1)
+                .astype(np.int32)[:, None]
+                for wd, pd in zip(self.widths, self.padded)]
+
+    def lane_coef(self) -> np.ndarray:
+        """(sum(widths),) the padded lane of each categorical coefficient,
+        in coefficient order."""
+        offs = np.cumsum((0,) + self.padded[:-1]) if self.cards else ()
+        return np.concatenate(
+            [o + np.arange(w) for o, w in zip(offs, self.widths)]
+            or [np.zeros(0)]).astype(np.int32)
+
+
+def design_rows(layout: DesignLayout, moments, arrays):
+    """The design of a block of rows with the rows on the LANE axis and no
+    (rows, p) matrix: -> (O, D). O is the (sum(padded), rows) bool one-hot
+    of the categorical columns (None without any), one compare a column of
+    its imputed codes against the static level of each lane; D the
+    (n_num, rows) f32 numerics, imputed and standardized. The zeros and
+    ones are `DataInfo.expand`'s."""
+    import jax.numpy as jnp
+
+    cat_modes = moments[0]
+    ncat = len(layout.cards)
+    O = None
+    if ncat:
+        levels = layout.lane_levels()
+        parts = []
+        for i in range(ncat):
+            codes = arrays[i].astype(jnp.int32)
+            codes = jnp.where(codes < 0, cat_modes[i], codes)
+            parts.append(codes[None, :] == levels[i])
+        O = jnp.concatenate(parts) if ncat > 1 else parts[0]
+    return O, _numeric_rows(layout, moments, arrays)
+
+
+def _numeric_rows(layout: DesignLayout, moments, arrays):
+    """(n_num, rows) f32 numerics, imputed and standardized."""
+    import jax.numpy as jnp
+
+    _modes, impute, means, sigmas = moments
+    nums = arrays[len(layout.cards):len(layout.cards) + layout.n_num]
+    if not nums:
+        return jnp.zeros((0, arrays[0].shape[0]), jnp.float32)
+    D = jnp.stack(nums).astype(jnp.float32)
+    D = jnp.where(jnp.isnan(D), impute[:, None], D)
+    if layout.standardize:
+        D = (D - means[:, None]) / sigmas[:, None]
+    return D
+
+
+def lane_beta(layout: DesignLayout, beta):
+    """(n_cat_coefs,) categorical coefficients -> (sum(padded), 1) on the
+    lanes of `design_rows`' one-hot, zeros in the padding."""
+    import jax.numpy as jnp
+
+    return jnp.zeros(int(sum(layout.padded)), beta.dtype).at[
+        layout.lane_coef()].set(beta[: layout.n_cat_coefs])[:, None]
+
+
+def linear_predictor(layout: DesignLayout, moments, arrays, beta):
+    """x . beta + intercept for every row from codes and coefficients
+    (beta: n_coefs + 1, intercept last), without the expanded matrix: a
+    categorical column reads its row's coefficient by select over its own
+    levels (levels on sublanes, rows on lanes; a column at a time, so that
+    the compare feeds its sum and no (levels, rows) array is kept); nine
+    terms a row where the design is 668 wide."""
+    import jax.numpy as jnp
+
+    k = layout.n_cat_coefs
+    # the numerics by a dot, not by multiply-adds a compiler may or may not
+    # contract: the sum is then the same bits in every program that holds it
+    eta = beta[k:k + layout.n_num] @ _numeric_rows(layout, moments, arrays) \
+        + beta[-1]
+    off = 0
+    for i, wd in enumerate(layout.widths):
+        codes = arrays[i].astype(jnp.int32)
+        codes = jnp.where(codes < 0, moments[0][i], codes)
+        hit = codes[None, :] == (np.arange(wd, dtype=np.int32)
+                                 + layout.base)[:, None]
+        eta = eta + jnp.sum(jnp.where(hit, beta[off:off + wd, None], 0.0),
+                            axis=0)
+        off += wd
+    return eta
 
 
 class DataInfo:
@@ -85,7 +193,7 @@ class DataInfo:
             s = r.sigma
             sigmas.append(s if s and s > 0 else 1.0)
         for n in self.cat_names:
-            modes.append(_device_mode(frame.col(n)))
+            modes.append(frame.col(n).mode)
         self.num_means = np.asarray(means, np.float32) if means else np.zeros(0, np.float32)
         self.num_sigmas = np.asarray(sigmas, np.float32) if sigmas else np.ones(0, np.float32)
         self.cat_modes = np.asarray(modes, np.int32) if modes else np.zeros(0, np.int32)
@@ -123,6 +231,21 @@ class DataInfo:
 
     def cols(self, frame: Frame) -> List[Column]:
         return [frame.col(n) for n in self.predictor_names]
+
+    # -- the design without the expanded matrix -----------------------------
+    def layout(self) -> DesignLayout:
+        return DesignLayout(tuple(int(c) for c in self.cards),
+                            0 if self.use_all_factor_levels else 1,
+                            len(self.num_names), bool(self.standardize))
+
+    def moments(self) -> Tuple[np.ndarray, ...]:
+        """What `design_rows` needs of the data: (cat_modes, impute_values,
+        num_means, num_sigmas)."""
+        return (self.cat_modes, self.impute_values, self.num_means,
+                self.num_sigmas)
+
+    def linear_predictor(self, arrays, beta):
+        return linear_predictor(self.layout(), self.moments(), arrays, beta)
 
     # -- device-side expansion (traced inside jit) ------------------------
     def expand(self, *arrays):

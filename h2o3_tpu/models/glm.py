@@ -7,10 +7,16 @@ IRLSM / L-BFGS / coordinate descent (GLMModel.java:659), families
 
 TPU-native design:
 - The design matrix X (one-hot cats + standardized nums, hex/DataInfo.java)
-  is expanded ON DEVICE once and kept row-sharded; each IRLS iteration is a
-  single fused XLA program: eta = X·β → IRLS weights → Gram = XᵀWX via MXU
-  matmul with the cross-shard psum inserted by the SPMD partitioner — the
-  GLMIterationTask MRTask and its tree-reduce collapse into one all-reduce.
+  never exists for IRLS or for scoring one coefficient vector. The whole fit
+  is ONE XLA program (`_irls_fit`): a shard_map over the mesh's rows whose
+  while_loop walks the shard in row blocks; a block builds its design from
+  the raw columns with the rows on lanes (data_info.design_rows), its part
+  of Gram = XᵀWX as an MXU matmul (a one-hot is exact in bf16, so three
+  bf16 passes against the weights' three bf16 pieces give f32 products) and
+  of the score; the blocks' parts are summed compensated and psum'd over
+  the shards — the GLMIterationTask MRTask and its tree-reduce.
+  Multinomial, ordinal, the lambda path's lambda_max and p-values still
+  expand the design (DataInfo.expand).
 - Solve is a device Cholesky (jax.scipy cho_factor/cho_solve) on the (p+1)²
   Gram — H2O's gram/Gram.java:452 single-node solve, unchanged in spirit.
 - L1 (elastic net) uses ADMM around the cached Cholesky factor, exactly the
@@ -29,9 +35,11 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from h2o3_tpu.core.frame import Frame, T_CAT
+from h2o3_tpu.core.runtime import cluster
 from h2o3_tpu.models.data_info import DataInfo
 from h2o3_tpu.models.model import Model, ModelCategory
 from h2o3_tpu.models.model_builder import ModelBuilder, register
+from h2o3_tpu.obs import metrics, tracing
 
 EPS = 1e-10
 
@@ -227,97 +235,285 @@ def _make_family(name: str, params: dict) -> _Family:
 # jitted solver cores
 # ---------------------------------------------------------------------------
 
-@functools.partial(__import__("jax").jit, static_argnames=("expand", "famname", "linkname",
-                                                           "max_iter", "var_power", "link_power",
-                                                           "with_intercept", "non_negative"))
-def _irls_fit(arrays, y, w, offset, beta0, lam_l2, lam_l1, beta_eps, *, expand,
-              famname, linkname, max_iter, var_power=1.5, link_power=0.0,
-              with_intercept=True, non_negative=False):
+TIKHONOV_REFINEMENTS = 2       # of the jittered solve, on the true residual
+IRLS_BLOCK_ELEMS = 1 << 26     # design entries a row block may hold
+IRLS_BLOCK_MAX = 1 << 17
+
+
+def irls_block_rows(n_shard: int, lanes: int) -> int:
+    """Rows of one IRLS block, from shape alone: the largest power of two
+    whose (lanes, rows) design stays under IRLS_BLOCK_ELEMS entries, at most
+    IRLS_BLOCK_MAX and at most the shard (a shard no longer than a block is
+    one block: whole and blocked are one program). 65,536 rows at the
+    airline design's 696 lanes: the bf16 operands of a block are 0.36 GB,
+    and the 1.9 MB Gram update a block is a twentieth of its matmul."""
+    blk = 1 << max((IRLS_BLOCK_ELEMS // max(lanes, 1)).bit_length() - 1, 10)
+    return int(min(blk, IRLS_BLOCK_MAX, max(n_shard, 1)))
+
+
+def gram_form(layout) -> str:
+    """How a block's Gram is computed, from the design's shape: `onehot3`
+    where it has categorical columns (the one-hot is exact in bf16, so
+    three bf16 passes against the weights' three bf16 pieces give the f32
+    products), `dense` (f32 at `highest`) where it has none."""
+    return "onehot3" if layout.cards else "dense"
+
+
+def _bf16_pieces(x):
+    """x (f32) as three f32 arrays that a convert to bf16 keeps (the last
+    to a rounding) and whose sum is x to its last bit or two:
+    reduce_precision, not a convert pair the compiler may take for excess
+    precision and drop."""
+    import jax
+
+    rp = lambda a: jax.lax.reduce_precision(a, exponent_bits=8,
+                                            mantissa_bits=7)
+    hi = rp(x)
+    mid = rp(x - hi)
+    return hi, mid, x - hi - mid
+
+
+def _kahan_add(acc, x):
+    """Compensated sum of the blocks' partials: (sum, carry) + x. The error
+    of the total is a rounding or two whatever the number of blocks; a
+    pairwise sum would bound it by their logarithm but has to keep a
+    partial Gram a level, and a plain sum's error grows with the blocks."""
+    s, c = acc
+    y = x - c
+    t = s + y
+    return t, (t - s) - y
+
+
+@functools.partial(__import__("jax").jit, static_argnames=(
+    "layout", "blk", "mesh", "famname", "linkname", "max_iter", "var_power",
+    "link_power", "with_intercept", "non_negative"))
+def _irls_fit(arrays, moments, y, w, offset, beta0, lam_l2, lam_l1, beta_eps,
+              *, layout, blk, mesh, famname, linkname, max_iter,
+              var_power=1.5, link_power=0.0, with_intercept=True,
+              non_negative=False):
     """Full IRLS in one XLA program (lax.while_loop). Returns (beta, iters,
-    deviance). X stays row-sharded; Gram/XtWz reduce over shards via the
-    partitioner's all-reduce (the GLMIterationTask analog)."""
+    deviance).
+
+    The design never exists as a (rows, p) matrix. Inside a shard_map over
+    the mesh's "rows" axis an iteration walks its shard in blocks of `blk`
+    rows (irls_block_rows); a block builds its design from the raw columns
+    with the rows on lanes (data_info.design_rows), computes eta, mu, the
+    IRLS weights and its part of the Gram G = X'WX and of the score
+    g = X'W(y - mu)g'(mu), and the parts are summed compensated
+    (_kahan_add), then psum'd over the shards (the GLMIterationTask
+    analog). The step solves G b = q with q = G beta + g, which is X'Wz of
+    the textbook working response z = X beta + (y - mu)g'(mu) with the part
+    that cancels taken out of the f32 sum over the rows: the fixed point is
+    g = 0 whatever rounding G has."""
     import jax
     import jax.numpy as jnp
     import jax.scipy.linalg as jsl
+    from jax.sharding import PartitionSpec as P
+
+    from h2o3_tpu.compat import pcast, shard_map
+    from h2o3_tpu.models.data_info import design_rows, lane_beta
 
     fam = _make_family(famname, {"tweedie_variance_power": var_power})
     link, linkinv, dlink = _Link.of(linkname, link_power)
+    hi = jax.lax.Precision.HIGHEST
 
-    X = expand(*arrays)                       # (N, p) row-sharded
-    N, p = X.shape
-    # intercept=False: zeroed ones-column ⇒ q[p]=0 and the ridge eps pins
-    # beta[p] to exactly 0, so downstream scoring needs no special case
-    ones = jnp.full((N, 1), 1.0 if with_intercept else 0.0, X.dtype)
-    Xi = jnp.concatenate([X, ones], axis=1)   # intercept column last
-    pi = p + 1
+    p = layout.n_coefs
+    pi = p + 1                                # intercept last
+    kc, nn = layout.n_cat_coefs, layout.n_num
+    Lc = int(sum(layout.padded))              # lanes of the one-hot
+    nd = nn + 1                               # dense columns: numerics, ones
+    lane_coef = layout.lane_coef()
+    icpt = 1.0 if with_intercept else 0.0
+    form = gram_form(layout)
 
-    def dev_of(beta):
-        eta = Xi @ beta + offset
-        mu = linkinv(eta)
-        return jnp.sum(fam.deviance(w, y, mu))
+    Lp = -(-(Lc + nd + 1) // 8) * 8           # rows of one bf16 piece
 
-    def admm_solve(G, q, l1, rho=1.0, sweeps=50):
-        """min ½βᵀGβ - qᵀβ + l1·|β|₁ (+ β≥0 when non_negative; no penalty or
-        bound on the intercept) via ADMM (optimization/ADMM.java — the
-        reference handles the non-negative bound inside the same ADMM):
-        cached Cholesky of G+ρI, jitted sweeps. Unlike a coordinate clip of
-        the Newton step, the projection INSIDE ADMM converges to the true
-        constrained optimum."""
-        Grho = G + rho * jnp.eye(pi, dtype=G.dtype)
-        cf = jsl.cho_factor(Grho)
-        pen = jnp.concatenate([jnp.full(p, l1), jnp.zeros(1)])
+    def local_fit(arrays, moments, y, w, offset, b_init, lam_l2, lam_l1,
+                  beta_eps):
+        n = y.shape[0]
+        nblk = -(-n // blk)
 
-        def sweep(carry, _):
-            z, u = carry
-            b = jsl.cho_solve(cf, q + rho * (z - u))
-            z2 = jnp.sign(b + u) * jnp.maximum(jnp.abs(b + u) - pen / rho, 0.0)
-            if non_negative:
-                z2 = z2.at[:p].set(jnp.maximum(z2[:p], 0.0))
-            return (z2, u + b - z2), None
+        def block(i):
+            """Rows [i*blk, (i+1)*blk) of the shard; the last block starts
+            early enough to be whole and gives the rows it shares with the
+            one before it no weight."""
+            start = jnp.minimum(i * blk, n - blk)
+            sl = lambda a: jax.lax.dynamic_slice_in_dim(a, start, blk)
+            fresh = start + jnp.arange(blk) >= i * blk
+            return (tuple(sl(a) for a in arrays), sl(y),
+                    jnp.where(fresh, sl(w), 0.0), sl(offset))
 
-        (z, _), _ = jax.lax.scan(sweep, (jnp.zeros(pi, G.dtype), jnp.zeros(pi, G.dtype)),
-                                 None, length=sweeps)
-        return z
+        def eta_of(O, D, beta, lb):
+            """x . beta of a block (no offset): numerics and intercept, and
+            a categorical column's coefficient by select over its lanes."""
+            eta = beta[kc:kc + nn] @ D + beta[p] * icpt
+            if O is not None:
+                eta = eta + jnp.sum(jnp.where(O, lb, 0.0), axis=0)
+            return eta
 
-    def body(carry):
-        beta, it, _prev, _dev = carry
-        eta = Xi @ beta + offset
-        mu = linkinv(eta)
-        gp = dlink(mu)
-        wls = w / jnp.maximum(fam.variance(mu) * gp * gp, EPS)
-        z = (eta - offset) + (y - mu) * gp
-        # the distributed Gram pass: one MXU matmul + psum (gram/Gram.java)
-        Xw = Xi * wls[:, None]
-        # full f32 precision: TPU matmuls default to bf16, which destroys the
-        # conditioning the Cholesky/ADMM relies on for collinear designs
-        with jax.default_matmul_precision("highest"):
-            G = Xi.T @ Xw
-            q = Xw.T @ z
-        Greg = G + lam_l2 * jnp.diag(jnp.concatenate([jnp.ones(p), jnp.zeros(1)]))
-        use_admm = (lam_l1 > 0) | non_negative
-        # jitter scaled to the Gram's magnitude: collinear designs (e.g.
-        # one-hot groups summing to the intercept) stay solvable in f32
-        jitter = 1e-6 * (jnp.trace(Greg) / pi + 1.0)
-        beta_new = jax.lax.cond(
-            use_admm,
-            lambda: admm_solve(Greg, q, lam_l1),
-            lambda: jsl.cho_solve(
-                jsl.cho_factor(Greg + jitter * jnp.eye(pi, dtype=G.dtype)), q))
-        dev = dev_of(beta_new)
-        return beta_new, it + 1, beta, dev
+        def gram_pass(beta):
+            """-> (G, g): X'WX and the score at beta, over the shards."""
+            lb = lane_beta(layout, beta)
 
-    def cond(carry):
-        beta, it, prev, _ = carry
-        delta = jnp.max(jnp.abs(beta - prev))
-        return (it < max_iter) & (delta > beta_eps)
+            def body(i, acc):
+                cols, yb, wb, ob = block(i)
+                with jax.named_scope("irls/design"):
+                    O, D = design_rows(layout, moments, cols)
+                    Di = jnp.concatenate(
+                        [D, jnp.full((1, blk), icpt, jnp.float32)])
+                    eta = eta_of(O, D, beta, lb) + ob
+                    mu = linkinv(eta)
+                    gp = dlink(mu)
+                    # the variance floored where the link's derivative is:
+                    # a row whose f32 mu rounds to 1 has variance 0, and
+                    # 0 * (1/EPS)^2 under the floor below would weigh it
+                    # 1/EPS = 1e10 rows' worth where it should weigh none
+                    var = jnp.maximum(fam.variance(mu), EPS)
+                    wls = wb / jnp.maximum(var * gp * gp, EPS)
+                    u = wls * (yb - mu) * gp
+                with jax.named_scope("irls/gram"):
+                    # dense x dense, and the dense side of the score
+                    R = jnp.concatenate([Di * wls[None, :], u[None, :]])
+                    dd = jax.lax.dot_general(
+                        Di, R, (((1,), (1,)), ((), ())), precision=hi)
+                    parts = [dd]
+                    if form == "onehot3":
+                        # one-hot (exact in bf16) x three bf16 pieces of the
+                        # weighted dense columns and of the score's values:
+                        # products exact, f32 accumulation
+                        Rz = jnp.pad(R, ((0, Lp - Lc - nd - 1), (0, 0)))
+                        V = jnp.concatenate(
+                            [jnp.concatenate([jnp.where(O, wk[None, :], 0.0),
+                                              rk])
+                             for wk, rk in zip(_bf16_pieces(wls),
+                                               _bf16_pieces(Rz))])
+                        A = jax.lax.dot_general(
+                            O.astype(jnp.bfloat16), V.astype(jnp.bfloat16),
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+                        A = A.reshape(Lc, 3, Lp)             # (3 Lp, blk) V
+                        parts.append(A[:, 2] + A[:, 1] + A[:, 0])
+                return tuple(_kahan_add(a, x) for a, x in zip(acc, parts))
+
+            zeros = lambda *s: pcast(jnp.zeros(s, jnp.float32), ("rows",),
+                                     to="varying")
+            acc0 = [(zeros(nd, nd + 1), zeros(nd, nd + 1))]
+            if form == "onehot3":
+                acc0.append((zeros(Lc, Lp), zeros(Lc, Lp)))
+            acc = jax.lax.fori_loop(0, nblk, body, tuple(acc0))
+            dd = jax.lax.psum(acc[0][0], "rows")
+            G_dd, g_d = dd[:, :nd], dd[:, nd]
+            if form != "onehot3":
+                return G_dd, g_d
+            A = jax.lax.psum(acc[1][0], "rows")[lane_coef]     # (kc, ...)
+            G_cc, G_cd, g_c = A[:, :Lc][:, lane_coef], A[:, Lc:Lc + nd], \
+                A[:, Lc + nd]
+            G = jnp.concatenate([jnp.concatenate([G_cc, G_cd], axis=1),
+                                 jnp.concatenate([G_cd.T, G_dd], axis=1)])
+            return G, jnp.concatenate([g_c, g_d])
+
+        def dev_of(beta):
+            lb = lane_beta(layout, beta)
+
+            def body(i, acc):
+                cols, yb, wb, ob = block(i)
+                O, D = design_rows(layout, moments, cols)
+                mu = linkinv(eta_of(O, D, beta, lb) + ob)
+                return _kahan_add(acc, jnp.sum(fam.deviance(wb, yb, mu)))
+
+            with jax.named_scope("irls/deviance"):
+                zero = pcast(jnp.float32(0), ("rows",), to="varying")
+                dev, _ = jax.lax.fori_loop(0, nblk, body, (zero, zero))
+                return jax.lax.psum(dev, "rows")
+
+        def admm_solve(G, q, l1, rho=1.0, sweeps=50):
+            """min ½βᵀGβ - qᵀβ + l1·|β|₁ (+ β≥0 when non_negative; no penalty
+            or bound on the intercept) via ADMM (optimization/ADMM.java — the
+            reference handles the non-negative bound inside the same ADMM):
+            cached Cholesky of G+ρI, jitted sweeps. Unlike a coordinate clip
+            of the Newton step, the projection INSIDE ADMM converges to the
+            true constrained optimum."""
+            Grho = G + rho * jnp.eye(pi, dtype=G.dtype)
+            cf = jsl.cho_factor(Grho)
+            pen = jnp.concatenate([jnp.full(p, l1), jnp.zeros(1)])
+
+            def sweep(carry, _):
+                z, u = carry
+                b = jsl.cho_solve(cf, q + rho * (z - u))
+                z2 = jnp.sign(b + u) * jnp.maximum(jnp.abs(b + u) - pen / rho,
+                                                   0.0)
+                if non_negative:
+                    z2 = z2.at[:p].set(jnp.maximum(z2[:p], 0.0))
+                return (z2, u + b - z2), None
+
+            (z, _), _ = jax.lax.scan(
+                sweep, (jnp.zeros(pi, G.dtype), jnp.zeros(pi, G.dtype)),
+                None, length=sweeps)
+            return z
+
+        def body(carry):
+            beta, it, _prev = carry
+            G, g = gram_pass(beta)
+            with jax.named_scope("irls/solve"):
+                # intercept=False: the zeroed ones-column gives G[p] = 0 and
+                # q[p] = 0, and the ridge eps pins beta[p] to exactly 0, so
+                # downstream scoring needs no special case
+                pen = jnp.concatenate([jnp.ones(p), jnp.zeros(1)])
+                Greg = G + lam_l2 * jnp.diag(pen)
+                use_admm = (lam_l1 > 0) | non_negative
+                # jitter scaled to the Gram's magnitude: collinear designs
+                # (e.g. one-hot groups summing to the intercept) stay
+                # solvable in f32, their free direction pinned to zero
+                jitter = 1e-6 * (jnp.trace(Greg) / pi + 1.0)
+
+                def newton():
+                    """Greg b = G beta + g by iterated Tikhonov, as steps
+                    from beta. The first solve is (Greg + jitter I)^-1 of
+                    the right-hand side, a ridge that shrinks a direction
+                    of curvature c by jitter / (c + jitter): 1e-3 of a rare
+                    level's coefficient on a 300-level column. Each
+                    refinement on the true system's residual takes that
+                    share to its next power, and leaves a free direction
+                    where the ridge pinned it. Solving for the step keeps
+                    the f32 Cholesky's error a share of the step, not of
+                    beta."""
+                    cf = jsl.cho_factor(
+                        Greg + jitter * jnp.eye(pi, dtype=G.dtype))
+                    rhs = g - lam_l2 * pen * beta
+                    step = jsl.cho_solve(cf, rhs - jitter * beta)
+                    for _ in range(TIKHONOV_REFINEMENTS):
+                        step = step + jsl.cho_solve(
+                            cf, rhs - jnp.dot(Greg, step, precision=hi))
+                    return beta + step
+
+                beta_new = jax.lax.cond(
+                    use_admm,
+                    lambda: admm_solve(
+                        Greg, jnp.dot(G, beta, precision=hi) + g, lam_l1),
+                    newton)
+            return beta_new, it + 1, beta
+
+        def cond(carry):
+            beta, it, prev = carry
+            delta = jnp.max(jnp.abs(beta - prev))
+            return (it < max_iter) & (delta > beta_eps)
+
+        beta, iters, _ = jax.lax.while_loop(
+            cond, body, (b_init, jnp.int32(0), b_init + 1e3))
+        return beta, iters, dev_of(beta)
 
     mu0 = fam.init_mu(y, w)
     init_icpt = jnp.mean(link(mu0)) if with_intercept else 0.0
     b_init = jnp.where(jnp.any(beta0 != 0), beta0,
                        jnp.zeros(pi).at[p].set(init_icpt))
-    beta, iters, _, dev = jax.lax.while_loop(
-        cond, body, (b_init, jnp.int32(0), b_init + 1e3, jnp.float32(0)))
-    return beta, iters, dev_of(beta)
+    rows, rep = P("rows"), P()
+    fn = shard_map(local_fit, mesh=mesh,
+                   in_specs=(tuple(rows for _ in arrays),
+                             tuple(rep for _ in moments), rows, rows, rows,
+                             rep, rep, rep, rep),
+                   out_specs=(rep, rep, rep))
+    return fn(tuple(arrays), tuple(moments), y, w, offset, b_init, lam_l2,
+              lam_l1, beta_eps)
 
 
 @functools.partial(__import__("jax").jit, static_argnames=("expand", "nclasses", "max_iter"))
@@ -439,17 +635,21 @@ def _ordinal_predict(arrays, v, *, expand):
     return jnp.maximum(_ordinal_class_probs(X, v), 0.0)
 
 
-@functools.partial(__import__("jax").jit, static_argnames=("expand", "linkname", "link_power", "nclasses"))
-def _glm_predict(arrays, beta, offset, *, expand, linkname, link_power=0.0, nclasses=1):
+@functools.partial(__import__("jax").jit, static_argnames=("dinfo", "linkname", "link_power", "nclasses"))
+def _glm_predict(arrays, beta, offset, *, dinfo, linkname, link_power=0.0, nclasses=1):
+    """mu of every row. One coefficient vector needs no expanded matrix:
+    eta comes from codes and coefficients (DataInfo.linear_predictor), the
+    DataInfo's moments closed over as program constants. The multinomial
+    matrix of coefficients still goes through `expand`."""
     import jax
     import jax.numpy as jnp
 
-    X = expand(*arrays)
-    Xi = jnp.concatenate([X, jnp.ones((X.shape[0], 1), X.dtype)], axis=1)
     if nclasses > 2:
+        X = dinfo.expand(*arrays)
+        Xi = jnp.concatenate([X, jnp.ones((X.shape[0], 1), X.dtype)], axis=1)
         return jax.nn.softmax(Xi @ beta, axis=-1)
     _, linkinv, _ = _Link.of(linkname, link_power)
-    return linkinv(Xi @ beta + offset)
+    return linkinv(dinfo.linear_predictor(arrays, beta) + offset)
 
 
 # ---------------------------------------------------------------------------
@@ -559,13 +759,13 @@ class GLMModel(Model):
             if self.linkname == "ordinal":
                 return {"probs": _ordinal_predict(arrays, self.beta,
                                                   expand=self.dinfo.expand)}
-            probs = _glm_predict(arrays, self.beta, 0.0, expand=self.dinfo.expand,
+            probs = _glm_predict(arrays, self.beta, 0.0, dinfo=self.dinfo,
                                  linkname=self.linkname, nclasses=K)
             return {"probs": probs}
         offset = 0.0
         if self._parms.get("offset_column") and self._parms["offset_column"] in frame:
             offset = frame.col(self._parms["offset_column"]).data
-        mu = _glm_predict(arrays, self.beta, offset, expand=self.dinfo.expand,
+        mu = _glm_predict(arrays, self.beta, offset, dinfo=self.dinfo,
                           linkname=self.linkname, link_power=self.link_power)
         if K == 2:
             return {"probs": jnp.stack([1 - mu, mu], axis=-1)}
@@ -714,13 +914,18 @@ class GLM(ModelBuilder):
         # the prediction to linkInv(0) at the feature MEANS, a meaningless
         # constraint that also breaks coef() de-standardization
         with_icpt = bool(self.params.get("intercept", True))
-        dinfo = DataInfo(train, response=resp,
-                         ignored=self.params.get("ignored_columns") or (),
-                         weights=self.params.get("weights_column"),
-                         offset=self.params.get("offset_column"),
-                         standardize=(bool(self.params.get("standardize", True))
-                                      and with_icpt),
-                         use_all_factor_levels=not with_icpt)
+        # stage span ``design``: DataInfo reads the rollups and modes of the
+        # predictors on the host (cached on the columns after a frame's
+        # first job), which is where it has always blocked
+        with tracing.span("design", rows=train.nrows):
+            dinfo = DataInfo(train, response=resp,
+                             ignored=self.params.get("ignored_columns") or (),
+                             weights=self.params.get("weights_column"),
+                             offset=self.params.get("offset_column"),
+                             standardize=(bool(self.params.get("standardize",
+                                                               True))
+                                          and with_icpt),
+                             use_all_factor_levels=not with_icpt)
         model.dinfo = dinfo
 
         cols = dinfo.cols(train)
@@ -794,18 +999,39 @@ class GLM(ModelBuilder):
             lam = 0.0 if self.params.get("compute_p_values") else 1e-5
         max_iter = int(self.params["max_iterations"])
 
+        layout = dinfo.layout()
+        mesh = cluster().mesh
+        n_shard = int(y.shape[0]) // cluster().row_shards
+        blk = irls_block_rows(n_shard,
+                              sum(layout.padded) + layout.n_num + 2)
+        form = gram_form(layout)
+
+        static = dict(
+            layout=layout, blk=blk, mesh=mesh, famname=fam, linkname=linkname,
+            var_power=float(self.params["tweedie_variance_power"]),
+            link_power=model.link_power,
+            with_intercept=bool(self.params.get("intercept", True)),
+            non_negative=bool(self.params.get("non_negative", False)))
+        beta_eps = jnp.float32(self.params.get("beta_epsilon", 1e-4))
+
         def fit_one(lam_val, beta_init, max_it=None):
-            l2 = float(lam_val) * (1 - alpha) * nobs
-            l1 = float(lam_val) * alpha * nobs
-            return _irls_fit(arrays, y, wts, offset, beta_init,
-                             jnp.float32(l2), jnp.float32(l1),
-                             jnp.float32(self.params.get("beta_epsilon", 1e-4)),
-                             expand=dinfo.expand, famname=fam, linkname=linkname,
-                             max_iter=max_iter if max_it is None else int(max_it),
-                             var_power=float(self.params["tweedie_variance_power"]),
-                             link_power=model.link_power,
-                             with_intercept=bool(self.params.get("intercept", True)),
-                             non_negative=bool(self.params.get("non_negative", False)))
+            """One IRLS program -> (beta, iterations as an int, deviance).
+            Stage span ``irls``: from the dispatch to the iteration count's
+            fetch, where the host has always blocked."""
+            l2 = jnp.float32(float(lam_val) * (1 - alpha) * nobs)
+            l1 = jnp.float32(float(lam_val) * alpha * nobs)
+            with tracing.span("irls", p=dinfo.fullN + 1, gram_form=form,
+                              row_blocks=-(-n_shard // blk)) as sp:
+                beta, iters, dev = _irls_fit(
+                    arrays, dinfo.moments(), y, wts, offset, beta_init,
+                    l2, l1, beta_eps,
+                    max_iter=max_iter if max_it is None else int(max_it),
+                    **static)
+                iters = int(iters)
+                sp.set(iterations=iters)
+            metrics.inc("h2o3_glm_iterations_total", iters)
+            metrics.inc("h2o3_glm_gram_passes_total", iters, form=form)
+            return beta, iters, dev
 
         pi = dinfo.fullN + 1
         b0 = jnp.zeros(pi, jnp.float32)

@@ -473,6 +473,13 @@ def _install_default_metrics() -> None:
               "(max_depth a tree: the last level builds none), by lowering: "
               "matmul | scatter = hist_lowering's rule from the level's "
               "width")
+    r.counter("h2o3_glm_iterations_total",
+              "IRLS iterations of the GLM programs that ended, counted at "
+              "the fetch of each program's iteration count")
+    r.counter("h2o3_glm_gram_passes_total",
+              "passes over the rows that built a Gram (one an IRLS "
+              "iteration), by form: onehot3 | dense = gram_form's rule "
+              "from the design's shape")
     r.counter("h2o3_forest_walk_total",
               "dispatches of a forest-walk program (predict_binned, "
               "leaf_index, the scoring session's fused programs), by form: "

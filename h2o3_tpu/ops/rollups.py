@@ -67,6 +67,32 @@ def compute_rollups(col) -> Rollups:
                    mean, sigma, int(na), int(nz), n)
 
 
+MODE_SELECT_LEVELS = 1024   # up to here a level's count is a compare-and-sum
+
+
+@functools.lru_cache(maxsize=32)
+def _mode_fn(k: int):
+    @jax.jit
+    def mode(codes):
+        codes = codes.astype(jnp.int32)
+        if k <= MODE_SELECT_LEVELS:
+            # levels on sublanes, rows on lanes: no scatter over the rows
+            counts = jnp.sum(codes[None, :] == jnp.arange(k)[:, None],
+                             axis=1, dtype=jnp.int32)
+        else:
+            counts = jnp.zeros(k, jnp.int32).at[jnp.maximum(codes, 0)].add(
+                (codes >= 0).astype(jnp.int32))
+        return jnp.argmax(counts)
+
+    return mode
+
+
+def compute_mode(col) -> int:
+    """Most frequent level of a categorical column (the first of equals;
+    NA and pad rows, code -1, count for none)."""
+    return int(_mode_fn(max(col.cardinality, 1))(col.data))
+
+
 @functools.lru_cache(maxsize=8)
 def _hist_fn(nbins: int):
     @jax.jit

@@ -40,7 +40,7 @@ the planner DAG:
 - **GLM.** :func:`try_glm_raw` is the linear-model twin: engineered
   numeric predictors evaluate as fused plans (device arrays — never a
   Column), and ONE ``pipeline``-family program runs the exact
-  ``models/glm._glm_predict`` core (expand + intercept matmul + linkinv)
+  ``models/glm._glm_predict`` core (eta from codes and coefficients + linkinv)
   over them at the frame's padded length.
 
 Anything capture cannot hold (pending sorts, domain-remapped or missing
@@ -717,7 +717,7 @@ def try_glm_raw(model, frame: Frame) -> Optional[dict]:
 
         def run(offset, beta, *arrs):
             return _glm_predict(
-                tuple(arrs), beta, offset, expand=d.expand,
+                tuple(arrs), beta, offset, dinfo=d,
                 linkname=model.linkname,
                 link_power=(model.link_power if K <= 2 else 0.0),
                 nclasses=K if K > 2 else 1)
