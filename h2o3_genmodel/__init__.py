@@ -20,6 +20,11 @@ AOT artifacts (the serving-tier lineage; needs jax at score time):
     tbl = scorer.score(cols)
     python -m h2o3_genmodel.aot_predict --artifact model_artifact/ \
         --input in.csv --output out.csv
+
+`levels.py` is the one numpy-only module that h2o3_tpu imports from here
+(models/tree/compressed.py, artifact/packer.py): the layout of a forest for
+the device walk, shared so that an artifact's runner and its exporter
+cannot lay the same stored arrays out differently.
 """
 
 from h2o3_genmodel.aot import AotScorer, load_artifact

@@ -31,9 +31,11 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from h2o3_genmodel.levels import WALK_ARGS, level_view
+
 _FORMAT = "h2o3-tpu-aot-artifact"
 _FORMAT_VERSION = 1
-_BLOB_VERSION = 1
+_BLOB_VERSION = 2      # h2o3_tpu.artifact.aot.BLOB_VERSION
 
 
 class ArtifactError(ValueError):
@@ -172,6 +174,15 @@ class AotScorer:
             if int(arrays["spec_is_cat"].shape[0]) != F:
                 raise ArtifactError("packed spec width disagrees with "
                                     "manifest names")
+            # both load paths (serialized executable, StableHLO) bind the
+            # forest inputs by position: refuse programs lowered for
+            # another layout than _device_args builds
+            if m.get("forest_args") != list(WALK_ARGS):
+                raise ArtifactError(
+                    f"the artifact's programs take the forest as "
+                    f"{m.get('forest_args') or 'the stored arrays'}, this "
+                    f"runtime passes {list(WALK_ARGS)} — re-export the "
+                    "artifact on a current framework build")
             self.is_cat = arrays["spec_is_cat"].astype(bool)
         self.domains: Dict[str, List[str]] = {
             k: list(v) for k, v in (m.get("domains") or {}).items()}
@@ -208,16 +219,11 @@ class AotScorer:
         init = (np.asarray(a["init_class"], np.float32)
                 if "init_class" in a
                 else np.float32(self.manifest["init_f"]))
-        self._dev = (jnp.asarray(ep), jnp.asarray(self.is_cat),
-                     jnp.asarray(init),
-                     jnp.asarray(a["feat"]), jnp.asarray(a["thresh_bin"]),
-                     jnp.asarray(a["na_left"].astype(bool)),
-                     jnp.asarray(a["left"]), jnp.asarray(a["right"]),
-                     jnp.asarray(a["leaf_val"].astype(np.float32)),
-                     jnp.asarray(a["cat_split"]),
-                     jnp.asarray(a["cat_table"].astype(bool)),
-                     jnp.asarray(a["tree_class"]),
-                     jnp.asarray(a["na_bins"]))
+        # the program's forest inputs are the level-ordered view of the
+        # stored arrays, rebuilt here as the exporter built it
+        self._dev = tuple(jnp.asarray(x) for x in (
+            ep, self.is_cat, init,
+            *level_view(a, int(self.manifest["max_depth"]))))
         return self._dev
 
     # -- executables ------------------------------------------------------

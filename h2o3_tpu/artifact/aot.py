@@ -23,7 +23,10 @@ from typing import Any, Dict, Tuple
 
 import numpy as np
 
-BLOB_VERSION = 1
+# 2: the forest programs take the level-ordered view (compressed.WALK_ARGS,
+# ISSUE 33); an executable serialized before that has other inputs and is a
+# cache miss, never a call with the wrong arguments
+BLOB_VERSION = 2
 
 
 def backend_fingerprint(single_device: bool = False) -> str:

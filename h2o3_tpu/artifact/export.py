@@ -167,7 +167,8 @@ def export_model(model, out_dir: str,
     forest_entry = manifest.write_payload(out_dir, FOREST_FILE,
                                           packer.dump_npz(arrays))
 
-    edges, is_cat, forest_args = packer.scoring_inputs(arrays)
+    edges, is_cat, forest_args = packer.scoring_inputs(
+        arrays, meta["max_depth"])
     init = (arrays["init_class"] if "init_class" in arrays
             else np.float32(meta["init_f"]))
     fingerprint = aot.backend_fingerprint(single_device=True)
@@ -207,6 +208,7 @@ def export_model(model, out_dir: str,
                 getattr(model, "_distribution", None), "power", 1.5)),
         },
         files={"forest": forest_entry},
+        forest_args=list(packer.WALK_ARGS),
         buckets=buckets,
         executables=execs,
         stablehlo=hlos,

@@ -22,6 +22,7 @@ from typing import Any, Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from h2o3_genmodel.levels import WALK_ARGS, level_view
 
 # ---------------------------------------------------------------------------
 # forest + spec <-> npz
@@ -107,22 +108,18 @@ def padded_edges(edges_flat: np.ndarray, edges_len: np.ndarray,
     return ep
 
 
-def scoring_inputs(arrays: Dict[str, np.ndarray]
+def scoring_inputs(arrays: Dict[str, np.ndarray], max_depth: int
                    ) -> Tuple[np.ndarray, np.ndarray, tuple]:
     """(edges_padded, is_cat, forest_arg_tuple) in the fused program's
-    argument order — shared by the server-side loader and the standalone
-    runner."""
+    argument order (compressed.WALK_ARGS: the level-ordered view, rebuilt
+    from the stored arrays) — shared by the server-side loader and the
+    standalone runner. A forest manifest records WALK_ARGS as `forest_args`:
+    the layout the artifact's programs were lowered for."""
     F = int(arrays["spec_is_cat"].shape[0])
     edges = padded_edges(arrays["spec_edges_flat"], arrays["spec_edges_len"],
                          F)
     is_cat = arrays["spec_is_cat"].astype(bool)
-    forest_args = (
-        arrays["feat"], arrays["thresh_bin"], arrays["na_left"].astype(bool),
-        arrays["left"], arrays["right"],
-        arrays["leaf_val"].astype(np.float32),
-        arrays["cat_split"], arrays["cat_table"].astype(bool),
-        arrays["tree_class"], arrays["na_bins"])
-    return edges, is_cat, forest_args
+    return edges, is_cat, level_view(arrays, max_depth)
 
 
 # ---------------------------------------------------------------------------

@@ -194,7 +194,8 @@ def _scorer_fn(cap, inner: str, model):
     if inner == "forest":
         arrays = packer.pack_forest(model.forest, model.spec)
         meta = packer.forest_meta(model.forest, model.spec)
-        edges, is_cat, fargs = packer.scoring_inputs(arrays)
+        edges, is_cat, fargs = packer.scoring_inputs(
+            arrays, meta["max_depth"])
         init = (arrays["init_class"] if "init_class" in arrays
                 else np.float32(meta["init_f"]))
         edges_c = jnp.asarray(edges)

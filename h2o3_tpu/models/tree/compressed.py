@@ -7,11 +7,16 @@ mirrors the walk for MOJOs.
 TPU-native design: the forest IS a pytree of dense arrays shaped
 (n_trees, max_nodes): feat / thresh_bin / na_left / left / right /
 leaf_val, plus one shared categorical-subset LUT. Scoring every row
-through every tree is a lax.scan over trees of a lax.fori_loop pointer
-chase — all rows advance one level per step in lockstep (SIMD traversal),
-bins replace raw feature comparisons so test data is binned once with the
-training edges and the traversal is pure int compares. Row-sharded input
-⇒ embarrassingly parallel over the mesh.
+through every tree is a lax.scan over trees of a walk level by level — all
+rows advance one level per step in lockstep (SIMD traversal), and step d
+reads the tables of depth d alone: a row at depth d stands on one of at
+most 2^d nodes. The walk reads a level-ordered view of the stored arrays
+(level_view, built once a forest on the host): a tree's nodes breadth
+first, each depth a contiguous run, the subsets of a depth's enum splits
+contiguous too and packed 32 bins a uint32 word. Bins replace raw feature
+comparisons so test data is binned once with the training edges and the
+traversal is pure int compares. Row-sharded input ⇒ embarrassingly
+parallel over the mesh.
 """
 
 from __future__ import annotations
@@ -20,6 +25,11 @@ import functools
 from typing import List, Optional
 
 import numpy as np
+
+# the level-ordered view the walk reads lives with the standalone runner,
+# which rebuilds an exported program's inputs with it (numpy only)
+from h2o3_genmodel import levels
+from h2o3_genmodel.levels import WALK_ARGS, level_view, walk_widths
 
 
 class CompressedForest:
@@ -162,13 +172,17 @@ class CompressedForest:
         return out
 
     # -- device scoring ----------------------------------------------------
+    @functools.cached_property
+    def _level_view(self) -> tuple:
+        return level_view(vars(self), self.max_depth)
+
     def arrays(self):
+        """What every walk program takes after the rows (WALK_ARGS): the
+        level-ordered view of the stored arrays, built once a forest on
+        the host, with tree_class and na_bins."""
         import jax.numpy as jnp
 
-        return tuple(jnp.asarray(a) for a in (
-            self.feat, self.thresh_bin, self.na_left, self.left, self.right,
-            self.leaf_val, self.cat_split, self.cat_table, self.tree_class,
-            self.na_bins))
+        return tuple(jnp.asarray(a) for a in self._level_view)
 
     @property
     def per_class_trees(self) -> bool:
@@ -180,19 +194,46 @@ class CompressedForest:
             and int(np.asarray(self.tree_class).max(initial=0)) > 0)
 
     @functools.cached_property
+    def _walk_counts(self) -> dict:
+        """What one dispatch adds to the span open at count_walk: the steps
+        it walks (trees x max_depth) and how many of them read their widest
+        table by gather on a TPU, by _at_node's rule from the level's
+        static width: the packed subset words in a tree that takes the
+        categorical branch of _walk_tree's cond (read from the view, as the
+        cond reads it), the node tables in one that does not."""
+        nodes, cat_words = self._level_view[:2]
+        T, _, M = nodes.shape
+        rows, W = cat_words.shape
+        widths = walk_widths(self.max_depth, M)
+        cat_trees = int((nodes[:, levels.CAT_SPLIT] >= 0).any(axis=1).sum())
+        by_node = sum(table_form(w) == "gather" for w in widths)
+        by_word = sum(table_form(max(w, min(w, rows) * W)) == "gather"
+                      for w in widths)
+        return dict(walk_levels=T * len(widths),
+                    walk_gather_levels=(cat_trees * by_word
+                                        + (T - cat_trees) * by_node))
+
+    @functools.cached_property
     def walk_form(self) -> str:
         """Which form of the walk this forest's tables select (the label of
         h2o3_forest_walk_total): the row's bin is always read by select;
-        `select` / `gather` says how a TPU reads the node tables (_at_node's
-        rule from M), `+cat` that some tree takes the categorical branch of
-        _walk_tree's cond."""
-        return table_form(self.feat.shape[1]) + (
-            "+cat" if (np.asarray(self.cat_split) >= 0).any() else "")
+        `select` / `gather` says how a TPU reads the widest table of the
+        walk, a level's (_at_node's rule from its static width), `+cat`
+        that some tree takes the categorical branch of _walk_tree's cond."""
+        nodes = self._level_view[0]
+        return ("gather" if self._walk_counts["walk_gather_levels"]
+                else "select") + (
+            "+cat" if (nodes[:, levels.CAT_SPLIT] >= 0).any() else "")
 
     def count_walk(self) -> None:
-        from h2o3_tpu.obs import metrics
+        """One dispatch of a walk program: h2o3_forest_walk_total{form},
+        and on the span open here (a job's `metrics`, a flush) the levels
+        it walks and how many of them gather, from the static widths: host
+        arithmetic, no device op."""
+        from h2o3_tpu.obs import metrics, tracing
 
         metrics.inc("h2o3_forest_walk_total", form=self.walk_form)
+        tracing.add_attrs(**self._walk_counts)
 
     def predict_binned(self, binned):
         """binned (N, F) integer bins (any width) → (N,) sums (regression/binomial margin) or
@@ -208,19 +249,23 @@ class CompressedForest:
         return out + self.init_f
 
     def leaf_index(self, binned):
-        """(N, T) leaf node id per tree (used by RuleFit/TreeSHAP/partial)."""
+        """(N, T) leaf node id per tree, as stored (used by
+        RuleFit/TreeSHAP/partial)."""
         fn = _leaf_fn(self.max_depth)
         self.count_walk()
         return fn(binned, *self.arrays())
 
 
-# node tables up to this many entries are read by compare-and-select over
-# the table axis (one fused reduce a table a level, cost by the row and the
-# entry); wider ones (DRF at depth 20: 10^4-10^5 nodes a tree) by a gather
-# from the (M,) table (cost by the row, ~6 ns a row a table). Measured on a
-# v5e, 1M rows (PERF.md §6, PR 27): select ahead 8x at 127 and 255 entries,
-# 6.5x at 1,023, 1.9x at 3,997, the largest measured; the lines would cross
-# near 8,000
+# tables up to this many entries (a level's slice of a tree's node tables,
+# its packed subset words, a tree's leaf values) are read by
+# compare-and-select over the table axis (one fused reduce a table, cost by
+# the row and the entry); wider ones (DRF from depth 13: 8,192 nodes a
+# level and more) by a gather from the table (cost by the row, ~6 ns a row
+# a table). Measured on a v5e, 1M rows (PERF.md §6, PR 27): select ahead 8x
+# at 127 and 255 entries, 6.5x at 1,023, 1.9x at 3,997, the largest
+# measured with five tables a read; the lines would cross near 8,000. One
+# table of 5,120 words reads 4 ns a row faster by select (PR 29, PR 33):
+# whoever moves the rule measures five tables at 8,192 too
 _SELECT_MAX_NODES = 4096
 
 
@@ -280,42 +325,73 @@ def _at_node(tables, node):
                                       default=_tables_by_select)
 
 
-def _step(node, tree, binned, cat_table, na_bins, with_cat: bool):
+def _step(node, start, tables, words, binned, na_bins):
     """One level of the lockstep walk: every row moves from `node` to its
-    child (a leaf stays). The ONE step margins, leaf ids and everything
-    built on them share. No operand of a gather here carries the row axis.
-    `with_cat` is static: the categorical lookup is traced only into the
-    walk of a tree that has a categorical split."""
+    child (a leaf stays, at this depth or above it). `tables` = this
+    level's slice of the tree's FEAT, THRESH, NA_LEFT, LEFT rows and, in a
+    tree with an enum split, CAT_SPLIT, from position `start`; `words` its
+    slice of cat_words, or None: the categorical lookup is traced only into
+    the walk of a tree that has one. The subset test is
+    device_tree._route's: bit b & 31 of the word at row * W + (b >> 5),
+    read through _at_node like the node tables. The ONE step margins, leaf
+    ids and everything built on them share. No operand of a gather here
+    carries the row axis, and none is two-dimensional."""
     import jax.numpy as jnp
 
-    at = _at_node(tree if with_cat else tree[:5], node)
-    f, t, na_goes_left, lft, rgt = at[:5]
+    local = node - start
+    at = _at_node(tables, local)
+    f, t, na_goes_left, lft = at[:4]
     b, is_na = _bin_at(binned, jnp.maximum(f, 0), na_bins)
     go_left = b <= t
-    if with_cat:
-        csid = at[5]
-        cat_left = cat_table[jnp.maximum(csid, 0),
-                             jnp.minimum(b, cat_table.shape[1] - 1)]
+    if words is not None:
+        csid = at[4]
+        W = words.shape[1]
+        bw = jnp.minimum(b, 32 * W - 1)
+        word, = _at_node((words.reshape(-1),),
+                         jnp.maximum(csid, 0) * W + (bw >> 5))
+        cat_left = (word >> (bw & 31).astype(jnp.uint32)) & 1 == 1
         go_left = jnp.where(csid >= 0, cat_left, go_left)
-    go_left = jnp.where(is_na, na_goes_left, go_left)
-    return jnp.where(f < 0, node, jnp.where(go_left, lft, rgt))
+    go_left = jnp.where(is_na, na_goes_left != 0, go_left)
+    return jnp.where((local < 0) | (f < 0), node,
+                     jnp.where(go_left, lft, lft + 1))
 
 
-def _walk_tree(binned, tree, cat_table, na_bins, max_depth: int):
-    """(N,) node id each row ends in after max_depth + 1 steps of one tree
-    (tree = its feat, thresh, na_left, left, right, cat_split rows). Which
-    of the two step forms runs is read from the tree itself, on the device:
-    a tree with no categorical split (every tree of a numeric forest) never
-    executes the (C, maxB) lookup it would only discard."""
+def _walk_tree(binned, nodes, starts, cat_words, na_bins, max_depth: int):
+    """(N,) position each row ends in after max_depth steps of one tree
+    (its (7, M) `nodes` and (2, max_depth) `starts` of level_view; at depth
+    max_depth every node is a leaf, so no step reads it). Step d reads
+    walk_widths' W_d entries of the node rows from LEVEL_START[d]; the
+    levels whose width has reached M have one shape and share one
+    fori_loop. Which of the two step forms runs is read from the tree
+    itself, on the device: a tree with no categorical split never executes
+    the lookup it would only discard. A forest that reaches no enum split
+    at all has no row in cat_words, a static shape: its program holds the
+    numeric walk alone (half the trace, no cond)."""
     import jax
     import jax.numpy as jnp
 
+    M = nodes.shape[1]
+    widths = walk_widths(max_depth, M)
+    narrow = sum(w < M for w in widths)
+
     def walk(with_cat: bool):
+        read = levels.CAT_SPLIT + 1 if with_cat else levels.LEFT + 1
+
+        def level(d, width, node):
+            start = starts[levels.LEVEL_START, d]
+            tables = jax.lax.dynamic_slice(nodes, (0, start), (read, width))
+            words = jax.lax.dynamic_slice_in_dim(
+                cat_words, starts[levels.CAT_START, d],
+                min(width, cat_words.shape[0])) if with_cat else None
+            return _step(node, start, tuple(tables), words, binned, na_bins)
+
         def run(node):
-            return jax.lax.fori_loop(
-                0, max_depth + 1,
-                lambda _, n: _step(n, tree, binned, cat_table, na_bins,
-                                   with_cat), node)
+            for d in range(narrow):
+                node = level(d, widths[d], node)
+            if narrow < max_depth:
+                node = jax.lax.fori_loop(
+                    narrow, max_depth, lambda d, n: level(d, M, n), node)
+            return node
         return run
 
     # the carry is derived from `binned` so it carries its type: under
@@ -323,28 +399,36 @@ def _walk_tree(binned, tree, cat_table, na_bins, max_depth: int):
     # would not, which the loop carry check rejects; under plain jit this
     # is the same zeros
     node0 = jnp.zeros_like(binned[:, 0], dtype=jnp.int32)
-    *_, cat_split = tree
-    return jax.lax.cond(jnp.any(cat_split >= 0), walk(True), walk(False),
-                        node0)
+    if cat_words.shape[0] == 0:
+        return walk(False)(node0)
+    # the barrier keeps what reads the position out of the cond: the TPU
+    # compiler otherwise moves a select's broadcast of it over a table's M
+    # entries into both branches, whose output is then (N, M) in memory
+    return jax.lax.optimization_barrier(jax.lax.cond(
+        jnp.any(nodes[levels.CAT_SPLIT] >= 0), walk(True), walk(False),
+        node0))
 
 
-def _forest_margins(binned, feat, thresh, na_left, left, right, leaf_val,
-                    cat_split, cat_table, tree_class, na_bins,
+def _forest_margins(binned, nodes, cat_words, tree_class, na_bins, starts,
                     max_depth: int, K: int):
     """Traceable core of the lockstep traversal: (N, F) integer bins →
-    (N,) / (N, K) leaf-value sums. Shared verbatim by the per-request
-    traversal (_traverse_fn) and the serving fast path's fused program
-    (_fused_score_fn) so both produce bitwise-identical margins."""
+    (N,) / (N, K) leaf-value sums (the tables: WALK_ARGS). Shared verbatim
+    by the per-request traversal (_traverse_fn) and the serving fast
+    path's fused program (_fused_score_fn) so both produce
+    bitwise-identical margins."""
     import jax
     import jax.numpy as jnp
 
     N = binned.shape[0]
 
     def walk_one_tree(acc, tree):
-        tf, tt, tnl, tl, tr, tlv, tcs, tcls = tree
-        node = _walk_tree(binned, (tf, tt, tnl, tl, tr, tcs), cat_table,
-                          na_bins, max_depth)
-        contrib = tlv[node]
+        tnodes, tstarts, tcls = tree
+        node = _walk_tree(binned, tnodes, tstarts, cat_words, na_bins,
+                          max_depth)
+        # the leaf's value by _at_node's rule too, as its bits: a sum over
+        # floats would turn a leaf of -0.0 into 0.0
+        bits, = _at_node((tnodes[levels.LEAF_BITS],), node)
+        contrib = jax.lax.bitcast_convert_type(bits, jnp.float32)
         if K > 1:
             acc = acc.at[:, tcls].add(contrib)
         else:
@@ -356,24 +440,17 @@ def _forest_margins(binned, feat, thresh, na_left, left, right, leaf_val,
     if K > 1:
         acc0 = jnp.broadcast_to(acc0[:, None], (N, K))
     with jax.named_scope("walk"):      # metadata: device time by scope
-        acc, _ = jax.lax.scan(
-            walk_one_tree, acc0,
-            (feat, thresh, na_left, left, right, leaf_val, cat_split,
-             tree_class))
+        acc, _ = jax.lax.scan(walk_one_tree, acc0,
+                              (nodes, starts, tree_class))
     return acc
 
 
 @functools.lru_cache(maxsize=32)
 def _traverse_fn(max_depth: int, nclasses: int, per_class: bool = False):
-    import jax
-
     K = nclasses if (nclasses > 2 or per_class) else 1
 
-    def run(binned, feat, thresh, na_left, left, right, leaf_val,
-            cat_split, cat_table, tree_class, na_bins):
-        return _forest_margins(binned, feat, thresh, na_left, left, right,
-                               leaf_val, cat_split, cat_table, tree_class,
-                               na_bins, max_depth, K)
+    def run(binned, *forest):
+        return _forest_margins(binned, *forest, max_depth, K)
 
     from h2o3_tpu.obs import compiles
 
@@ -399,40 +476,42 @@ def _bin_features(X, edges, is_cat, na_bins):
     return jnp.where(is_cat[None, :], cat_b, num_b)
 
 
-def _forest_leaves(binned, feat, thresh, na_left, left, right, cat_split,
-                   cat_table, na_bins, max_depth: int):
+def _forest_leaves(binned, nodes, cat_words, tree_class, na_bins, starts,
+                   max_depth: int):
     """Traceable leaf-walk core: (N, F) integer bins → (N, T) leaf node
-    ids. The walk is _forest_margins' own (_walk_tree), so the leaf a row
-    lands in is by construction the leaf whose value the margin summed —
-    shared by the per-request _leaf_fn and the fused leaf programs."""
+    ids, the stored ones (one more _at_node read a tree, of STORED_ID at
+    the position the walk ends in). The walk is _forest_margins' own
+    (_walk_tree), so the leaf a row lands in is by construction the leaf
+    whose value the margin summed — shared by the per-request _leaf_fn and
+    the fused leaf programs."""
     import jax
     import jax.numpy as jnp
 
     def walk(carry, tree):
-        return carry, _walk_tree(binned, tree, cat_table, na_bins, max_depth)
+        tnodes, tstarts = tree
+        node = _walk_tree(binned, tnodes, tstarts, cat_words, na_bins,
+                          max_depth)
+        return carry, _at_node((tnodes[levels.STORED_ID],), node)[0]
 
-    _, leaves = jax.lax.scan(
-        walk, None, (feat, thresh, na_left, left, right, cat_split))
+    _, leaves = jax.lax.scan(walk, None, (nodes, starts))
     return jnp.transpose(leaves)       # (N, T)
 
 
-def _fused_margins(X, edges, is_cat, init, feat, thresh, na_left, left,
-                   right, leaf_val, cat_split, cat_table, tree_class,
-                   na_bins, max_depth: int, K: int):
+def _fused_margins(X, edges, is_cat, init, *forest_depth_k):
     """Traceable fused bin + traverse + init core: (N, F) raw float32
-    features → (N,) / (N, K) margins. Shared verbatim by the jit serving
-    path (_fused_score_fn) and the shard_map'd sharded-data-plane path
+    features → (N,) / (N, K) margins; after `init` the WALK_ARGS tables,
+    then max_depth and K. Shared verbatim by the jit serving path
+    (_fused_score_fn) and the shard_map'd sharded-data-plane path
     (_fused_score_sharded_fn) — every op is row-local, so the two lower to
     bitwise-identical per-row programs. Binning is _bin_features (the
     BinSpec.bin_columns-bitwise core)."""
     import jax
 
+    *forest, max_depth, K = forest_depth_k
     with jax.named_scope("bin"):
-        binned = _bin_features(X, edges, is_cat, na_bins)
-    acc = _forest_margins(binned, feat, thresh, na_left, left, right,
-                          leaf_val, cat_split, cat_table, tree_class,
-                          na_bins, max_depth, K)
-    return acc + init
+        binned = _bin_features(X, edges, is_cat,
+                               forest[WALK_ARGS.index("na_bins")])
+    return _forest_margins(binned, *forest, max_depth, K) + init
 
 
 @functools.lru_cache(maxsize=32)
@@ -443,15 +522,10 @@ def _fused_score_fn(max_depth: int, nclasses: int, per_class: bool = False):
     their integer codes, NA as NaN for numerics / negative for cats) plus
     the BinSpec tables, so the per-request host work is a single
     device_put."""
-    import jax
-
     K = nclasses if (nclasses > 2 or per_class) else 1
 
-    def run(X, edges, is_cat, init, feat, thresh, na_left, left, right,
-            leaf_val, cat_split, cat_table, tree_class, na_bins):
-        return _fused_margins(X, edges, is_cat, init, feat, thresh,
-                              na_left, left, right, leaf_val, cat_split,
-                              cat_table, tree_class, na_bins, max_depth, K)
+    def run(X, edges, is_cat, init, *forest):
+        return _fused_margins(X, edges, is_cat, init, *forest, max_depth, K)
 
     from h2o3_tpu.obs import compiles
 
@@ -469,20 +543,16 @@ def _fused_score_sharded_fn(max_depth: int, nclasses: int, per_class: bool,
     communication inside the program — each process scores only its
     addressable shards, and margins come back row-sharded for the single
     gather that assembles the prediction frame."""
-    import jax
     from jax.sharding import PartitionSpec as P
 
     from h2o3_tpu.compat import shard_map as _compat_shard_map
 
     K = nclasses if (nclasses > 2 or per_class) else 1
 
-    def run(X, edges, is_cat, init, feat, thresh, na_left, left, right,
-            leaf_val, cat_split, cat_table, tree_class, na_bins):
-        return _fused_margins(X, edges, is_cat, init, feat, thresh,
-                              na_left, left, right, leaf_val, cat_split,
-                              cat_table, tree_class, na_bins, max_depth, K)
+    def run(X, edges, is_cat, init, *forest):
+        return _fused_margins(X, edges, is_cat, init, *forest, max_depth, K)
 
-    in_specs = (P("rows", None),) + (P(),) * 13
+    in_specs = (P("rows", None),) + (P(),) * (3 + len(WALK_ARGS))
     out_specs = P("rows", None) if K > 1 else P("rows")
     fn = _compat_shard_map(run, mesh=mesh, in_specs=in_specs,
                            out_specs=out_specs)
@@ -493,41 +563,32 @@ def _fused_score_sharded_fn(max_depth: int, nclasses: int, per_class: bool,
 
 @functools.lru_cache(maxsize=8)
 def _leaf_fn(max_depth: int):
-    import jax
-
-    def run(binned, feat, thresh, na_left, left, right, leaf_val,
-            cat_split, cat_table, tree_class, na_bins):
-        return _forest_leaves(binned, feat, thresh, na_left, left, right,
-                              cat_split, cat_table, na_bins, max_depth)
+    def run(binned, *forest):
+        return _forest_leaves(binned, *forest, max_depth)
 
     from h2o3_tpu.obs import compiles
 
     return compiles.ledgered_jit("tree", run, program="forest_leaves")
 
 
-def _fused_leaves(X, edges, is_cat, feat, thresh, na_left, left, right,
-                  cat_split, cat_table, na_bins, max_depth: int):
+def _fused_leaves(X, edges, is_cat, *forest_depth):
     """Traceable fused bin + leaf-walk core: (N, F) raw float32 features →
     (N, T) leaf node ids — the explainability twin of _fused_margins
     (leaf assignment, staged probabilities, RuleFit paths). Binning and
     walk are the SAME cores serving uses, so
     leaf = spec.bin_columns + forest.leaf_index bitwise."""
-    binned = _bin_features(X, edges, is_cat, na_bins)
-    return _forest_leaves(binned, feat, thresh, na_left, left, right,
-                          cat_split, cat_table, na_bins, max_depth)
+    *forest, max_depth = forest_depth
+    binned = _bin_features(X, edges, is_cat,
+                           forest[WALK_ARGS.index("na_bins")])
+    return _forest_leaves(binned, *forest, max_depth)
 
 
 @functools.lru_cache(maxsize=32)
 def _fused_leaf_fn(max_depth: int):
     """Explainability fast path: binning + leaf walk in ONE program over a
     bucketed (N, F) raw feature matrix (host-packed serving layout)."""
-    import jax
-
-    def run(X, edges, is_cat, feat, thresh, na_left, left, right,
-            cat_split, cat_table, na_bins):
-        return _fused_leaves(X, edges, is_cat, feat, thresh, na_left, left,
-                             right, cat_split, cat_table, na_bins,
-                             max_depth)
+    def run(X, edges, is_cat, *forest):
+        return _fused_leaves(X, edges, is_cat, *forest, max_depth)
 
     from h2o3_tpu.obs import compiles
 
@@ -540,18 +601,14 @@ def _fused_leaf_sharded_fn(max_depth: int, mesh):
     shard under shard_map over the named 'rows' axis (every op is
     row-local — no cross-shard communication; leaves come back
     row-sharded (N, T))."""
-    import jax
     from jax.sharding import PartitionSpec as P
 
     from h2o3_tpu.compat import shard_map as _compat_shard_map
 
-    def run(X, edges, is_cat, feat, thresh, na_left, left, right,
-            cat_split, cat_table, na_bins):
-        return _fused_leaves(X, edges, is_cat, feat, thresh, na_left, left,
-                             right, cat_split, cat_table, na_bins,
-                             max_depth)
+    def run(X, edges, is_cat, *forest):
+        return _fused_leaves(X, edges, is_cat, *forest, max_depth)
 
-    in_specs = (P("rows", None),) + (P(),) * 10
+    in_specs = (P("rows", None),) + (P(),) * (2 + len(WALK_ARGS))
     fn = _compat_shard_map(run, mesh=mesh, in_specs=in_specs,
                            out_specs=P("rows", None))
     from h2o3_tpu.obs import compiles

@@ -483,8 +483,10 @@ def _install_default_metrics() -> None:
     r.counter("h2o3_forest_walk_total",
               "dispatches of a forest-walk program (predict_binned, "
               "leaf_index, the scoring session's fused programs), by form: "
-              "select | gather = how a TPU reads the node tables, +cat = some tree "
-              "takes the categorical branch")
+              "select | gather = how a TPU reads the widest table of the "
+              "walk, a level's (its node tables, or the packed subset words "
+              "of its enum splits), +cat = some tree takes the categorical "
+              "branch")
     r.counter("h2o3_backend_compiles_total",
               "XLA backend compiles seen by jax.monitoring: ledgered call "
               "sites, bare jits and eager ops alike")

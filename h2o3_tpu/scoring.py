@@ -589,8 +589,6 @@ class ScoringSession:
         if n <= 0:
             return np.zeros((0, self.forest.n_trees), np.int32)
         maxb = self.buckets[-1]
-        a = self._arrays
-        tail = (a[0], a[1], a[2], a[3], a[4], a[6], a[7], a[9])
         outs: List[Any] = []
         sf = self._sharded_view(adapted)
         if sf is None and jax.process_count() > 1:
@@ -616,7 +614,8 @@ class ScoringSession:
             def window(pos: int, m: int):
                 bucket = self._bucket_for(m)
                 Xd = sf.pack_features(pos, n, bucket)
-                call_args = (Xd, self._edges, self._is_cat) + tail
+                call_args = (Xd, self._edges, self._is_cat) + \
+                    tuple(self._arrays)
                 exe = self._executable_for(bucket, False, call_args,
                                            sharded=True, kind="leaf")
                 with tracing.span("dispatch", bucket=bucket, rows=m,
@@ -640,7 +639,8 @@ class ScoringSession:
                 buf = np.zeros((bucket, X.shape[1]), np.float32)
                 buf[:m] = X[pos: pos + m]
                 xd = jax.device_put(buf, sharding)
-                call_args = (xd, self._edges, self._is_cat) + tail
+                call_args = (xd, self._edges, self._is_cat) + \
+                    tuple(self._arrays)
                 exe = self._executable_for(bucket, False, call_args,
                                            kind="leaf")
                 with tracing.span("dispatch", bucket=bucket, rows=m,

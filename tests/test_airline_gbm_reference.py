@@ -246,8 +246,10 @@ def test_counters_and_spans_of_an_enum_fit(traced_and_plain, cfg, what):
             "enum": enum, "numeric": int(internal.sum()) - enum}
         assert enum > internal.sum() / 2
     elif what == "walk":
-        assert fo.walk_form == "select+cat"
-        assert set(moved["h2o3_forest_walk_total"]) == {"select+cat"}
+        # the widest read of the walk is depth 9's: 512 subsets of ten
+        # packed words, past _SELECT_MAX_NODES
+        assert fo.walk_form == "gather+cat"
+        assert set(moved["h2o3_forest_walk_total"]) == {"gather+cat"}
     elif what == "trace_changes_nothing":
         assert moved_traced == moved
         assert not moved.get("h2o3_backend_compiles_total")
@@ -260,3 +262,7 @@ def test_counters_and_spans_of_an_enum_fit(traced_and_plain, cfg, what):
         assert by_name["assemble"]["enum_splits"] == enum
         assert by_name["assemble"]["nodes"] == \
             2 * int(internal.sum()) + fo.n_trees
+        # the metrics pass walks two trees of ten levels; depth 9's packed
+        # words are gathered in each
+        assert by_name["metrics"]["walk_levels"] == 20
+        assert by_name["metrics"]["walk_gather_levels"] == 2

@@ -279,6 +279,34 @@ class TestCorruptArtifactRejection:
         with pytest.raises(artifact.ArtifactError, match="format_version"):
             artifact.describe(art)
 
+    @pytest.mark.parametrize("forest_args", [
+        None,                  # as the parent wrote a manifest: no such key
+        ["feat", "thresh_bin", "na_left", "left", "right", "leaf_val",
+         "cat_split", "cat_table", "tree_class", "na_bins"]])
+    def test_programs_of_another_forest_layout_refused(self, cl, gbm,
+                                                       tmp_path, forest_args):
+        """An artifact whose programs were lowered for the stored arrays
+        (before the level-ordered walk) is refused by the standalone runner
+        with a message that says what to do, on the serialized executable
+        and the StableHLO alike, never called with the level view bound to
+        the old positions. A serving cloud still imports it: it reads the
+        stored arrays and compiles its own programs."""
+        from h2o3_genmodel.aot import ArtifactError, load_artifact
+        from h2o3_tpu import artifact
+
+        art = self._export(gbm, tmp_path)
+        mpath = os.path.join(art, "manifest.json")
+        m = json.load(open(mpath))
+        assert m.pop("forest_args") == ["nodes", "cat_words", "tree_class",
+                                        "na_bins", "starts"]
+        if forest_args is not None:
+            m["forest_args"] = forest_args
+        json.dump(m, open(mpath, "w"))
+        with pytest.raises(ArtifactError, match="re-export"):
+            load_artifact(art)
+        assert artifact.load_model(art, install=False).forest.n_trees == \
+            gbm.forest.n_trees
+
     def test_path_traversal_in_manifest_rejected(self, cl, gbm, tmp_path):
         from h2o3_tpu import artifact
 

@@ -1,7 +1,9 @@
-"""The forest walk (ISSUE 27): margins and leaf ids of the device traversal
-are bitwise those of a row-at-a-time walk on the host, over every kind of
-forest that runs the one step (`compressed._step`); the step gathers from no
-operand that carries the rows; `h2o3_forest_walk_total` says which form ran.
+"""The forest walk (ISSUE 27, level by level since ISSUE 33): margins and
+leaf ids of the device traversal are bitwise those of a row-at-a-time walk
+on the host over the stored arrays, over every kind of forest that runs the
+one step (`compressed._step`); step d reads depth d's entries alone, the
+enum subset as one bit of a packed word; the step gathers from no operand
+that carries the rows; `h2o3_forest_walk_total` says which form ran.
 
 Tiny frames on the CPU mesh: what is asserted is equality and structure,
 never a time."""
@@ -17,10 +19,12 @@ N = 236          # rows; no table of any case below has this many entries
 
 
 def _forest(seed, F, na_bins, depth, *, split_p=1.0, cat_feats=(), T=5,
-            K=1):
+            K=1, order="random", leaf_at_2=False):
     """A random forest as CompressedForest holds it. Node ids are handed
-    out in the order nodes are reached, so left/right are not 2m+1/2m+2;
-    `split_p` < 1 leaves branches short (uneven trees); a split on a
+    out in the order nodes are reached (`order`: at random, depth first, or
+    a level at a time as tree_program stores them), so left/right are not
+    2m+1/2m+2; `split_p` < 1 leaves branches short (uneven trees);
+    `leaf_at_2` makes the first node of depth 2 a leaf; a split on a
     feature in `cat_feats` is a categorical subset over all of its bins."""
     rng = np.random.default_rng(seed)
     maxB = int(na_bins.max()) + 1
@@ -28,9 +32,15 @@ def _forest(seed, F, na_bins, depth, *, split_p=1.0, cat_feats=(), T=5,
     for _ in range(T):
         nodes = [dict(depth=0)]
         todo = [0]
+        early = leaf_at_2
         while todo:
-            m = todo.pop(rng.integers(len(todo)))
+            m = todo.pop({"random": rng.integers(len(todo)), "dfs": -1,
+                          "level": 0}[order])
             nd = nodes[m]
+            if early and nd["depth"] == 2:
+                early = False
+                nd["leaf"] = np.float32(rng.standard_normal())
+                continue
             if nd["depth"] >= depth or (m and rng.random() > split_p):
                 nd["leaf"] = np.float32(rng.standard_normal())
                 continue
@@ -113,7 +123,21 @@ def _same_bits(a, b):
         a.tobytes() == b.tobytes()
 
 
-# name -> (forest kwargs, dtype of the bin matrix, the form it must count as)
+AIRLINE_NA = np.array([12, 31, 7, 22, 300, 300, 100, 100])
+
+
+def _after_concat(seed):
+    """A categorical forest with a numeric one of another width appended
+    (checkpoint continuation): b's subset rows shift, the tables pad."""
+    a = _forest(seed, F=4, na_bins=np.array([20, 300, 20, 40]), depth=4,
+                cat_feats=(1, 3), T=3, split_p=.8)
+    b = _forest(seed + 1, F=4, na_bins=np.array([20, 300, 20, 40]), depth=6,
+                cat_feats=(3,), T=4, split_p=.9)
+    return CompressedForest.concat(a, b)
+
+
+# name -> (forest kwargs or a maker, dtype of the bin matrix, the form it
+# must count as)
 CASES = {
     "higgs_d5_u8": (dict(F=28, na_bins=np.full(28, 20), depth=5), np.uint8,
                     "select"),
@@ -132,61 +156,160 @@ CASES = {
     "uneven_d9_hundreds_of_nodes": (dict(F=7, na_bins=np.full(7, 20),
                                          depth=9, split_p=.9, T=4),
                                     np.uint8, "select"),
+    # no level above depth 13 is wider than 4,096: the whole tree is
     "deep_uneven_d13": (dict(F=7, na_bins=np.full(7, 20), depth=13,
-                             split_p=.97, T=3), np.uint8, "gather"),
+                             split_p=.97, T=3), np.uint8, "select"),
+    "deep_uneven_d14": (dict(F=7, na_bins=np.full(7, 20), depth=14,
+                             split_p=.97, T=2), np.uint8, "gather"),
     "one_feature": (dict(F=1, na_bins=np.array([20]), depth=3), np.uint8,
                     "select"),
     "f300": (dict(F=300, na_bins=np.full(300, 20), depth=5), np.uint8,
              "select"),
+    # ISSUE 33: what the level view has to get right
+    "stored_depth_first": (dict(F=6, na_bins=np.array([20] * 5 + [300]),
+                                depth=6, cat_feats=(5,), T=4, split_p=.85,
+                                order="dfs"), np.int16, "select+cat"),
+    "stored_level_order": (dict(F=6, na_bins=np.array([20] * 5 + [300]),
+                                depth=6, cat_feats=(5,), T=4, split_p=.85,
+                                order="level"), np.int16, "select+cat"),
+    "leaf_at_depth_2": (dict(F=5, na_bins=np.full(5, 20), depth=7, T=3,
+                             leaf_at_2=True), np.uint8, "select"),
+    "saturated_widths": (dict(F=5, na_bins=np.array([20, 20, 300, 20, 20]),
+                              depth=11, cat_feats=(2,), T=4, split_p=.62),
+                         np.int16, "select+cat"),
+    "after_concat": (_after_concat, np.int16, "select+cat"),
+    "airline_int16_301_bins": (dict(F=8, na_bins=AIRLINE_NA, depth=7,
+                                    cat_feats=range(6), T=2, split_p=.95),
+                               np.int16, "select+cat"),
+    "cat_1025_bins_d8": (dict(F=3, na_bins=np.array([1024, 20, 700]),
+                              depth=8, cat_feats=(0, 2), T=3, split_p=.93),
+                         np.int16, "gather+cat"),
+    "multinomial_k3_cat": (dict(F=5, na_bins=np.array([16, 16, 40, 16, 16]),
+                                depth=5, cat_feats=(2,), T=9, K=3,
+                                split_p=.8), np.uint8, "select+cat"),
 }
+
+
+def _case(name):
+    """(forest, bins of every feature with its NA bin, dtype, form)."""
+    kw, dtype, form = CASES[name]
+    seed = sum(map(ord, name))
+    fo = kw(seed) if callable(kw) else _forest(seed, **kw)
+    return fo, _bins(1, np.asarray(fo.na_bins), dtype), form
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_walk_is_bitwise_the_host_walk(name):
-    kw, dtype, form = CASES[name]
-    fo = _forest(sum(map(ord, name)), **kw)
-    binned = _bins(1, kw["na_bins"], dtype)
+    fo, binned, form = _case(name)
     want_margin, want_leaves = _host_walk(fo, binned)
     assert fo.walk_form == form
+    M = fo.feat.shape[1]
     if name == "uneven_d9_hundreds_of_nodes":
-        assert 200 < fo.feat.shape[1] <= compressed._SELECT_MAX_NODES
-    if name == "deep_uneven_d13":        # the other side of _at_node's rule
-        assert fo.feat.shape[1] > compressed._SELECT_MAX_NODES
+        assert 200 < M <= compressed._SELECT_MAX_NODES
+    if name in ("deep_uneven_d13", "deep_uneven_d14"):
+        # the rule is a level's now: both trees are wider than it, one has
+        # a level that is
+        assert M > compressed._SELECT_MAX_NODES
+        assert (max(compressed.walk_widths(fo.max_depth, M))
+                > compressed._SELECT_MAX_NODES) == (form == "gather")
     if name == "cat_and_numeric_trees":      # both sides of the cond
         has_cat = (fo.cat_split >= 0).any(axis=1)
         assert has_cat.any() and not has_cat.all()
+    nodes = fo._level_view[compressed.WALK_ARGS.index("nodes")]
+    identity = (nodes[:, compressed.levels.STORED_ID] == np.arange(M)).all()
+    if name == "stored_depth_first":
+        assert not identity
+    if name == "stored_level_order":
+        assert identity
+    if name == "saturated_widths":           # levels that share the loop
+        assert M < 2 ** (fo.max_depth - 2)
+    if name == "leaf_at_depth_2":
+        assert (want_leaves.min(axis=0) < 7).all()    # rows stop there ...
+        assert fo.max_depth == 7 and M > 2 ** 6       # ... beside full paths
+    if name == "after_concat":
+        assert fo.n_trees == 7 and (fo.cat_split[3:] >= 0).any()
     assert _same_bits(fo.predict_binned(binned), want_margin)
     assert _same_bits(fo.leaf_index(binned), want_leaves)
 
 
-@pytest.mark.parametrize("name", ["higgs_d5_u8", "cat_and_numeric_trees",
-                                  "uneven_d9_hundreds_of_nodes"])
+SELECT_CASES = ["higgs_d5_u8", "cat_and_numeric_trees",
+                "uneven_d9_hundreds_of_nodes", "stored_depth_first",
+                "saturated_widths", "after_concat",
+                "airline_int16_301_bins", "multinomial_k3_cat"]
+
+
+@pytest.mark.parametrize("name", SELECT_CASES)
 def test_tables_by_select_walk_like_the_host(name, monkeypatch):
-    """The TPU's form of the node-table lookup (XLA:CPU lowers the gather
-    form: _at_node), run here on the CPU in the CPU form's place, in fresh
+    """The TPU's form of the table lookups (XLA:CPU lowers the gather form:
+    _at_node), run here on the CPU in the CPU form's place, in fresh
     programs: the same bits."""
     import jax
 
     monkeypatch.setattr(compressed, "_tables_by_gather",
                         compressed._tables_by_select)
-    kw, dtype, _ = CASES[name]
-    fo = _forest(sum(map(ord, name)), **kw)
-    binned = _bins(1, kw["na_bins"], dtype)
+    fo, binned, _ = _case(name)
+    K = fo.nclasses if fo.per_class_trees else 1
     want_margin, want_leaves = _host_walk(fo, binned)
     a = fo.arrays()
     jaxpr = jax.make_jaxpr(lambda b, *a: compressed._forest_margins(
-        b, *a, fo.max_depth, 1))(binned, *a).jaxpr
-    gathered = [e.invars[0].aval for e in _eqns(jaxpr)
-                if e.primitive.name == "gather"]
-    assert gathered and all(            # leaf values and cat_table only
-        v.ndim == 2 or v.dtype == np.float32 for v in gathered), gathered
+        b, *a, fo.max_depth, K))(binned, *a).jaxpr
+    assert not [e.invars[0].aval for e in _eqns(jaxpr)      # the leaf's
+                if e.primitive.name == "gather"]            # value too
     got = jax.jit(lambda b, *a: compressed._forest_margins(
-        b, *a, fo.max_depth, 1))(binned, *a)
+        b, *a, fo.max_depth, K))(binned, *a)
     assert _same_bits(got, want_margin)
     got = jax.jit(lambda b, *a: compressed._forest_leaves(
-        b, a[0], a[1], a[2], a[3], a[4], a[6], a[7], a[9],
-        fo.max_depth))(binned, *a)
+        b, *a, fo.max_depth))(binned, *a)
     assert _same_bits(got, want_leaves)
+
+
+@pytest.mark.parametrize("program,cat", [("margins", True), ("leaves", True),
+                                         ("margins", False)])
+def test_step_d_compares_with_depth_d_alone(program, cat, monkeypatch):
+    """The structure ISSUE 33's speed rests on, in the TPU form's jaxpr: a
+    compare over a table axis is as wide as the level (min(2^d, M) entries
+    of the node tables, as many rows of W packed words or all there are),
+    once a step in
+    each branch of the cond, never the whole tree's M; and no gather reads
+    a two-dimensional table (the (C, maxB) cat_table is gone from the
+    program). So the walk cannot silently go back to whole-tree tables. A
+    forest that reaches no enum split has no row of words, and its program
+    neither the cond nor its second branch: one trace of a step a level."""
+    import collections
+
+    import jax
+
+    monkeypatch.setattr(compressed, "_tables_by_gather",
+                        compressed._tables_by_select)
+    depth, F = 6, 7
+    fo = _forest(9, F=F, na_bins=np.array([20] * 6 + [300]), depth=depth,
+                 cat_feats=(6,) if cat else (), T=3)
+    M = fo.feat.shape[1]
+    C, W = fo._level_view[compressed.WALK_ARGS.index("cat_words")].shape
+    assert M == 2 ** (depth + 1) - 1 and W == 10       # full trees
+    assert (C > 0) == cat
+    binned = _bins(4, np.asarray(fo.na_bins), np.int16)
+    fn = ((lambda b, *a: compressed._forest_margins(b, *a, depth, 1))
+          if program == "margins" else
+          (lambda b, *a: compressed._forest_leaves(b, *a, depth)))
+    eqns = list(_eqns(jax.make_jaxpr(fn)(binned, *fo.arrays()).jaxpr))
+    branches = 2 if cat else 1
+    widths = collections.Counter(
+        e.outvars[0].aval.shape[1] for e in eqns
+        if e.primitive.name == "eq" and e.outvars[0].aval.ndim == 2
+        and e.outvars[0].aval.shape[0] == N)
+    bin_reads = widths.pop(F)            # _bin_at: the hit mask and the NA
+    assert bin_reads == 2 * branches * depth     # test, a step, a branch
+    # _at_node traces its two platform forms, and both are the select here
+    want = collections.Counter()
+    for d in range(depth):
+        want[2 ** d] += 2 * branches     # node tables
+        if cat:
+            want[min(2 ** d, C) * W] += 2    # packed words: the cat branch
+    want[M] += 2         # once a tree: the leaf's value, or its stored id
+    assert widths == want
+    assert not [e.invars[0].aval for e in eqns if e.primitive.name ==
+                "gather" and e.invars[0].aval.ndim != 1]
 
 
 def _host_bin(X, edges, is_cat, na_bins):
@@ -237,10 +360,7 @@ def test_shard_map_programs_walk_like_the_host(K):
     assert len(got.sharding.device_set) == 4
     assert _same_bits(got, want_margin)
     leaf = compressed._fused_leaf_sharded_fn(fo.max_depth, mesh)
-    feat, thresh, na_left, left, right, _, cat_split, cat_table, _, nb = \
-        arrays
-    got = leaf(Xd, *tables, feat, thresh, na_left, left, right, cat_split,
-               cat_table, nb)
+    got = leaf(Xd, *tables, *arrays)
     assert _same_bits(got, want_leaves)
 
 
@@ -259,9 +379,9 @@ def _eqns(jaxpr):
 def test_no_gather_operand_carries_the_rows(program):
     """The structure the speed rests on: in the traversal of a numeric
     forest no `gather` reads from an array whose leading dimension is the
-    row count (a per-row gather has no hardware on a TPU). Gathers from the
-    (M,) leaf values and, in the other branch of the cond, from cat_table
-    stay."""
+    row count (a per-row gather has no hardware on a TPU). What XLA:CPU
+    gathers from is a level's slice of a (M,) table, the packed words and
+    the leaf values."""
     import jax
 
     kw, dtype, _ = CASES["higgs_d5_u8"]
@@ -273,7 +393,7 @@ def test_no_gather_operand_carries_the_rows(program):
     names = {e.primitive.name for e in eqns}
     assert {"scan", "cond"} <= names
     gathers = [e for e in eqns if e.primitive.name == "gather"]
-    assert gathers                       # cat_table's, in the cat branch
+    assert gathers                       # the CPU form of _at_node
     assert all(e.invars[0].aval.shape[0] != N for e in gathers), \
         [e.invars[0].aval for e in gathers]
 
@@ -291,18 +411,27 @@ def _delta(before):
 
 
 def test_walk_counter_names_the_form():
-    kw, dtype, _ = CASES["higgs_d5_u8"]
-    numeric, bn = _forest(1, **kw), _bins(3, kw["na_bins"], dtype, 16)
-    kw, dtype, _ = CASES["cat_bins_above_255"]
-    cat, bc = _forest(2, **kw), _bins(3, kw["na_bins"], dtype, 16)
-    kw, dtype, _ = CASES["deep_uneven_d13"]
-    deep, bd = _forest(3, **kw), _bins(3, kw["na_bins"], dtype, 16)
+    """`select` | `gather` is the widest read of the walk, a level's:
+    4,096 nodes at depth 12 are selected, 8,192 at depth 13 gathered, and
+    so are 128 subset rows of 33 words."""
+    numeric, bn, _ = _case("higgs_d5_u8")
+    cat, bc, _ = _case("cat_bins_above_255")
+    deep, bd, _ = _case("deep_uneven_d14")
+    wide, bw, _ = _case("cat_1025_bins_d8")
     before = _walks()
-    numeric.predict_binned(bn)
-    numeric.leaf_index(bn)
-    cat.predict_binned(bc)
-    deep.leaf_index(bd)
-    assert _delta(before) == {"select": 2, "select+cat": 1, "gather": 1}
+    numeric.predict_binned(bn[:16])
+    numeric.leaf_index(bn[:16])
+    cat.predict_binned(bc[:16])
+    deep.leaf_index(bd[:16])
+    wide.predict_binned(bw[:16])
+    assert _delta(before) == {"select": 2, "select+cat": 1, "gather": 1,
+                              "gather+cat": 1}
+    # what count_walk gives the open span: trees x levels, from widths
+    assert deep._walk_counts == dict(walk_levels=2 * 14,
+                                     walk_gather_levels=2)      # depth 13
+    assert wide._walk_counts == dict(walk_levels=3 * 8,
+                                     walk_gather_levels=3)      # depth 7
+    assert numeric._walk_counts["walk_gather_levels"] == 0
 
 
 def test_walk_counter_counts_session_dispatches_and_adds_none(cl):
@@ -367,42 +496,81 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+# rows, F, bins' dtype, T, M, subset rows, maxB, depth, scratch bytes a row.
+# No subset row is a forest that reaches no enum split (the cell's own
+# program: the numeric walk alone); the depth-20 shape has one, so that both
+# branches of the cond hold the loop over its saturated levels. The scratch
+# bound of a shape is what it measured compiled for a v5e (24.1, 36.4 and
+# 100.2 B a row: PERF.md §6, PR 33; the parent 33 at the first) with less
+# room above it than one more (N, F) copy of the bins in their own dtype
+# takes (28, 16, 28 B a row).
+CHIP_SHAPES = {
+    "higgs_gbm_d5": (16_000_000, 28, "uint8", 5, 63, 0, 21, 5, 40),
+    "airline_gbm_d10": (16_000_000, 8, "int16", 2, 2047, 2000, 301, 10, 48),
+    "drf_d20_m20000": (16_000_000, 28, "uint8", 5, 20_000, 1, 21, 20, 112),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(CHIP_SHAPES))
 def test_training_metrics_walk_compiles_for_the_chip_without_a_row_gather(
-        one_chip):
-    """`gbm_train`'s training-metrics traversal at its real shapes (16M x 28
-    u8 bins, 5 trees of 63 nodes), compiled for a v5e: the select over the
-    feature axis fuses into reduces over the bin matrix as it lies (no
-    (N, 28) intermediate: the program's scratch stays under one more copy
-    of the matrix, 0.53 GB is the loop carries), and no gather reads it."""
+        shape, one_chip):
+    """The training-metrics traversal at real shapes, compiled for a v5e:
+    `gbm_train`'s (16M x 28 u8 bins, 5 trees of 63 nodes),
+    `airline_gbm_d10`'s (16M x 8 int16, 2 trees of 2,047 nodes, 2,000
+    subsets of 301 bins) and a depth-20 forest of 20,000 nodes a tree (15
+    unrolled levels and one loop over the five that read all M). The select
+    over the feature axis fuses into reduces over the bin matrix as it
+    lies (no (N, F), (N, W_d) or (N, M) intermediate: the scratch is the
+    loop carries and a few (N,) vectors, held to each shape's own bound in
+    CHIP_SHAPES), and no gather reads the rows or a two-dimensional table.
+    Compile seconds and scratch are printed (PERF.md §6 holds them beside
+    the parent's: compiler work on this sandbox's CPU)."""
     import re
+    import time
 
     import jax
     import jax.numpy as jnp
 
-    rows, F, T, M = 16_000_000, 28, 5, 63
+    from h2o3_tpu.models.tree import device_tree
+
+    rows, F, dtype, T, M, C, maxB, depth, scratch_row = CHIP_SHAPES[shape]
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    i32, tm = jnp.int32, (T, M)
-    lowered = compressed._traverse_fn(5, 2).lower(
-        arg((rows, F), jnp.uint8), arg(tm, i32), arg(tm, i32),
-        arg(tm, jnp.bool_), arg(tm, i32), arg(tm, i32),
-        arg(tm, jnp.float32), arg(tm, i32), arg((1, 21), jnp.bool_),
-        arg((T,), i32), arg((F,), i32))
+    i32 = jnp.int32
+    forest = dict(
+        nodes=arg((T, 7, M), i32),
+        cat_words=arg((C, device_tree.route_words(maxB)), jnp.uint32),
+        tree_class=arg((T,), i32), na_bins=arg((F,), i32),
+        starts=arg((T, 2, depth), i32))
+    t0 = time.perf_counter()
+    lowered = compressed._traverse_fn(depth, 2).lower(
+        arg((rows, F), jnp.dtype(dtype)),
+        *(forest[k] for k in compressed.WALK_ARGS))
     compiled = lowered.compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * rows * F
-    # lowered for a TPU the node tables are selected, not gathered: what is
-    # left to gather is the leaf value a tree and cat_table in its branch
-    assert len(re.findall(r"stablehlo\.gather\"?\(", lowered.as_text())) == 2
+    scratch = compiled.memory_analysis().temp_size_in_bytes
+    print(f"\n{shape}: walk compiled for a v5e in "
+          f"{time.perf_counter() - t0:.1f} s on this CPU, "
+          f"scratch {scratch / rows:.1f} B a row")
+    assert scratch < scratch_row * rows
+    # lowered for a TPU the level's tables and the leaf's value are
+    # selected while the table is narrow: what is left to gather are the
+    # levels past _SELECT_MAX_NODES entries (airline: depth 9's 5,120
+    # words; depth 20: 8,192, 16,384 and 20,000 nodes, four tables in one
+    # branch and cat_split beside them in the other, and the leaf values)
+    gathers = re.findall(r"stablehlo\.gather.*?:\s*\(tensor<([^>]*)>",
+                         lowered.as_text())
+    assert len(gathers) == {"higgs_gbm_d5": 0, "airline_gbm_d10": 1,
+                            "drf_d20_m20000": 1 + (4 + 5) * 3}[shape]
+    assert all("x" not in g.split("x", 1)[1] for g in gathers), gathers
     text = compiled.as_text()
     assert "reduce(" in text
     shapes = dict(re.findall(
         r"^\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]", text, re.M))
-    gathered = re.findall(r" gather\((%[\w.\-]+),", text)
-    assert gathered                      # cat_table's, in the cat branch
-    assert not [shapes[op] for op in gathered
-                if shapes[op].split(",")[0] == str(rows)]
+    assert not [shapes[op] for op in re.findall(
+        r" gather\((%[\w.\-]+),", text)
+        if shapes[op].split(",")[0] == str(rows)]
 
 
 @pytest.mark.parametrize("config", ["higgs_gbm_d5", "airline_gbm_d10"])

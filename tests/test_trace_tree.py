@@ -126,6 +126,11 @@ def test_gbm_train_trace_holds_the_stages(rest):
                                   "route_levels": 9, "route_gather_levels": 0,
                                   "hist_matmul_levels": 9,
                                   "hist_scatter_levels": 0}
+    # each metrics pass walks the forest once: 3 trees x 3 levels, from the
+    # static widths (count_walk), none past _at_node's rule
+    for c in stages[3:]:
+        assert c["attrs"]["walk_levels"] == 9
+        assert c["attrs"]["walk_gather_levels"] == 0
     for c in stages:
         assert c["parent_id"] == job["span_id"]
         assert job["start_ms"] <= c["start_ms"] <= c["end_ms"] \
@@ -178,6 +183,50 @@ def test_an_active_trace_changes_no_dispatch_compile_or_forest(cl):
         root.span["trace_id"], include_remote=False)]
     assert {"queue_wait", "flush", "adapt", "pack", "dispatch", "fetch",
             "metrics"} <= set(names)
+
+
+def test_walk_levels_ride_the_open_span_and_add_no_dispatch(cl):
+    """count_walk gives the span open at each of its call sites (a job's
+    `metrics`, a flush) `walk_levels` / `walk_gather_levels`: host
+    arithmetic on the forest's static widths. The same requests dispatch
+    and compile the same with and without a trace to carry them."""
+    from h2o3_tpu import scoring
+    from h2o3_tpu.models.tree.gbm import GBM
+
+    model = GBM(ntrees=3, max_depth=3, seed=7).train(
+        y="y", training_frame=_frame(seed=5))
+    fo = model.forest
+    assert fo._walk_counts == dict(walk_levels=9, walk_gather_levels=0)
+    fr = _frame(700, seed=9, response=False)
+    scoring.session_for(model).predict(fr)             # warm the bucket
+    binned = model.spec.bin_columns(_frame(64, seed=3, response=False))
+
+    def compiles():
+        return sum(s["value"] for s in metrics.REGISTRY.get(
+            "h2o3_backend_compiles_total").snapshot()["samples"])
+
+    def work():
+        before, c0 = scoring.dispatch_counters(), compiles()
+        scoring.score_request(model, fr, with_metrics=True)
+        fo.predict_binned(binned)
+        fo.leaf_index(binned)
+        after = scoring.dispatch_counters()
+        return ({k: after[k] - before.get(k, 0) for k in after},
+                compiles() - c0)
+
+    work()                                             # compiles happen here
+    untraced = work()
+    with tracing.root_span("ingress", path="/3/Predictions/x") as root:
+        traced = work()
+    assert traced == untraced and untraced[1] == 0
+    n_dispatch = sum(untraced[0].values())
+    assert n_dispatch > 0
+    spans = tracing.get_trace(root.span["trace_id"], include_remote=False)
+    levels = sum(s["attrs"].get("walk_levels", 0) for s in spans)
+    assert levels == 9 * (n_dispatch + 2)              # + the two by hand
+    assert all(s["attrs"].get("walk_gather_levels", 0) == 0 for s in spans)
+    assert {s["name"] for s in spans if "walk_levels" in s["attrs"]} \
+        <= {"ingress", "flush"}
 
 
 def test_spans_never_step_with_the_wall_clock(monkeypatch):
@@ -332,15 +381,16 @@ def test_device_programs_carry_scopes_and_keep_their_names(cl):
 
     score = compressed._fused_score_fn(depth, 2)
     T, nodes = 2, 7
-    i32 = jax.ShapeDtypeStruct((T, nodes), jnp.int32)
+    forest = dict(
+        nodes=jax.ShapeDtypeStruct((T, 7, nodes), jnp.int32),
+        cat_words=jax.ShapeDtypeStruct((0, 1), jnp.uint32),
+        tree_class=jax.ShapeDtypeStruct((T,), jnp.int32),
+        na_bins=jax.ShapeDtypeStruct((F,), jnp.int32),
+        starts=jax.ShapeDtypeStruct((T, 2, depth), jnp.int32))
     text = score.lower(
         jax.ShapeDtypeStruct((16, F), jnp.float32),
         jax.ShapeDtypeStruct((F, maxB), jnp.float32),
         jax.ShapeDtypeStruct((F,), jnp.bool_), jnp.float32(0.0),
-        i32, i32, jax.ShapeDtypeStruct((T, nodes), jnp.bool_), i32, i32,
-        jax.ShapeDtypeStruct((T, nodes), jnp.float32), i32,
-        jax.ShapeDtypeStruct((1, maxB), jnp.bool_),
-        jax.ShapeDtypeStruct((T,), jnp.int32),
-        jax.ShapeDtypeStruct((F,), jnp.int32)).as_text(debug_info=True)
+        *(forest[k] for k in compressed.WALK_ARGS)).as_text(debug_info=True)
     assert "module @jit_run" in text
     assert "bin" in text and "walk" in text
