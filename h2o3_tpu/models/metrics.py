@@ -314,11 +314,24 @@ def gains_lift(pos_hist: np.ndarray, neg_hist: np.ndarray, groups: int = 16):
 # builders (called from Model.score / ModelBuilder scoring)
 # ---------------------------------------------------------------------------
 
+def _count_psum(y, values: int) -> None:
+    """`shards` and `psum_bytes` on the span open at the call (a job's
+    `metrics`, a flush's): the f32 values a shard hands the all-reduces XLA
+    puts behind this pass's sums, from static shapes; 0 where `y` lies on
+    one device. Host arithmetic, no device op."""
+    from h2o3_tpu.obs import tracing
+
+    shards = len(y.sharding.device_set) if hasattr(y, "sharding") else 1
+    tracing.set_attrs(shards=shards)
+    tracing.add_attrs(psum_bytes=4 * values if shards > 1 else 0)
+
+
 def make_regression_metrics(y, f, w, distribution=None) -> ModelMetricsRegression:
     """y/f/w: row-sharded device arrays (pad rows carry w=0)."""
     import jax.numpy as jnp
 
     parts = {k: float(v) for k, v in _regression_partials(y, f, w).items()}
+    _count_psum(y, len(parts))
     wsum = parts["wsum"]
     if wsum == 0:
         return ModelMetricsRegression()
@@ -342,6 +355,7 @@ def make_binomial_metrics(y, p, w, domain: Optional[List[str]] = None) -> ModelM
     """y in {0,1}, p = P(class 1); all row-sharded device arrays."""
     parts = {k: float(v) for k, v in _binomial_partials(y, p, w).items()}
     pos, neg = _binomial_hist(y, p, w)
+    _count_psum(y, len(parts) + 2 * NBINS)
     auc = compute_auc(np.asarray(pos), np.asarray(neg))
     wsum = parts["wsum"]
     if wsum == 0:
@@ -361,6 +375,7 @@ def make_binomial_metrics(y, p, w, domain: Optional[List[str]] = None) -> ModelM
 def make_multinomial_metrics(y, probs, w, domain: List[str]) -> ModelMetricsMultinomial:
     k = len(domain)
     parts = _multinomial_partials(y, probs, w, k)
+    _count_psum(y, sum(int(v.size) for v in parts.values()))
     wsum = float(parts["wsum"])
     if wsum == 0:
         return ModelMetricsMultinomial()

@@ -296,6 +296,12 @@ def route_forms(max_depth: int, F: int, maxB: int) -> Tuple[str, ...]:
 _SUBLANES = 8               # sublanes of a 32-bit TPU tile
 
 
+def hist_lane_widths(nbins: tuple) -> list:
+    """Lanes a feature takes in hist_matmul's one-hot: its bins rounded up
+    to whole sublane tiles."""
+    return [-(-int(nb) // _SUBLANES) * _SUBLANES for nb in nbins]
+
+
 def hist_matmul(binned, row_node, live, w, y, S: int, *, nbins: tuple,
                 maxB: int, blk: int):
     """(S, F, maxB, 3) via blocked bf16 one-hot matmul + psum — the
@@ -321,7 +327,7 @@ def hist_matmul(binned, row_node, live, w, y, S: int, *, nbins: tuple,
     import jax.numpy as jnp
 
     F = len(nbins)
-    widths = [-(-nb // _SUBLANES) * _SUBLANES for nb in nbins]
+    widths = hist_lane_widths(nbins)
     offs = np.cumsum([0] + widths[:-1])
     lane_bin = [np.where(np.arange(wd) < nb, np.arange(wd), -1)[:, None]
                 .astype(np.int32) for nb, wd in zip(nbins, widths)]
@@ -350,7 +356,8 @@ def hist_matmul(binned, row_node, live, w, y, S: int, *, nbins: tuple,
     acc0 = _compat_pcast(jnp.zeros((sum(widths), 3 * S), jnp.float32),
                          ("rows",), to="varying")
     acc = jax.lax.fori_loop(0, binned.shape[0] // blk, body, acc0)
-    acc = jax.lax.psum(acc, "rows")
+    with jax.named_scope("psum"):
+        acc = jax.lax.psum(acc, "rows")
     acc = jnp.concatenate(
         [jnp.pad(acc[o:o + nb], ((0, maxB - nb), (0, 0)))
          for o, nb in zip(offs, nbins)])
@@ -376,7 +383,8 @@ def hist_scatter(binned, row_node, live, w, y, S: int, *, nbins: tuple,
     acc = acc0.at[base.reshape(-1)].add(
         jnp.broadcast_to(vals[:, None, :],
                          (vals.shape[0], F, 3)).reshape(-1, 3))
-    acc = jax.lax.psum(acc, "rows")
+    with jax.named_scope("psum"):
+        acc = jax.lax.psum(acc, "rows")
     return acc[: S * F * maxB].reshape(S, F, maxB, 3)
 
 
@@ -417,7 +425,9 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
             jnp.zeros((tot_slots + 1, cols.shape[1]), jnp.float32),
             ("rows",), to="varying")
         acc = acc0.at[idx].add(cols)
-        return jax.lax.psum(acc, "rows")[:tot_slots]
+        with jax.named_scope("psum"):
+            acc = jax.lax.psum(acc, "rows")
+        return acc[:tot_slots]
 
     def tree_program(binned, w, y, num, den, masks):
         n = binned.shape[0]
@@ -434,8 +444,10 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
         # otherwise bury the gains in quantization noise). Leaf statistics
         # (leaf4) use the UNcentered values through the f32 path below; only
         # the packed per-node (w, wy, wyy) totals are in centered space.
-        ymean = jax.lax.psum(jnp.sum(w * y), "rows") / \
-            jnp.maximum(jax.lax.psum(jnp.sum(w), "rows"), EPS_W)
+        swy, sw = jnp.sum(w * y), jnp.sum(w)
+        with jax.named_scope("stats/psum"):     # the all-reduces alone
+            swy, sw = jax.lax.psum(swy, "rows"), jax.lax.psum(sw, "rows")
+        ymean = swy / jnp.maximum(sw, EPS_W)
         yc = y - ymean
         row_node = jnp.zeros(pad_to, jnp.int32)
         row_leaf = jnp.full(pad_to, -1, jnp.int32)
@@ -567,6 +579,39 @@ def _count_hist(forms: Tuple[str, ...]) -> None:
                       hist_scatter_levels=scattered)
 
 
+_LEAF_COLS = 4              # leaf_sums' columns: w, w·y, num, den
+
+
+def psum_bytes(max_depth: int, nbins: tuple, shards: int) -> dict:
+    """Bytes a shard hands each all-reduce over `rows` of one tree, summed
+    by site, from static shapes: `hist` (a level's f32 sums as its lowering
+    lays them out: lanes x 3S for the matmul, (S + 1)·F·maxB x 3 for the
+    scatter), `leaf_sums` ((total_slots + 1) x 4 f32) and `stats` (the two
+    scalars of the centering mean). All 0 on a mesh of one device, where a
+    psum moves nothing."""
+    if shards <= 1:
+        return {"hist": 0, "leaf_sums": 0, "stats": 0}
+    F, maxB = len(nbins), max(nbins)
+    widths = level_widths(max_depth, frontier_cap(F, maxB))
+    lanes = sum(hist_lane_widths(nbins))
+    hist = sum(lanes * 3 * S if form == "matmul" else (S + 1) * F * maxB * 3
+               for form, S in zip(hist_forms(max_depth, F, maxB), widths))
+    return {"hist": 4 * hist, "leaf_sums": 4 * (sum(widths) + 1) * _LEAF_COLS,
+            "stats": 4 * 2}
+
+
+def _count_psum(sites: dict, shards: int) -> None:
+    """h2o3_tree_psum_bytes_total{site} and the `trees` span's `shards` /
+    `psum_bytes`, counted like _count_route: host arithmetic on static
+    shapes, no device op."""
+    from h2o3_tpu.obs import metrics, tracing
+
+    for site, n in sites.items():
+        metrics.inc("h2o3_tree_psum_bytes_total", n, site=site)
+    tracing.set_attrs(shards=shards)
+    tracing.add_attrs(psum_bytes=sum(sites.values()))
+
+
 def _pick_blk(n_shard: int, lanes: int) -> int:
     """Row-block size: keep the per-block (blk, lanes) bf16 one-hot under
     ~64 MB."""
@@ -604,16 +649,19 @@ def grow_tree_device(binned, w, y, spec, *, max_depth: int, min_rows: float,
 
     mesh = _mesh()
     N, F = binned.shape
-    n_shard = N // _mesh_size(mesh)
+    shards = _mesh_size(mesh)
+    n_shard = N // shards
     maxB = int(spec.nbins.max())
+    nbins = tuple(int(b) for b in spec.nbins)
     blk = _pick_blk(n_shard, int(spec.nbins.sum()))
     has_masks = feat_masks is not None
-    fn = _grow_fn(int(max_depth), F, maxB, tuple(int(b) for b in spec.nbins),
+    fn = _grow_fn(int(max_depth), F, maxB, nbins,
                   tuple(bool(c) for c in spec.is_cat), float(min_rows),
                   float(min_split_improvement), has_masks, mesh, n_shard, blk,
                   frontier_cap(F, maxB))
     _count_route(route_forms(int(max_depth), F, maxB))
     _count_hist(hist_forms(int(max_depth), F, maxB))
+    _count_psum(psum_bytes(int(max_depth), nbins, shards), shards)
     w = w.astype(jnp.float32)
     y = y.astype(jnp.float32)
     if num is None:

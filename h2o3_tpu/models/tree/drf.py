@@ -146,7 +146,6 @@ class DRF(SharedTree):
         if classification and self.params.get("binomial_double_trees"):
             return self._fit_multinomial(model, binned, y, w, offset, spec,
                                          2, rng, ntrees)
-        N = binned.shape[0]
         mtries = self._mtries(spec.F, classification)
         feat_mask_fn = _node_feat_mask_fn(rng, spec.F, mtries)
 
@@ -169,10 +168,11 @@ class DRF(SharedTree):
             v_sum = (self._ckpt.forest.predict_binned(vs["binned"])
                      .astype(jnp.float32) * t_base)
         else:
-            v_sum = jnp.zeros(vs["binned"].shape[0], jnp.float32)
-        # OOB accumulation: sum of oob predictions and counts per row
-        oob_sum = jnp.zeros(N, jnp.float32)
-        oob_cnt = jnp.zeros(N, jnp.float32)
+            v_sum = jnp.zeros_like(vs["y"])
+        # OOB accumulation: sum of oob predictions and counts per row,
+        # row-sharded like y (shared_tree._fit)
+        oob_sum = jnp.zeros_like(y)
+        oob_cnt = jnp.zeros_like(y)
         sample_rate = float(self.params.get("sample_rate", 0.632) or 1.0)
         sampling = sample_rate < 1.0
         pre, post = _drf_step_fns(sampling)
@@ -309,8 +309,8 @@ class DRF(SharedTree):
         msi = float(self.params["min_split_improvement"])
         tree_class = []
         t_base = self._ckpt_start(ntrees, per_iter=K)
-        oob_sum = jnp.zeros((N, K), jnp.float32)
-        oob_cnt = jnp.zeros(N, jnp.float32)
+        oob_sum = jnp.zeros_like(onehot)
+        oob_cnt = jnp.zeros_like(onehot[:, 0])
         packs, leaf_means, leaf_wys = [], [], []
         t_start = t_base
         rs = self._take_resume_state("drf_multi")

@@ -390,14 +390,16 @@ class SharedTree(ModelBuilder):
                          max_bins=int(spec.nbins.max()))
         self._ckpt = prev
         model.spec = spec
-        N = binned.shape[0]
 
         w_user = None
         if self.params.get("weights_column"):
             w_user = train.col(self.params["weights_column"]).data
         w = DataInfo.response_weight(y_col.data, w_user)
         y = DataInfo.clean_response(y_col.data).astype(jnp.float32)
-        offset = jnp.zeros(N, jnp.float32)
+        # per-row state is made from a row-sharded sibling (zeros_like keeps
+        # its sharding): jnp.zeros(N) is a whole-length array on ONE device,
+        # 320 MB at 80M rows, which the first jitted step then has to spread
+        offset = jnp.zeros_like(y)
         if self.params.get("offset_column"):
             oc = train.col(self.params["offset_column"]).data
             offset = jnp.where(jnp.isnan(oc), 0.0, oc).astype(jnp.float32)
@@ -431,14 +433,15 @@ class SharedTree(ModelBuilder):
             # update via the packed-tree traversal (device_tree.apply_packed)
             # with no host scans (round-2 weakness W3)
             binned_v = spec.bin_columns(va)
-            off_v = jnp.zeros(binned_v.shape[0], jnp.float32)
+            y_v = DataInfo.clean_response(yv_col.data).astype(jnp.float32)
+            off_v = jnp.zeros_like(y_v)
             ocn = self.params.get("offset_column")
             if ocn and ocn in valid:
                 oc = valid.col(ocn).data
                 off_v = jnp.where(jnp.isnan(oc), 0.0, oc).astype(jnp.float32)
             self._vstate = {
                 "binned": binned_v,
-                "y": DataInfo.clean_response(yv_col.data).astype(jnp.float32),
+                "y": y_v,
                 "w": DataInfo.response_weight(yv_col.data, wv_user),
                 "offset": off_v,
             }
@@ -479,7 +482,6 @@ class SharedTree(ModelBuilder):
                                                       grow_tree_device,
                                                       stash_packed)
 
-        N = binned.shape[0]
         t_base = self._ckpt_start(ntrees)   # trees already in a user
         if t_base:                          # checkpoint model (concat below)
             # resume: margins restart from the checkpoint forest's predictions
@@ -495,7 +497,7 @@ class SharedTree(ModelBuilder):
                 # only the log-odds prior needs clamping (GBM.java
                 # getInitialValue); identity/log links keep large means intact
                 init_f = float(np.clip(init_f, -19, 19))
-            f = jnp.full(N, init_f, jnp.float32) + offset
+            f = jnp.float32(init_f) + offset
 
         leaf_clip = self._leaf_clip()
         history = []
@@ -643,9 +645,9 @@ class SharedTree(ModelBuilder):
             pri = np.asarray(kprior(yi, jnp.asarray(w, jnp.float32)))
             pri = np.maximum(pri / max(pri.sum(), 1e-12), 1e-9)
             init = np.log(pri).astype(np.float32)
-            f = jnp.broadcast_to(jnp.asarray(init), (N, K)).astype(jnp.float32)
-            f_valid = (jnp.broadcast_to(jnp.asarray(init),
-                                        (vs["binned"].shape[0], K)).astype(jnp.float32)
+            # (rows, K) of the priors, row-sharded like y (see _fit)
+            f = jnp.zeros_like(y)[:, None] + jnp.asarray(init)
+            f_valid = (jnp.zeros_like(vs["y"])[:, None] + jnp.asarray(init)
                        if vs is not None else None)
 
         leaf_clip = self._leaf_clip()

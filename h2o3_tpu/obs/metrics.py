@@ -473,6 +473,11 @@ def _install_default_metrics() -> None:
               "(max_depth a tree: the last level builds none), by lowering: "
               "matmul | scatter = hist_lowering's rule from the level's "
               "width")
+    r.counter("h2o3_tree_psum_bytes_total",
+              "bytes a shard handed the all-reduces over `rows` of the "
+              "trees dispatched to the tree program, from static shapes "
+              "(0 on a mesh of one device), by site: hist (a level's sums) "
+              "| leaf_sums | stats (the centering mean)")
     r.counter("h2o3_glm_iterations_total",
               "IRLS iterations of the GLM programs that ended, counted at "
               "the fetch of each program's iteration count")

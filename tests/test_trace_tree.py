@@ -122,15 +122,25 @@ def test_gbm_train_trace_holds_the_stages(rest):
     assert [c["name"] for c in stages] == \
         ["bin", "trees", "assemble", "metrics", "metrics"]
     assert [c["attrs"].get("frame") for c in stages[3:]] == ["train", "valid"]
+    # what a shard hands the three trees' all-reduces, from static shapes
+    from h2o3_tpu.core.dkv import DKV
+    from h2o3_tpu.core.runtime import cluster
+    from h2o3_tpu.models.tree import device_tree
+
+    shards = cluster().row_shards
+    nbins = tuple(int(b) for b in DKV.get("trace_tree_stages").spec.nbins)
     assert stages[1]["attrs"] == {"ntrees": 3, "rows": 1200, "max_depth": 3,
                                   "route_levels": 9, "route_gather_levels": 0,
                                   "hist_matmul_levels": 9,
-                                  "hist_scatter_levels": 0}
+                                  "hist_scatter_levels": 0, "shards": shards,
+                                  "psum_bytes": 3 * sum(device_tree.psum_bytes(
+                                      3, nbins, shards).values())}
     # each metrics pass walks the forest once: 3 trees x 3 levels, from the
     # static widths (count_walk), none past _at_node's rule
     for c in stages[3:]:
         assert c["attrs"]["walk_levels"] == 9
         assert c["attrs"]["walk_gather_levels"] == 0
+        assert c["attrs"]["shards"] == shards and c["attrs"]["psum_bytes"] > 0
     for c in stages:
         assert c["parent_id"] == job["span_id"]
         assert job["start_ms"] <= c["start_ms"] <= c["end_ms"] \
