@@ -40,6 +40,7 @@ from h2o3_tpu.models.data_info import DataInfo
 from h2o3_tpu.models.model import Model, ModelCategory
 from h2o3_tpu.models.model_builder import ModelBuilder, register
 from h2o3_tpu.obs import metrics, tracing
+from h2o3_tpu.ops.elementwise import bf16_pieces
 
 EPS = 1e-10
 
@@ -259,20 +260,6 @@ def gram_form(layout) -> str:
     return "onehot3" if layout.cards else "dense"
 
 
-def _bf16_pieces(x):
-    """x (f32) as three f32 arrays that a convert to bf16 keeps (the last
-    to a rounding) and whose sum is x to its last bit or two:
-    reduce_precision, not a convert pair the compiler may take for excess
-    precision and drop."""
-    import jax
-
-    rp = lambda a: jax.lax.reduce_precision(a, exponent_bits=8,
-                                            mantissa_bits=7)
-    hi = rp(x)
-    mid = rp(x - hi)
-    return hi, mid, x - hi - mid
-
-
 def _kahan_add(acc, x):
     """Compensated sum of the blocks' partials: (sum, carry) + x. The error
     of the total is a rounding or two whatever the number of blocks; a
@@ -385,8 +372,8 @@ def _irls_fit(arrays, moments, y, w, offset, beta0, lam_l2, lam_l1, beta_eps,
                         V = jnp.concatenate(
                             [jnp.concatenate([jnp.where(O, wk[None, :], 0.0),
                                               rk])
-                             for wk, rk in zip(_bf16_pieces(wls),
-                                               _bf16_pieces(Rz))])
+                             for wk, rk in zip(bf16_pieces(wls),
+                                               bf16_pieces(Rz))])
                         A = jax.lax.dot_general(
                             O.astype(jnp.bfloat16), V.astype(jnp.bfloat16),
                             (((1,), (1,)), ((), ())),

@@ -59,7 +59,22 @@ TPU-native design:
   last level reads nothing: every slot there is terminal.
 - The GammaPass inputs (num, den) are computed BEFORE the tree from
   (w, y, z, f) and segment-summed per leaf inside the same program, so leaf
-  Newton steps need no extra dispatch.
+  Newton steps need no extra dispatch. The leaf pass left the scatter for
+  the MXU too (leaf_sums): a block's sums are one dot of the four columns'
+  three bf16 pieces each (12 lanes; the pieces sum to the f32 value)
+  against the leaf one-hot, rows on lanes, f32 accumulation, so every
+  product is exact and the sums are f32 sums in blocks. The scatter-add of
+  an (N, 4) array it replaces took the rows one by one (123 ms a tree at
+  16M rows whatever the slots, 272 ms at 40,960; the largest device op of a
+  depth-5 job), padded the 4 to 128 lanes (8.2 GB at 16M rows: what capped
+  a chip at 20M rows) and stood 0.3-0.7% off the exact Newton step. Past a
+  tile's 128 slots the slot splits into hi·lo + low, the one-hot carries
+  the low part and the values, laid out (12, H), are masked by the high
+  part: (12·H, blk) · (lo, blk)ᵀ, lo the power of two at or above
+  sqrt(12·slots) (leaf_split, the one rule). Swept on a v5e at 16M rows:
+  6.0 ms a tree at 64 slots, 8.9 at 2,048, 23.9 at 8,192, 42 at 16,384,
+  102 at 40,960 (depth 20), the old scatter 2.7 times that at its best, so
+  there is no second lowering.
 - All per-level tables pack into ONE (depth+1, S_max, 4+maxB+3+2) f32
   array; training keeps it on device and fetches every tree's tables in a
   single end-of-training transfer (one round trip in total, not one per
@@ -396,6 +411,104 @@ def hist_lowering(S: int):
 
 
 # ---------------------------------------------------------------------------
+# the leaf pass: per-leaf sums of the GammaPass inputs, on the MXU
+# ---------------------------------------------------------------------------
+
+_LEAF_COLS = 4              # leaf_sums' columns: w, w·y, num, den
+_LEAF_PIECES = 3            # bf16 pieces an f32 value splits into, exactly
+_LANES = 128                # lanes of a TPU tile
+
+
+def leaf_split(L: int) -> Tuple[int, int]:
+    """(H, lo): how leaf_sums lays L slots out, slot = hi·lo + low with
+    hi < H — the one rule of the leaf pass, from its static width. Up to a
+    tile's 128 lanes the slots are one one-hot (H = 1, lo = L). Past that
+    the one-hot carries the low lo of a slot and the values are masked by
+    its high part: 12·H + lo lanes a row where a flat one-hot would be L,
+    least near lo = sqrt(12·L); lo is the power of two at or above it (the
+    sweep on a v5e, 16M rows, ms a tree at lo 128 / 256 / 512 / 1,024:
+    2,048 slots 11.4 / 8.9 / 16.7 / 21.7, flat 33.1; 8,192 slots 29.5 /
+    25.5 / 24.9 / 23.9; 16,384 slots 55.4 / 45.7 / 42.0 / 42.7; 40,960
+    slots 133 / 113 / 105 / 102)."""
+    if L <= _LANES:
+        return 1, L
+    lo = _LANES
+    while lo * lo < _LEAF_COLS * _LEAF_PIECES * L:
+        lo *= 2
+    return -(-L // lo), lo
+
+
+def leaf_lanes(L: int) -> int:
+    """Lanes a row takes in leaf_sums' two operands (_pick_blk's input)."""
+    H, lo = leaf_split(L)
+    return H * _LEAF_COLS * _LEAF_PIECES + lo
+
+
+def leaf_sums(row_leaf, w, y, num, den, tot_slots: int, blk: int):
+    """(tot_slots, 4) f32 sums of (w, w·y, num, den) over the rows of each
+    global leaf slot, psum'd over `rows`; inside the same shard_map as the
+    histograms. row_leaf (n,) int32 holds a row's leaf slot; a negative or
+    off-range one (dead rows, pad rows at tot_slots) is summed into the
+    off-range slot tot_slots and dropped. The four (n,) vectors are never
+    stacked into an (n, 4) array, whose minor axis a TPU pads to 128 lanes.
+
+    A block of blk rows is one dot contracting the row axis, rows on
+    lanes in both operands: the values (12, blk) are the four columns each
+    as its three bf16 pieces (ops.elementwise.bf16_pieces: their sum is the
+    f32 value), the leaf one-hot (L, blk) is one compare against a static
+    slot a lane and exact in bf16, so every product is exact and only the
+    f32 accumulation rounds: f32 sums, in blocks, not a lower precision.
+    Past 128 slots (leaf_split) the one-hot carries the slot's low part and
+    the values are laid out (12, H) and masked by its high part:
+    (12·H, blk) · (lo, blk)ᵀ. n need not be a multiple of blk: the last
+    block starts early and the rows it shares with the one before count
+    once."""
+    import jax
+    import jax.numpy as jnp
+
+    from h2o3_tpu.ops.elementwise import bf16_pieces
+
+    L = tot_slots + 1
+    H, lo = leaf_split(L)
+    C = _LEAF_COLS * _LEAF_PIECES
+    n = row_leaf.shape[0]
+    blk = min(blk, n)
+    lane_lo = np.arange(lo, dtype=np.int32)[:, None]
+    lane_hi = np.tile(np.arange(H, dtype=np.int32), C)[:, None]
+    at = jnp.arange(blk, dtype=jnp.int32)
+
+    def body(i, acc):
+        start = jnp.minimum(i * blk, n - blk)
+        sl = lambda a: jax.lax.dynamic_slice_in_dim(a, start, blk)
+        slot = sl(row_leaf)
+        slot = jnp.where(slot >= 0, jnp.minimum(slot, tot_slots), tot_slots)
+        slot = jnp.where(start + at >= i * blk, slot, -1)   # counted before
+        wb = sl(w)
+        V = jnp.stack([p for ps in zip(*(bf16_pieces(c) for c in
+                                         (wb, wb * sl(y), sl(num), sl(den))))
+                       for p in ps])                         # (12, blk)
+        if H == 1:
+            O = slot[None, :] == lane_lo
+        else:
+            # lo is a power of two here: low part by mask, high by shift
+            O = (slot & (lo - 1))[None, :] == lane_lo
+            V = jnp.where((slot >> (lo.bit_length() - 1))[None, :] == lane_hi,
+                          jnp.repeat(V, H, axis=0), 0.0)     # (12·H, blk)
+        return acc + jax.lax.dot_general(
+            V.astype(jnp.bfloat16), O.astype(jnp.bfloat16),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+    acc0 = _compat_pcast(jnp.zeros((C * H, lo), jnp.float32), ("rows",),
+                         to="varying")
+    acc = jax.lax.fori_loop(0, -(-n // blk), body, acc0)
+    acc = acc.reshape(_LEAF_PIECES, _LEAF_COLS, H * lo)
+    acc = (acc[2] + acc[1] + acc[0])[:, :L].T                # (L, 4)
+    with jax.named_scope("psum"):
+        acc = jax.lax.psum(acc, "rows")
+    return acc[:tot_slots]
+
+
+# ---------------------------------------------------------------------------
 # the per-tree program
 # ---------------------------------------------------------------------------
 
@@ -416,18 +529,7 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
     tot_slots = sum(widths)
     Smax = max(widths)
     K = pack_width(maxB)
-
-    def leaf_sums(row_leaf, cols):
-        """(tot_slots, C) per-leaf sums (scatter; O(N) at any tree size)."""
-        idx = jnp.where(row_leaf >= 0, row_leaf, tot_slots)
-        idx = jnp.minimum(idx, tot_slots)
-        acc0 = _compat_pcast(
-            jnp.zeros((tot_slots + 1, cols.shape[1]), jnp.float32),
-            ("rows",), to="varying")
-        acc = acc0.at[idx].add(cols)
-        with jax.named_scope("psum"):
-            acc = jax.lax.psum(acc, "rows")
-        return acc[:tot_slots]
+    leaf_blk = _pick_blk(pad_to, leaf_lanes(tot_slots + 1))
 
     def tree_program(binned, w, y, num, den, masks):
         n = binned.shape[0]
@@ -529,8 +631,7 @@ def _grow_fn(max_depth: int, F: int, maxB: int, nbins: tuple, is_cat: tuple,
                     (split_feat, left_slot, right_slot, left_table))
 
         with jax.named_scope("leaf_sums"):
-            cols = jnp.stack([w, w * y, num, den], axis=-1)
-            leaf4 = leaf_sums(row_leaf, cols)
+            leaf4 = leaf_sums(row_leaf, w, y, num, den, tot_slots, leaf_blk)
         row_leaf = jnp.where(row_leaf >= tot_slots, -1, row_leaf)  # clear pad
         return packed, leaf4, row_leaf[:n]
 
@@ -579,7 +680,22 @@ def _count_hist(forms: Tuple[str, ...]) -> None:
                       hist_scatter_levels=scattered)
 
 
-_LEAF_COLS = 4              # leaf_sums' columns: w, w·y, num, den
+def leaf_forms(max_depth: int, F: int, maxB: int) -> str:
+    """How a tree's leaf pass lays its slots out (`matmul`: one one-hot |
+    `matmul_split`: the slot's low part a one-hot, its high part a mask
+    of the values: leaf_split's rule, from the tree's total slots)."""
+    H, _lo = leaf_split(total_slots(max_depth, frontier_cap(F, maxB)) + 1)
+    return "matmul" if H == 1 else "matmul_split"
+
+
+def _count_leaf(form: str) -> None:
+    """h2o3_tree_leaf_sums_total{lowering} and the `trees` span's
+    `leaf_lowering`, counted like _count_route: host arithmetic on static
+    widths, no device op."""
+    from h2o3_tpu.obs import metrics, tracing
+
+    metrics.inc("h2o3_tree_leaf_sums_total", 1, lowering=form)
+    tracing.set_attrs(leaf_lowering=form)
 
 
 def psum_bytes(max_depth: int, nbins: tuple, shards: int) -> dict:
@@ -661,6 +777,7 @@ def grow_tree_device(binned, w, y, spec, *, max_depth: int, min_rows: float,
                   frontier_cap(F, maxB))
     _count_route(route_forms(int(max_depth), F, maxB))
     _count_hist(hist_forms(int(max_depth), F, maxB))
+    _count_leaf(leaf_forms(int(max_depth), F, maxB))
     _count_psum(psum_bytes(int(max_depth), nbins, shards), shards)
     w = w.astype(jnp.float32)
     y = y.astype(jnp.float32)

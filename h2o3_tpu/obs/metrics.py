@@ -473,6 +473,12 @@ def _install_default_metrics() -> None:
               "(max_depth a tree: the last level builds none), by lowering: "
               "matmul | scatter = hist_lowering's rule from the level's "
               "width")
+    r.counter("h2o3_tree_leaf_sums_total",
+              "leaf passes of the trees dispatched to the tree program (one "
+              "a tree: the per-leaf sums of w, w·y and the GammaPass "
+              "inputs), by lowering: matmul (one leaf one-hot) | "
+              "matmul_split (the slot split into a one-hot and a mask) = "
+              "leaf_split's rule from the tree's total slots")
     r.counter("h2o3_tree_psum_bytes_total",
               "bytes a shard handed the all-reduces over `rows` of the "
               "trees dispatched to the tree program, from static shapes "

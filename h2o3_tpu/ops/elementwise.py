@@ -37,6 +37,20 @@ def cat_to_f32_expr(d):
 _cat_to_f32 = jax.jit(cat_to_f32_expr)
 
 
+def bf16_pieces(x):
+    """x (f32) as three f32 arrays that a convert to bf16 keeps (the last
+    to a rounding) and whose sum is x to its last bit or two:
+    reduce_precision, not a convert pair the compiler may take for excess
+    precision and drop. Against a one-hot, which bf16 holds exactly, three
+    bf16 passes over the pieces give the f32 products (glm._irls_fit's
+    Gram, device_tree.leaf_sums)."""
+    rp = lambda a: jax.lax.reduce_precision(a, exponent_bits=8,
+                                            mantissa_bits=7)
+    hi = rp(x)
+    mid = rp(x - hi)
+    return hi, mid, x - hi - mid
+
+
 def _trigamma(x):
     """ψ′(x): recurrence ψ′(x)=1/x²+ψ′(x+1) shifted to z=x+8, then the
     asymptotic series 1/z + 1/2z² + 1/6z³ − 1/30z⁵ + 1/42z⁷ — stable in

@@ -637,3 +637,43 @@ def test_tree_program_lowers_for_the_chip_without_a_row_gather(config,
         assert not [shapes[op] for op in re.findall(
             r" gather\((%[\w.\-]+),", hlo)
             if shapes[op].split(",")[0] == str(rows)]
+
+
+def test_tree_program_fits_one_chip_at_32m_rows(one_chip):
+    """`jit_tree_program` compiled for a v5e at 32,000,000 x 28 rows, H2O's
+    4 x data sizing of a 16 GB chip (ISSUE 35): until the leaf pass stopped
+    stacking its four columns into an `(N, 4)` f32 array, whose minor axis a
+    TPU pads to 128 lanes (512 B a row: 15.27 GB here), the compiler
+    refused this size. The program's scratch is now a few `(N,)` vectors
+    (70 B a row measured; the bound leaves less room than one more padded
+    `(N, 4)` or an `(N, 28)` copy of the bins would take), no buffer of the
+    program has that shape, and scratch, arguments and results together
+    leave the frame's 3.6 GB beside them on the chip."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from h2o3_tpu.models.tree import device_tree
+
+    rows, F, maxB, depth = 32_000_000, 28, 21, 5
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("rows",))
+
+    def sharded(shape, dt, *axes):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, P(*axes)))
+
+    grow = device_tree._grow_fn(
+        depth, F, maxB, (maxB,) * F, (False,) * F, 10.0, 1e-5, False, mesh,
+        rows, device_tree._pick_blk(rows, F * maxB),
+        device_tree.frontier_cap(F, maxB))
+    f32 = sharded((rows,), jnp.float32, "rows")
+    compiled = grow.lower(sharded((rows, F), jnp.uint8, "rows", None), f32,
+                          f32, f32, f32, np.zeros(0, np.float32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 96 * rows
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 6 * 2 ** 30
+    assert not re.findall(r"f32\[\d{7,},4\]", compiled.as_text())

@@ -92,11 +92,14 @@ def _fit(k: int, seed: int, na_share=None) -> dict:
         device_tree.grow_tree_device = watched
         try:
             before = _counter("h2o3_tree_psum_bytes_total")
+            leaf_before = _counter("h2o3_tree_leaf_sums_total")
             with tracing.root_span("ingress",
                                    path="/3/ModelBuilders/gbm") as root:
                 model = GBM(seed=1, **cfg["params"]).train(
                     y=recipe.RESPONSE_NAME, training_frame=DKV.get(key))
             moved = _delta(before, _counter("h2o3_tree_psum_bytes_total"))
+            leaf_moved = _delta(leaf_before,
+                                _counter("h2o3_tree_leaf_sums_total"))
             assert not stray, stray
             assert len(model.spec.bin_columns(DKV.get(key))
                        .sharding.device_set) == k
@@ -109,6 +112,7 @@ def _fit(k: int, seed: int, na_share=None) -> dict:
             DKV.remove(key)
     spans = tracing.get_trace(root.span["trace_id"], include_remote=False)
     return {"forest": forest, "numbers": numbers, "psum": moved,
+            "leaf": leaf_moved,
             "attrs": {s["name"]: s["attrs"] for s in spans},
             "nbins": tuple(int(b) for b in model.spec.nbins)}
 
@@ -178,6 +182,10 @@ def test_psum_bytes_are_the_static_sum_on_a_mesh_and_0_on_one_device(
     assert got["psum"] == {s: float(ntrees * n) for s, n in a_tree.items()
                            if n}       # a site that moved by 0 is left out
     trees, scored = got["attrs"]["trees"], got["attrs"]["metrics"]
+    # a leaf pass a tree, 2,048 slots of four f32 sums on the MXU as 8 x 256,
+    # whether the rows lie on one device or four
+    assert got["leaf"] == {"matmul_split": float(ntrees)}
+    assert trees["leaf_lowering"] == "matmul_split"
     assert trees["shards"] == scored["shards"] == shards
     assert trees["psum_bytes"] == ntrees * sum(a_tree.values())
     assert scored["psum_bytes"] == walk

@@ -7,6 +7,7 @@ that spans add no dispatch, compile or sync, and that the clock never
 steps; never a time."""
 
 import json
+import re
 import threading
 import time
 import urllib.parse
@@ -132,7 +133,8 @@ def test_gbm_train_trace_holds_the_stages(rest):
     assert stages[1]["attrs"] == {"ntrees": 3, "rows": 1200, "max_depth": 3,
                                   "route_levels": 9, "route_gather_levels": 0,
                                   "hist_matmul_levels": 9,
-                                  "hist_scatter_levels": 0, "shards": shards,
+                                  "hist_scatter_levels": 0,
+                                  "leaf_lowering": "matmul", "shards": shards,
                                   "psum_bytes": 3 * sum(device_tree.psum_bytes(
                                       3, nbins, shards).values())}
     # each metrics pass walks the forest once: 3 trees x 3 levels, from the
@@ -381,13 +383,26 @@ def test_device_programs_carry_scopes_and_keep_their_names(cl):
                                 1.0, 1e-5, False, mesh, n // shards, 64,
                                 device_tree.frontier_cap(F, maxB))
     f32 = jax.ShapeDtypeStruct((n,), jnp.float32)
-    text = grow.lower(jax.ShapeDtypeStruct((n, F), jnp.uint8), f32, f32, f32,
-                      f32, np.zeros(0, np.float32)).as_text(debug_info=True)
+    lowered = grow.lower(jax.ShapeDtypeStruct((n, F), jnp.uint8), f32, f32,
+                         f32, f32, np.zeros(0, np.float32))
+    text = lowered.as_text(debug_info=True)
     assert "module @jit_tree_program" in text
     for scope in ["leaf_sums"] + [f"level{d}/{s}" for d in range(depth)
                                   for s in ("hist", "search", "route")]:
         assert scope in text, scope
     assert f"level{depth}/route" in text and f"level{depth}/hist" not in text
+    # a cold compile's metadata (conftest turns the compile cache off):
+    # `leaf_sums` names the leaf pass's blocked dot and, on a mesh,
+    # `leaf_sums/psum` the all-reduce of its (L, 4) result, so a trace sums
+    # the pass and psum_on_chip.py its collective by the names they had
+    ops = re.findall(r"= \S+ ([\w\-]+)\(.*op_name=\"([^\"]+)\"",
+                     lowered.compile().as_text())
+    assert [op for op, name in ops if op in ("dot", "convolution")
+            and re.search(r"/leaf_sums/while/body/.*dot_general$", name)]
+    assert not [op for op, name in ops if op == "scatter"
+                and "/leaf_sums/" in name]
+    assert bool([op for op, name in ops if op == "all-reduce"
+                 and "/leaf_sums/psum/" in name]) == (shards > 1)
 
     score = compressed._fused_score_fn(depth, 2)
     T, nodes = 2, 7

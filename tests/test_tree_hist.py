@@ -214,6 +214,140 @@ def test_dead_and_zero_weight_rows_drop(cl, lowering):
     assert np.all(out0 == 0)
 
 
+# ---------------------------------------------------------------------------
+# the leaf pass: per-leaf sums of (w, w·y, num, den)
+# ---------------------------------------------------------------------------
+
+def _leaf_scatter_f32(row_leaf, w, y, num, den, tot_slots, blk):
+    """The leaf pass as it was until PR 35, kept here as what the forms
+    are held against: an (n, 4) f32 array scatter-added row by row into
+    (tot_slots + 1, 4). blk is not used."""
+    import jax
+    import jax.numpy as jnp
+
+    idx = jnp.minimum(jnp.where(row_leaf >= 0, row_leaf, tot_slots),
+                      tot_slots)
+    acc = jnp.zeros((tot_slots + 1, 4), jnp.float32).at[idx].add(
+        jnp.stack([w, w * y, num, den], axis=-1))
+    return jax.lax.psum(acc, "rows")[:tot_slots]
+
+
+def _leaf_case(seed, n, tot_slots):
+    """Rows as the tree program hands them to the leaf pass: a leaf slot a
+    row, a third of them slots of upper levels (rows that terminalised
+    early), some at tot_slots (pad rows) and some negative (a row no level
+    gave a leaf: none in a grown tree, dropped all the same); weights with
+    zeros among them; num and den spanning 1e-6 to 1e4, num of either
+    sign."""
+    rng = np.random.default_rng(seed)
+    row_leaf = rng.integers(tot_slots // 2, tot_slots, n)
+    early = rng.random(n) < 1 / 3
+    row_leaf[early] = rng.integers(0, max(tot_slots // 2, 1), early.sum())
+    row_leaf[rng.random(n) < 0.05] = tot_slots
+    row_leaf[rng.random(n) < 0.02] = -1
+    w = (rng.random(n) + 0.25).astype(np.float32)
+    w[rng.random(n) < 0.1] = 0.0
+    y = rng.standard_normal(n).astype(np.float32)
+    num = (10.0 ** rng.uniform(-6, 4, n) * rng.choice([-1.0, 1.0], n)) \
+        .astype(np.float32)
+    den = (10.0 ** rng.uniform(-6, 4, n)).astype(np.float32)
+    return row_leaf.astype(np.int32), w, y, num, den
+
+
+def _leaf_truth(row_leaf, w, y, num, den, tot_slots):
+    """(tot_slots, 4) float64 sums of the same f32 terms (w·y rounded to
+    f32 as every form rounds it), and the sums of their magnitudes."""
+    ok = (row_leaf >= 0) & (row_leaf < tot_slots)
+    cols = (w, w * y, num, den)
+    sums = [np.bincount(row_leaf[ok], weights=f(c[ok].astype(np.float64)),
+                        minlength=tot_slots)
+            for f in (lambda v: v, np.abs) for c in cols]
+    return np.stack(sums[:4], -1), np.stack(sums[4:], -1)
+
+
+def _run_leaf(cl, fn, row_leaf, w, y, num, den, tot_slots, blk):
+    """One form alone under shard_map over the cluster's mesh, each shard
+    padded with rows at tot_slots as tree_program pads them."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from h2o3_tpu.compat import shard_map
+
+    pad = -len(row_leaf) % cl.mesh.devices.size
+    args = [np.pad(row_leaf, (0, pad), constant_values=tot_slots)] + \
+        [np.pad(a, (0, pad)) for a in (w, y, num, den)]
+    run = jax.jit(shard_map(
+        lambda *a: fn(*a, tot_slots, blk), mesh=cl.mesh,
+        in_specs=(P("rows"),) * 5, out_specs=P()))
+    return np.asarray(run(*(jnp.asarray(a) for a in args)), np.float64)
+
+
+@pytest.mark.parametrize("seed,n,tot_slots,blk,form", [
+    (30, 700, 1, 64, "matmul"),     # L = 2: a stump's leaf and the pad slot
+    (31, 4000, 63, 96, "matmul"),   # L = 64: higgs_gbm_d5; 500 rows a shard
+                                    # in blocks of 96: the last starts early
+    (32, 4000, 127, 500, "matmul"),         # L = 128: the widest one-hot
+    (33, 4000, 128, 500, "matmul_split"),   # L = 129: 2 x 128
+    (34, 16000, 2047, 300, "matmul_split"),     # airline_gbm_d10: 8 x 256
+    (35, 16000, 40959, 2000, "matmul_split"),   # depth 20: 40 x 1,024
+    (36, 999, 63, 4096, "matmul"),  # a block longer than a shard's rows
+])
+def test_leaf_sums_against_f64_truth(cl, seed, n, tot_slots, blk, form):
+    """The leaf pass in every layout leaf_split's rule returns, each
+    column of every leaf within 2e-6 of the float64 sum of the same f32
+    terms (relative to the sum of their magnitudes) and no further from it
+    than the row-by-row f32 scatter-add is on the same rows (down to four
+    f32 roundings, 2^-22: a leaf of a dozen rows and the all-reduce of
+    eight shards' partials round that much in any order, either way)."""
+    H, _lo = device_tree.leaf_split(tot_slots + 1)
+    assert ("matmul" if H == 1 else "matmul_split") == form
+    case = _leaf_case(seed, n, tot_slots)
+    want, mag = _leaf_truth(*case, tot_slots)
+    got = _run_leaf(cl, device_tree.leaf_sums, *case, tot_slots, blk)
+    was = _run_leaf(cl, _leaf_scatter_f32, *case, tot_slots, blk)
+    assert got.shape == want.shape == (tot_slots, 4)
+    err = np.abs(got - want) / np.maximum(mag, 1e-30)
+    err_was = np.abs(was - want) / np.maximum(mag, 1e-30)
+    assert err.max() <= 2e-6, np.argwhere(err > 2e-6)[:5]
+    for c in range(4):
+        assert err[:, c].max() <= max(err_was[:, c].max(), 2.0 ** -22), c
+    assert np.all(got[mag == 0] == 0)       # a leaf no row reaches
+
+
+def test_tree_program_holds_no_n_by_4_array():
+    """The one-tree program lowered and compiled at 65,536 rows, depth 5,
+    on one device: nothing in it has the shape (n, 4) or (4, n) in f32, the
+    stacked columns of the leaf pass whose minor axis of 4 a TPU pads to 128
+    lanes (512 B a row, 15.27 GB at 32M rows: what capped a chip at 20M
+    rows until PR 35). The regex is held to the old form beside it."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+    from jax.sharding import PartitionSpec as P
+
+    from h2o3_tpu.compat import shard_map
+
+    n, F, maxB, depth = 65_536, 28, 21, 5
+    mesh = Mesh(np.array(jax.devices()[:1]), ("rows",))
+    grow = device_tree._grow_fn(depth, F, maxB, (maxB,) * F, (False,) * F,
+                                10.0, 1e-5, False, mesh, n,
+                                device_tree._pick_blk(n, F * maxB),
+                                device_tree.frontier_cap(F, maxB))
+    f32 = jax.ShapeDtypeStruct((n,), jnp.float32)
+    stacked = re.compile(rf"f32\[(?:{n},4|4,{n})\]")
+    text = grow.lower(jax.ShapeDtypeStruct((n, F), jnp.uint8), f32, f32, f32,
+                      f32, np.zeros(0, np.float32)).compile().as_text()
+    assert "leaf_sums" in text and not stacked.findall(text)
+    was = jax.jit(shard_map(
+        lambda *a: _leaf_scatter_f32(*a, 63, 0), mesh=mesh,
+        in_specs=(P("rows"),) * 5, out_specs=P())).lower(
+            jax.ShapeDtypeStruct((n,), jnp.int32), f32, f32, f32, f32)
+    assert stacked.findall(was.compile().as_text())
+
+
 def _train_frame(seed=7, n=600):
     rng = np.random.default_rng(seed)
     x = rng.standard_normal(n)
@@ -300,6 +434,33 @@ def test_tree_family_is_declared():
     from h2o3_tpu.obs import compiles
 
     assert "tree" in compiles.FAMILIES
+
+
+@pytest.mark.parametrize("name,form,split", [
+    ("higgs_gbm_d5", "matmul", (1, 64)),            # one one-hot
+    ("drf_d6", "matmul", (1, 128)),                 # the widest one-hot
+    ("drf_d7", "matmul_split", (2, 128)),
+    ("airline_gbm_d10", "matmul_split", (8, 256)),  # 2,048 slots
+    ("drf_d14", "matmul_split", (32, 512)),         # 16,384 slots
+    ("drf_d20", "matmul_split", (40, 1024)),        # DRF's default depth
+])
+def test_leaf_split_rule_from_shape(name, form, split):
+    """The leaf pass has one lowering, the blocked dot, and one rule for
+    its layout, read from the tree's static slots: one one-hot up to a
+    tile's 128 lanes, beyond it the slot split at the power of two at or
+    above sqrt(12 L), where the two operands' lanes a row are fewest."""
+    depth, F, maxB = (int(name[5:]), 28, 21) if name.startswith("drf_d") \
+        else _config_shape(name)
+    L = device_tree.total_slots(
+        depth, device_tree.frontier_cap(F, maxB)) + 1
+    H, lo = device_tree.leaf_split(L)
+    assert (H, lo) == split and (H - 1) * lo < L <= H * lo
+    assert device_tree.leaf_forms(depth, F, maxB) == form
+    assert device_tree.leaf_lanes(L) == 12 * H + lo
+    if H > 1:       # no other power of two gives fewer lanes by a tile
+        assert lo & (lo - 1) == 0 and all(
+            12 * -(-L // o) + o > 12 * H + lo - 128
+            for o in (128, 256, 512, 1024, 2048))
 
 
 def _config_shape(name):

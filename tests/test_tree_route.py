@@ -321,6 +321,39 @@ def test_hist_levels_are_counted_by_lowering_on_the_host(depth, F, maxB,
     assert trees["attrs"]["hist_scatter_levels"] == 2 * scatter
 
 
+@pytest.mark.parametrize("depth,F,maxB,form", [
+    (5, 28, 21, "matmul"),       # higgs_gbm_d5: 64 slots
+    (10, 8, 301, "matmul_split"),    # airline_gbm_d10: 2,048 slots
+    (20, 28, 21, "matmul_split"),    # DRF's default depth: 40,960 slots
+])
+def test_leaf_passes_are_counted_by_lowering_on_the_host(depth, F, maxB,
+                                                         form):
+    """h2o3_tree_leaf_sums_total{lowering} moves by one a tree and the
+    `trees` span says which form its trees' leaf pass took, from
+    leaf_split's rule on the tree's static slots: nothing crosses to or
+    from a device, nothing compiles."""
+    import jax
+
+    assert device_tree.leaf_forms(depth, F, maxB) == form
+    counted = lambda: _by_label("h2o3_tree_leaf_sums_total", "lowering")
+    before = counted()
+    compiles = metrics.REGISTRY.get("h2o3_backend_compiles_total").snapshot()
+    with tracing.root_span("ingress", path="/3/ModelBuilders/gbm") as root:
+        with tracing.span("trees"):
+            with jax.transfer_guard("disallow"):
+                for _ in range(2):                   # two trees of a job
+                    device_tree._count_leaf(form)
+    now = counted()
+    assert {k: now[k] - before.get(k, 0) for k in now
+            if now[k] != before.get(k, 0)} == {form: 2}
+    assert metrics.REGISTRY.get(
+        "h2o3_backend_compiles_total").snapshot() == compiles
+    trees, = [s for s in tracing.get_trace(root.span["trace_id"],
+                                           include_remote=False)
+              if s["name"] == "trees"]
+    assert trees["attrs"]["leaf_lowering"] == form
+
+
 def test_a_fit_counts_its_trees_levels_and_adds_no_compile_or_change(cl):
     """A depth-3 GBM of 3 trees: 9 routing levels by select on the `trees`
     span and the counter; the same fit again compiles nothing, and the
