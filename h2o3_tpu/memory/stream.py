@@ -38,6 +38,7 @@ import time
 from typing import Any, Callable, List, Optional
 
 from h2o3_tpu.memory import MemoryPressureError, budget
+from h2o3_tpu.obs import tracing
 from h2o3_tpu.parallel import retry
 
 _LOCK = threading.Lock()
@@ -106,7 +107,12 @@ def run_windows(family: str, n: int, dispatch: Callable[[int, int], Any],
 
     if n <= 0:
         return []
-    decision = budget.plan(family, n, row_bytes)
+    # its own span: the plan reads the device's memory statistics and scans
+    # the residency of every column in the store, which is milliseconds
+    # beside thousands of resident columns
+    with tracing.span("plan", family=family) as sp:
+        decision = budget.plan(family, n, row_bytes)
+        sp.set(mode=decision.mode)
     if decision.mode == "refuse":
         _fail_pressure(family, n, [], decision)
     win = max_window

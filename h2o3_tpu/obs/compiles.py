@@ -174,13 +174,16 @@ BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _WATCHING = False
 
 
-def _on_backend_compile(event: str, secs: float, **_kw) -> None:
+def _on_backend_compile(event: str, secs: float, fun_name=None,
+                        **_kw) -> None:
     """``jax.monitoring`` calls this on the compiling thread, right after
     the compile: the ledger above sees only its own call sites, this sees
     eager ops and bare jits too (and a load from JAX's persistent cache,
     which the event wraps as well: a short one). Under an active span the
     compile becomes its child ``compile``, so ``/3/Trace/{id}`` names the
-    step that recompiled."""
+    step that recompiled, and ``program`` (JAX's ``fun_name``: ``jit(run)``,
+    an eager op's ``jit(concatenate)``) what it compiled. An attribute and
+    not a counter label: names are unbounded."""
     if event != BACKEND_COMPILE_EVENT:
         return
     from h2o3_tpu.obs import metrics, tracing
@@ -191,7 +194,8 @@ def _on_backend_compile(event: str, secs: float, **_kw) -> None:
     if ctx is not None:
         end = tracing.now_ms()
         tracing.record_span("compile", ctx, end - secs * 1000.0, end,
-                            seconds=round(secs, 6))
+                            seconds=round(secs, 6),
+                            program=str(fun_name) if fun_name else None)
 
 
 def watch_backend_compiles() -> None:

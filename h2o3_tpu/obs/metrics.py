@@ -447,6 +447,11 @@ def _install_default_metrics() -> None:
     r.histogram("h2o3_rest_request_seconds",
                 "REST request wall time (seconds)")
     r.counter("h2o3_trace_spans_total", "trace spans recorded")
+    r.counter("h2o3_trace_dropped_total",
+              "what the span store's bounds cost, by what: span (a trace "
+              "already held _SPAN_CAP spans and turned this one away) | "
+              "trace (the oldest of H2O_TPU_OBS_TRACE_CAP traces evicted "
+              "for a new one: the ring's turnover)")
     r.counter("h2o3_flight_records_total", "flight records written")
     r.counter("h2o3_oplog_ops_published_total",
               "oplog ops published by this coordinator")
@@ -597,6 +602,13 @@ def _install_default_metrics() -> None:
     r.histogram("h2o3_score_flush_requests",
                 "requests coalesced per micro-batch flush",
                 buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256))
+    r.counter("h2o3_score_flush_windows_total",
+              "row windows the sharded serving path dispatched, by arm: "
+              "single (one entry, packed a window) | coalesced (several "
+              "entries concatenated on the device and re-bucketed)")
+    r.counter("h2o3_score_flush_entries_total",
+              "entries (requests) the sharded serving path scored, by the "
+              "same arm")
     r.histogram("h2o3_score_request_seconds",
                 "fused-path request latency (admission + batching + "
                 "dispatch), by model — the SLO-adaptive admission signal")

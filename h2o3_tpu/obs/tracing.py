@@ -15,7 +15,14 @@ builders' stage spans ``bin`` / ``trees`` / ``assemble`` / ``metrics``
 beneath), and the micro-batcher's flush leader records ``queue_wait`` and
 ``flush`` into every coalesced request's own trace. The scoring fast path
 emits ``adapt`` / ``pack`` / ``dispatch`` / ``fetch`` / ``metrics`` under
-``flush``. None of them adds a device synchronization: a span is host time
+``flush``, and one span a sequential phase of the flush where the host does
+the work: ``view`` (an entry's shard view), ``parts`` (the coalesced arm's
+chunk loop, over its ``pack`` spans), ``windows`` (the whole window loop,
+over the memory planner's ``plan`` and its ``dispatch`` spans; never a span
+a window: what a window costs rides as summed attributes), ``join`` (the
+outputs' concatenation) and
+``lift`` (an entry's rows back out to its frame's layout).
+None of them adds a device synchronization: a span is host time
 around calls the path already makes and ends where the host already blocks
 (the fused-path ``gathered_rows``/compile counters assert the path itself
 is unchanged — see tests).
@@ -30,7 +37,8 @@ the device ops; with no capture running that is a TraceMe no-op.
 Cost model: ``span()`` is a no-op (no allocation, no store write) unless
 the calling thread has an ACTIVE trace — library-mode predict() pays one
 thread-local read. The store is bounded (``H2O_TPU_OBS_TRACE_CAP`` traces
-× ``_SPAN_CAP`` spans, oldest trace evicted) and follower-side spans from
+× ``_SPAN_CAP`` spans, oldest trace evicted; what either bound turns away
+is counted, ``h2o3_trace_dropped_total{what}``) and follower-side spans from
 replayed ops additionally publish to the cloud KV (bounded, self-GCing)
 so the coordinator can serve the full tree."""
 
@@ -113,19 +121,28 @@ def _proc_index() -> int:
 def _store(span: dict) -> None:
     """Bounded-store insert (oldest trace evicted) + the span counter —
     the single copy both the context-manager finish path and the
-    explicitly-timed record_span path go through."""
+    explicitly-timed record_span path go through. What the bounds cost is
+    counted: a span a full trace turns away (a request's root finishes
+    last, so it is the first to go) and a trace the ring evicts."""
     tid = span["trace_id"]
+    evicted = 0
     with _LOCK:
         spans = _STORE.get(tid)
         if spans is None:
             spans = _STORE[tid] = []
             while len(_STORE) > trace_cap():
                 _STORE.popitem(last=False)
-        if len(spans) < _SPAN_CAP:
+                evicted += 1
+        kept = len(spans) < _SPAN_CAP
+        if kept:
             spans.append(span)
     from h2o3_tpu.obs import metrics
 
     metrics.inc("h2o3_trace_spans_total")
+    if not kept:
+        metrics.inc("h2o3_trace_dropped_total", what="span")
+    if evicted:
+        metrics.inc("h2o3_trace_dropped_total", evicted, what="trace")
 
 
 def _finish(span: dict) -> None:

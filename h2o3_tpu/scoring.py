@@ -488,9 +488,12 @@ class ScoringSession:
         import jax.numpy as jnp
 
         from h2o3_tpu.memory import stream
+        from h2o3_tpu.obs import metrics as obs_metrics
 
         maxb = self.buckets[-1]
         n_disp = 0
+        rebucket_ns = 0
+        arm = "single" if len(items) == 1 else "coalesced"
 
         def dispatch(Xd, bucket: int, rows: int):
             nonlocal n_disp
@@ -508,8 +511,23 @@ class ScoringSession:
             self._note_dispatch("sharded")
             return out
 
+        def windows(rows: int, window) -> List[Any]:
+            # ONE span over the whole window loop, never one a window: its
+            # self time is what the host does between two dispatches (the
+            # executable lookup, the [:m] on each output and, coalesced, the
+            # re-bucketing, which rides as `rebucket_ms`); the planner's
+            # `plan` and the `dispatch` spans lie beneath it
+            with tracing.span("windows", arm=arm, entries=len(items)) as ws:
+                outs = stream.run_windows(
+                    "scoring", rows, window, maxb,
+                    row_bytes=self._row_bytes_hint(),
+                    window_sizer=self._window_snap)
+                ws.set(windows=n_disp,
+                       rebucket_ms=round(rebucket_ns / 1e6, 3))
+            return outs
+
         outs: List[Any] = []
-        if len(items) == 1:
+        if arm == "single":
             sf, n = items[0]
 
             def window(pos: int, m: int):
@@ -517,20 +535,22 @@ class ScoringSession:
                 Xd = sf.pack_features(pos, n, bucket)
                 return dispatch(Xd, bucket, m)[:m]
 
-            outs = stream.run_windows(
-                "scoring", n, window, maxb,
-                row_bytes=self._row_bytes_hint(),
-                window_sizer=self._window_snap)
+            outs = windows(n, window)
         else:
             parts: List[Any] = []
-            for sf, n in items:
-                pos = 0
-                while pos < n:
-                    m = min(maxb, n - pos)
-                    bucket = self._bucket_for(m)
-                    Xd = sf.pack_features(pos, n, bucket)
-                    parts.append(Xd if m == bucket else Xd[:m])
-                    pos += m
+            # per entry and bucket chunk a `pack` span (pack_features',
+            # around its executable alone); what `parts` keeps for itself is
+            # what pack_features does before it (two device scalars, the
+            # dtype tuple, the lookup) and the eager Xd[:m] of a tail
+            with tracing.span("parts", entries=len(items)):
+                for sf, n in items:
+                    pos = 0
+                    while pos < n:
+                        m = min(maxb, n - pos)
+                        bucket = self._bucket_for(m)
+                        Xd = sf.pack_features(pos, n, bucket)
+                        parts.append(Xd if m == bucket else Xd[:m])
+                        pos += m
             if parts:
                 total = sum(n for _, n in items)
                 # the device-side concat of per-entry shard-packed
@@ -539,25 +559,29 @@ class ScoringSession:
                 with tracing.span("pack", rows=total, path="coalesce"):
                     X = parts[0] if len(parts) == 1 else \
                         jnp.concatenate(parts)
-                N = int(X.shape[0])
 
                 def window(pos: int, m: int):
+                    nonlocal rebucket_ns
                     bucket = self._bucket_for(m)
+                    t0 = time.perf_counter_ns()
                     chunk = X[pos: pos + m]
                     if m < bucket:
                         chunk = jnp.pad(chunk, ((0, bucket - m), (0, 0)))
                     chunk = self._reshard_bucket(chunk)
+                    rebucket_ns += time.perf_counter_ns() - t0
                     return dispatch(chunk, bucket, m)[:m]
 
-                outs = stream.run_windows(
-                    "scoring", N, window, maxb,
-                    row_bytes=self._row_bytes_hint(),
-                    window_sizer=self._window_snap)
+                outs = windows(int(X.shape[0]), window)
+        obs_metrics.inc("h2o3_score_flush_windows_total", n_disp, arm=arm)
+        obs_metrics.inc("h2o3_score_flush_entries_total", len(items),
+                        arm=arm)
         K = self._out_k()
         if not outs:
             return jnp.zeros((0,) if K == 1 else (0, K), jnp.float32), 0
-        return (outs[0] if len(outs) == 1
-                else jnp.concatenate(outs)), n_disp
+        if len(outs) == 1:
+            return outs[0], n_disp
+        with tracing.span("join", pieces=len(outs)):
+            return jnp.concatenate(outs), n_disp
 
     def _lift_entry_margins(self, mg, n: int, padded_rows: int):
         """Pad one entry's exact (n, …) device margins out to its frame's
@@ -700,6 +724,16 @@ class ScoringSession:
         with tracing.span("metrics", rows=n, path=path):
             return pred, self.model._make_metrics(frame, raw)
 
+    def _adapt_view(self, frame, n: int, local_mp: bool):
+        """(adapted frame, its ShardedFrame view or None) of one entry on
+        the staged path: span ``adapt``, then span ``view``."""
+        with tracing.span("adapt", rows=n):
+            adapted = self.model.adapt_test(frame)
+        if local_mp:
+            return adapted, None
+        with tracing.span("view", rows=n, of="shards"):
+            return adapted, self._sharded_view(adapted)
+
     def predict_batch(self, entries: List[Tuple[Any, Optional[str], bool]],
                       local_only: bool = False):
         """Score a coalesced batch: entries = [(frame, dest_key,
@@ -770,16 +804,15 @@ class ScoringSession:
 
                 if pipeline.enabled():
                     try:
-                        cap = pipeline.try_capture(self, frame)
+                        with tracing.span("view", rows=n, of="pipeline"):
+                            cap = pipeline.try_capture(self, frame)
                     except Exception:   # noqa: BLE001 — staged is the
                         cap = None      # contract for anything capture
                     if cap is not None:  # cannot hold
                         pipe_entries.append((i, frame, n, dest,
                                              with_metrics, cap))
                         continue
-            with tracing.span("adapt", rows=n):
-                adapted = self.model.adapt_test(frame)
-            sf = None if local_mp else self._sharded_view(adapted)
+            adapted, sf = self._adapt_view(frame, n, local_mp)
             if sf is not None:
                 sharded_entries.append((i, frame, n, dest, with_metrics,
                                         sf))
@@ -811,9 +844,7 @@ class ScoringSession:
                     mg, nd = pipeline.execute_margins(self, cap)
                 except Exception:   # noqa: BLE001 — abandon to staged
                     pipeline.note_fallback(cap)
-                    with tracing.span("adapt", rows=n):
-                        adapted = self.model.adapt_test(frame)
-                    sf = None if local_mp else self._sharded_view(adapted)
+                    adapted, sf = self._adapt_view(frame, n, local_mp)
                     if sf is not None:
                         sharded_entries.append((i, frame, n, dest,
                                                 with_metrics, sf))
@@ -823,8 +854,9 @@ class ScoringSession:
                     continue
                 n_dispatches += nd
                 sharded_frame.note_packed(n)
-                raw = self.model._margin_to_raw(
-                    self._lift_entry_margins(mg, n, cap.padded))
+                with tracing.span("lift", rows=n, path="pipeline"):
+                    raw = self.model._margin_to_raw(
+                        self._lift_entry_margins(mg, n, cap.padded))
                 results[i] = self._assemble_result(frame, raw, n, dest,
                                                    with_metrics, "pipeline")
         if sharded_entries:
@@ -835,11 +867,15 @@ class ScoringSession:
             n_dispatches += nd
             off = 0
             for i, frame, n, dest, with_metrics, sf in sharded_entries:
-                mg = margins[off: off + n]
+                # the entry's own rows of the flush's margins, padded and
+                # resharded to its frame's layout, then margin -> raw: eager
+                # device ops the host only enqueues
+                with tracing.span("lift", rows=n, path="sharded"):
+                    mg = margins[off: off + n]
+                    raw = self.model._margin_to_raw(
+                        self._lift_entry_margins(mg, n, sf.padded_rows))
                 off += n
                 sharded_frame.note_packed(n)
-                raw = self.model._margin_to_raw(
-                    self._lift_entry_margins(mg, n, sf.padded_rows))
                 results[i] = self._assemble_result(frame, raw, n, dest,
                                                    with_metrics, "sharded")
         if host_entries:

@@ -413,8 +413,11 @@ class TestScoringMetricsRest:
             assert roots[0]["name"] == "ingress"
             children = {c["name"]: c for c in roots[0]["children"]}
             assert {"queue_wait", "flush"} <= set(children)
-            assert {"adapt", "pack", "dispatch", "fetch", "metrics"} <= {
-                c["name"] for c in children["flush"]["children"]}
+            phases = {c["name"]: c for c in children["flush"]["children"]}
+            assert {"adapt", "windows", "fetch", "metrics"} <= set(phases)
+            # one entry: the window loop packs and dispatches (ISSUE 36)
+            assert {"pack", "dispatch"} <= {
+                c["name"] for c in phases["windows"]["children"]}
             # -- cluster /3/Metrics agrees with the data_plane block
             with urllib.request.urlopen(base + "/3/Metrics",
                                         timeout=30) as r:

@@ -237,8 +237,9 @@ def test_walk_levels_ride_the_open_span_and_add_no_dispatch(cl):
     levels = sum(s["attrs"].get("walk_levels", 0) for s in spans)
     assert levels == 9 * (n_dispatch + 2)              # + the two by hand
     assert all(s["attrs"].get("walk_gather_levels", 0) == 0 for s in spans)
+    # a flush's dispatches happen under its `windows` span (ISSUE 36)
     assert {s["name"] for s in spans if "walk_levels" in s["attrs"]} \
-        <= {"ingress", "flush"}
+        <= {"ingress", "windows"}
 
 
 def test_spans_never_step_with_the_wall_clock(monkeypatch):
@@ -301,8 +302,11 @@ def test_coalesced_follower_gets_the_flush_in_its_own_trace(cl, monkeypatch):
     lead_flush = next(s for s in lead if s["name"] == "flush")
     assert (by_name["flush"]["start_ms"], by_name["flush"]["end_ms"]) == \
         (lead_flush["start_ms"], lead_flush["end_ms"])
-    assert {"adapt", "pack", "dispatch", "fetch"} <= {
+    assert {"adapt", "pack", "windows", "fetch"} <= {
         s["name"] for s in lead if s["parent_id"] == lead_flush["span_id"]}
+    (windows,) = [s for s in lead if s["name"] == "windows"]
+    assert {s["parent_id"] for s in lead if s["name"] == "dispatch"} == \
+        {windows["span_id"]}
     # queue_wait runs into flush without a hole, so ingress keeps only the
     # hand-over on either side
     ingress = by_name["ingress"]
@@ -335,6 +339,12 @@ def test_a_compile_lands_under_the_span_it_happened_in(cl):
     found = [s for s in spans if s["name"] == "compile"]
     assert found and all(s["parent_id"] == sp.span["span_id"] and
                          s["attrs"]["seconds"] > 0 for s in found)
+    # jax's fun_name: the span says which program it was (the eager ones
+    # of `jnp.ones` beside it), so a compile inside a flush names the eager
+    # op that caused it; an attribute, never a counter label
+    programs = {s["attrs"]["program"] for s in found}
+    assert "jit(fresh)" in programs
+    assert programs <= {"jit(fresh)", "jit(broadcast_in_dim)"}
     assert _counter("h2o3_backend_compile_seconds_total") > 0
 
 
