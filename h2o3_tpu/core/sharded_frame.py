@@ -282,6 +282,37 @@ def _packer_exe(key: tuple, jfn, call_args, program: str,
     return exe
 
 
+# device int32 scalars for the packers' `pos` / `n` (and the scoring
+# lay-out's `n`), replicated over the mesh where the executables read them,
+# keyed by value: a host value handed to an executable is a transfer every
+# call (about 200 us each on a v5e's host, PERF.md section 6, PR 37), a
+# cached device value none. A flush reads few values (the multiples of a
+# bucket, one n a frame), so a bound like _EXE_CACHE's holds them.
+_SCALARS: dict = {}
+_SCALAR_CAP = 4096
+_SCALAR_LOCK = threading.Lock()
+
+
+def device_int32(v: int, mesh):
+    """The int32 scalar `v` as a device array replicated over `mesh`,
+    made once a value (lock-free warm read, a lock on the miss)."""
+    key = (int(v), mesh)
+    d = _SCALARS.get(key)
+    if d is not None:
+        return d
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    with _SCALAR_LOCK:
+        d = _SCALARS.get(key)
+        if d is None:
+            d = jax.device_put(np.int32(v), NamedSharding(mesh, P()))
+            if len(_SCALARS) >= _SCALAR_CAP:
+                _SCALARS.pop(next(iter(_SCALARS)))
+            _SCALARS[key] = d
+    return d
+
+
 def _sharding_key(arrs) -> tuple:
     # the sharding OBJECTS, not their str(): jax shardings are hashable/
     # eq-comparable, and stringifying one per column per dispatch would
@@ -297,13 +328,16 @@ class ShardedFrame:
     switched off) — callers fall back to their legacy host/eager path and
     count the rows as ``gathered``."""
 
-    __slots__ = ("frame", "names", "_datas", "_cl", "padded_rows")
+    __slots__ = ("frame", "names", "_datas", "_dtypes", "_cl",
+                 "padded_rows")
 
     def __init__(self, frame, names: List[str], datas: list, cl,
                  padded_rows: int):
         self.frame = frame
         self.names = names
         self._datas = datas
+        # the packers' cache key: once a view, never once a chunk
+        self._dtypes = tuple(str(d.dtype) for d in datas)
         self._cl = cl
         self.padded_rows = padded_rows
 
@@ -364,15 +398,18 @@ class ShardedFrame:
     def pack_features(self, pos: int, n: int, bucket: int):
         """(bucket, F) float32 scoring matrix for logical rows
         [pos, min(pos+bucket, n)), zero elsewhere — built on device from
-        the columns' addressable shards; the host never sees a column."""
-        import jax.numpy as jnp
-
+        the columns' addressable shards; the host never sees a column.
+        `pos` and `n` go as cached device scalars (device_int32): once a
+        value has been seen, no transfer or eager device op runs before
+        the executable."""
         from h2o3_tpu.obs import tracing
 
-        dtypes = tuple(str(d.dtype) for d in self._datas)
+        dtypes = self._dtypes
         fn = _pack_features_fn(int(bucket), self.padded_rows, dtypes,
                                self._cl.mesh)
-        args = (jnp.int32(pos), jnp.int32(n)) + tuple(self._datas)
+        mesh = self._cl.mesh
+        args = (device_int32(pos, mesh), device_int32(n, mesh)) + \
+            tuple(self._datas)
         exe = _packer_exe(
             ("features", int(bucket), self.padded_rows, dtypes,
              self._cl.mesh, _sharding_key(self._datas)),

@@ -66,10 +66,12 @@ _PUBLISHED: "collections.deque[str]" = collections.deque()
 
 
 def trace_cap() -> int:
+    # 1,024: a traced 40 s window of gbm_batch_score makes over 300 traces
+    # since PR 37, and a window's readers need 95% of them still held
     try:
-        return max(int(os.environ.get("H2O_TPU_OBS_TRACE_CAP", "256")), 1)
+        return max(int(os.environ.get("H2O_TPU_OBS_TRACE_CAP", "1024")), 1)
     except ValueError:
-        return 256
+        return 1024
 
 
 # epoch ns at perf_counter 0: taken once, so the clock below never steps

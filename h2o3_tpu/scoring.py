@@ -17,7 +17,7 @@ Two properties make this a serving engine rather than a batch scorer
   keeping the trace count bounded. Padded rows are zero-filled and sliced
   off before anything reads them.
 - **Micro-batching**: concurrent requests against the SAME model coalesce
-  into one dispatch inside a time-boxed window
+  into one flush (one window loop) inside a time-boxed window
   (``H2O_TPU_SCORE_BATCH_WINDOW_MS``, default 2 ms); each request gets its
   exact row-slice back. Requests against different models never block
   each other (per-model queues). On a multi-process cloud the whole batch
@@ -30,7 +30,9 @@ traversal compile count) land in the timeline ring and are snapshotted by
 
 from __future__ import annotations
 
+import bisect
 import collections
+import functools
 import os
 import threading
 import time
@@ -41,6 +43,33 @@ import numpy as np
 from h2o3_tpu.obs import tracing
 
 _DEFAULT_BUCKETS = (256, 1024, 4096, 16384)
+
+
+@functools.lru_cache(maxsize=1024)
+def _lay_out_fn(lens: tuple, padded: int, tail: tuple, sharding):
+    """(n, *outs) -> one entry's margins as its frame's rows, row-sharded:
+    window output j gives its first ``lens[j]`` rows, back to back, zeros
+    fill out to `padded` rows, and every row from n on is exactly 0.0 (the
+    pad `_lift_entry_margins` writes). Its shapes follow the windows and
+    the frame, never n, so one compile serves every row count that windows
+    alike."""
+    import jax.numpy as jnp
+
+    from h2o3_tpu.obs import compiles
+
+    def lay_out(n, *outs):
+        x = jnp.concatenate([o[:v] for o, v in zip(outs, lens)]) if outs \
+            else jnp.zeros((0,) + tail, jnp.float32)
+        if x.shape[0] >= padded:
+            x = x[:padded]
+        else:
+            x = jnp.pad(x, ((0, padded - x.shape[0]),) + ((0, 0),) * len(tail))
+        keep = jnp.arange(padded, dtype=jnp.int32) < n
+        return jnp.where(keep.reshape((padded,) + (1,) * len(tail)), x,
+                         jnp.float32(0))
+
+    return compiles.ledgered_jit("pack", lay_out, program="lay_out_margins",
+                                 out_shardings=sharding)
 
 # -- per-process fused-dispatch accounting ----------------------------------
 # one increment per fused program execution on the serving/explainability
@@ -244,13 +273,22 @@ class ScoringSession:
         return self.buckets[-1]
 
     def _window_snap(self, w: int) -> int:
-        """Snap a planner-chosen window DOWN onto the bucket ladder so
-        chunk streaming reuses the compiled bucket programs (below the
-        smallest bucket the window stays as-is and pads up into it)."""
+        """Snap a planner-chosen window DOWN to a size that divides the top
+        bucket, so chunk streaming reuses the compiled bucket programs: the
+        largest bucket that is the top bucket over a power of two, else the
+        largest such quotient, else 1. Every size so chosen divides every
+        larger one, so whatever the planner and the OOM ladder pick, a
+        window starts at a multiple of its own size and never crosses a
+        multiple of the top bucket (_margins_sharded_batch's entries)."""
+        top = self.buckets[-1]
+        sizes = [top]
+        while sizes[-1] % 2 == 0:
+            sizes.append(sizes[-1] // 2)
+        sizes.append(1)
         for b in reversed(self.buckets):
-            if b <= w:
+            if b <= w and b in sizes:
                 return b
-        return max(w, 1)
+        return next(d for d in sizes if d <= max(w, 1))
 
     def _row_bytes_hint(self) -> float:
         """Static working-set bytes/row for one fused dispatch: packed
@@ -447,56 +485,53 @@ class ScoringSession:
                                          or self.forest.per_class_trees)
                 else 1)
 
-    def _reshard_bucket(self, x):
-        """Re-lay a device (bucket, F) matrix out as P('rows', None) — the
-        EXACT input sharding the shard_map'd fused programs are lowered
-        with (ShardedFrame.pack_features' out_shardings), so a coalesced
-        chunk and a directly-packed matrix hit the same AOT executable.
-        Device-to-device only; jit identity on multi-process (cross-host
-        resharding goes through XLA)."""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from h2o3_tpu.core.sharded_frame import ROW_AXIS
-
-        sh = NamedSharding(self._cl.mesh, P(ROW_AXIS, None))
-        if jax.process_count() > 1:
-            return jax.jit(lambda a: a, out_shardings=sh)(x)
-        return jax.device_put(x, sh)
-
-    def _margins_sharded_batch(self, items) -> Tuple[Any, int]:
+    def _margins_sharded_batch(self, items) -> Tuple[List[Any], int]:
         """Fused margins for ALL sharded-eligible entries of one flush:
         ``items`` is ``[(sf, n)]`` in flush order; returns (margins,
-        dispatches) where margins is ONE device array holding the flush's
-        exact logical rows back to back — (ΣN,) or (ΣN, K) — and
-        dispatches counts fused program executions.
+        dispatches): per entry, its margins laid out as its frame's rows —
+        (padded_rows,) or (padded_rows, K), row-sharded, exactly 0.0 from
+        row n on — and the count of fused program executions.
 
-        A multi-entry flush device-concatenates the per-entry
-        shard-packed matrices (each already built from addressable shards
-        — zero gathers) and dispatches ONE fused program per row-bucket
-        chunk of the concatenation: the host path's
-        one-dispatch-per-bucket batching, now with no host round-trip.
-        This deletes the recorded PR-7 trade-off (one fused dispatch PER
-        ENTRY per flush). A single-entry flush keeps the direct per-chunk
-        dispatch — no concat/reshard detour on the latency path.
+        One window loop a flush, whatever the number of entries: entry i's
+        rows start at ``off_i = sum(ceil(n_j / maxb) * maxb for j < i)`` of
+        one row space, and every window size divides the top bucket
+        (:meth:`_window_snap`), so no window straddles two entries. A
+        window is two compiled programs back to back, ``pack_features``
+        on the owning entry's chunk and the fused score program on its
+        output; no eager device op runs between two windows, and a window
+        that lies wholly in an entry's pad dispatches nothing. Each
+        entry's outputs are then laid out by one compiled program
+        (:func:`_lay_out_fn`) whose shapes follow the windows and the
+        frame, never the row count: a flush of any combination of row
+        counts compiles nothing once its buckets are warm.
 
         Bitwise contract: the fused program is row-local (bin + walk per
-        row), so every logical row's margin is independent of which
-        bucket chunk carried it — rows [0, n_i) equal the host-packed
-        path's margins per entry; pad lanes are zero-filled and sliced
-        off before anything reads them."""
-        import jax.numpy as jnp
-
+        row), so every logical row's margin is independent of which window
+        carried it — rows [0, n_i) equal the host-packed path's margins
+        per entry; pad rows are exactly 0.0, as `_lift_entry_margins`
+        pads."""
         from h2o3_tpu.memory import stream
         from h2o3_tpu.obs import metrics as obs_metrics
 
         maxb = self.buckets[-1]
         n_disp = 0
-        rebucket_ns = 0
         arm = "single" if len(items) == 1 else "coalesced"
+        offs, end = [], 0
+        for _sf, n in items:
+            offs.append(end)
+            end += -(-n // maxb) * maxb
+        rows = offs[-1] + items[-1][1]      # the last entry needs no pad
 
-        def dispatch(Xd, bucket: int, rows: int):
+        def window(pos: int, m: int):
             nonlocal n_disp
+            e = bisect.bisect_right(offs, pos) - 1
+            sf, n = items[e]
+            p = pos - offs[e]
+            r = min(m, n - p)
+            if r <= 0:
+                return ()                   # in the entry's pad: no rows
+            bucket = self._bucket_for(r)
+            Xd = sf.pack_features(p, n, bucket)
             call_args = (Xd, self._edges, self._is_cat, self._init) + \
                 tuple(self._arrays)
             exe = self._executable_for(bucket, False, call_args,
@@ -504,84 +539,46 @@ class ScoringSession:
             # host-side dispatch wall time only — the program is async and
             # NO block_until_ready is added here (the fused-path counters
             # assert the path is unchanged when profiling is off)
-            with tracing.span("dispatch", bucket=bucket, rows=rows,
+            with tracing.span("dispatch", bucket=bucket, rows=r,
                               path="sharded"):
                 out = exe(*call_args)
             n_disp += 1
             self._note_dispatch("sharded")
-            return out
+            return e, out, min(m, bucket), bucket
 
-        def windows(rows: int, window) -> List[Any]:
-            # ONE span over the whole window loop, never one a window: its
-            # self time is what the host does between two dispatches (the
-            # executable lookup, the [:m] on each output and, coalesced, the
-            # re-bucketing, which rides as `rebucket_ms`); the planner's
-            # `plan` and the `dispatch` spans lie beneath it
-            with tracing.span("windows", arm=arm, entries=len(items)) as ws:
-                outs = stream.run_windows(
-                    "scoring", rows, window, maxb,
-                    row_bytes=self._row_bytes_hint(),
-                    window_sizer=self._window_snap)
-                ws.set(windows=n_disp,
-                       rebucket_ms=round(rebucket_ns / 1e6, 3))
-            return outs
-
-        outs: List[Any] = []
-        if arm == "single":
-            sf, n = items[0]
-
-            def window(pos: int, m: int):
-                bucket = self._bucket_for(m)
-                Xd = sf.pack_features(pos, n, bucket)
-                return dispatch(Xd, bucket, m)[:m]
-
-            outs = windows(n, window)
-        else:
-            parts: List[Any] = []
-            # per entry and bucket chunk a `pack` span (pack_features',
-            # around its executable alone); what `parts` keeps for itself is
-            # what pack_features does before it (two device scalars, the
-            # dtype tuple, the lookup) and the eager Xd[:m] of a tail
-            with tracing.span("parts", entries=len(items)):
-                for sf, n in items:
-                    pos = 0
-                    while pos < n:
-                        m = min(maxb, n - pos)
-                        bucket = self._bucket_for(m)
-                        Xd = sf.pack_features(pos, n, bucket)
-                        parts.append(Xd if m == bucket else Xd[:m])
-                        pos += m
-            if parts:
-                total = sum(n for _, n in items)
-                # the device-side concat of per-entry shard-packed
-                # matrices — slices/concat/pad are cheap elementwise
-                # device ops, never a host staging
-                with tracing.span("pack", rows=total, path="coalesce"):
-                    X = parts[0] if len(parts) == 1 else \
-                        jnp.concatenate(parts)
-
-                def window(pos: int, m: int):
-                    nonlocal rebucket_ns
-                    bucket = self._bucket_for(m)
-                    t0 = time.perf_counter_ns()
-                    chunk = X[pos: pos + m]
-                    if m < bucket:
-                        chunk = jnp.pad(chunk, ((0, bucket - m), (0, 0)))
-                    chunk = self._reshard_bucket(chunk)
-                    rebucket_ns += time.perf_counter_ns() - t0
-                    return dispatch(chunk, bucket, m)[:m]
-
-                outs = windows(int(X.shape[0]), window)
+        # ONE span over the whole window loop, never one a window: its self
+        # time is what the host does between two programs (the executable
+        # lookups and the loop); the planner's `plan` and each window's
+        # `pack` and `dispatch` spans lie beneath it
+        with tracing.span("windows", arm=arm, entries=len(items)) as ws:
+            done = stream.run_windows(
+                "scoring", rows, window, maxb,
+                row_bytes=self._row_bytes_hint(),
+                window_sizer=self._window_snap)
+            ws.set(windows=n_disp, rebucket_ms=0.0)
         obs_metrics.inc("h2o3_score_flush_windows_total", n_disp, arm=arm)
         obs_metrics.inc("h2o3_score_flush_entries_total", len(items),
                         arm=arm)
+        outs: List[List[tuple]] = [[] for _ in items]
+        for w in done:
+            if w:
+                outs[w[0]].append(w[1:])
+        from h2o3_tpu.core.sharded_frame import device_int32
+
         K = self._out_k()
-        if not outs:
-            return jnp.zeros((0,) if K == 1 else (0, K), jnp.float32), 0
-        if len(outs) == 1:
-            return outs[0], n_disp
-        with tracing.span("join", pieces=len(outs)):
-            return jnp.concatenate(outs), n_disp
+        tail = () if K == 1 else (K,)
+        sharding = self._cl.row_sharding()
+        with tracing.span("join", pieces=n_disp):
+            margins = []
+            for (sf, n), got in zip(items, outs):
+                # each window gives the rows it covers; the entry's last
+                # gives its whole bucket, whose rows past n the mask zeroes
+                lens = tuple(v for _o, v, _b in got[:-1]) + \
+                    tuple(b for _o, _v, b in got[-1:])
+                fn = _lay_out_fn(lens, sf.padded_rows, tail, sharding)
+                margins.append(fn(device_int32(n, self._cl.mesh),
+                                  *(o for o, _v, _b in got)))
+        return margins, n_disp
 
     def _lift_entry_margins(self, mg, n: int, padded_rows: int):
         """Pad one entry's exact (n, …) device margins out to its frame's
@@ -753,15 +750,12 @@ class ScoringSession:
         coalesced into one bucketed program — or, multi-process, the
         generic predict path.
 
-        Coalesced dispatch (the PR-7 trade-off, removed): ALL
-        sharded-eligible entries of a flush are scored by ONE fused
-        dispatch per row-bucket chunk — their shard-packed matrices are
-        concatenated device-side (zero gathers) and the concatenation is
-        chunked at the bucket ladder exactly like the host path's
-        concatenated batches. A flush of many small entries therefore
-        costs ~one fused program execution per bucket, not one per entry;
-        the per-entry work that remains (adapt, margin→raw, frame
-        install, metrics) was per-entry on both paths. Dispatch counts
+        Coalesced dispatch: ALL sharded-eligible entries of a flush run
+        through ONE window loop under one memory plan, each entry in
+        windows of its own rows (_margins_sharded_batch): a window is the
+        entry's packing program and the fused program, back to back, and
+        no device op between two windows depends on the combination of row
+        counts, so a coalesced flush compiles nothing new. Dispatch counts
         land on /3/ScoringMetrics (``dispatches``) and
         ``h2o3_score_dispatches_total``.
 
@@ -865,16 +859,12 @@ class ScoringSession:
             margins, nd = self._margins_sharded_batch(
                 [(sf, n) for _i, _f, n, _d, _w, sf in sharded_entries])
             n_dispatches += nd
-            off = 0
-            for i, frame, n, dest, with_metrics, sf in sharded_entries:
-                # the entry's own rows of the flush's margins, padded and
-                # resharded to its frame's layout, then margin -> raw: eager
-                # device ops the host only enqueues
+            for (i, frame, n, dest, with_metrics, _sf), mg in zip(
+                    sharded_entries, margins):
+                # the entry's margins, already laid out as its frame's
+                # rows, to raw: eager device ops the host only enqueues
                 with tracing.span("lift", rows=n, path="sharded"):
-                    mg = margins[off: off + n]
-                    raw = self.model._margin_to_raw(
-                        self._lift_entry_margins(mg, n, sf.padded_rows))
-                off += n
+                    raw = self.model._margin_to_raw(mg)
                 sharded_frame.note_packed(n)
                 results[i] = self._assemble_result(frame, raw, n, dest,
                                                    with_metrics, "sharded")

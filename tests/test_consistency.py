@@ -296,13 +296,13 @@ def test_ingest_never_stages_whole_columns_on_coordinator(tmp_path):
     fr.delete()
 
 
-def test_multi_entry_flush_is_one_dispatch_per_bucket():
-    """ISSUE-13 guard: a multi-entry micro-batch flush on the sharded
-    path must coalesce into exactly ONE fused dispatch per row bucket
-    (device-side concat of the per-entry shard-packed matrices) with
-    ``gathered_rows`` untouched — the serving tier's
-    one-dispatch-per-flush contract. A regression back to the PR-7
-    per-entry dispatch (or to a host gather) trips this immediately."""
+def test_multi_entry_flush_is_one_dispatch_per_entry_window():
+    """ISSUE-13 guard, as ISSUE 37 left it: a multi-entry micro-batch
+    flush on the sharded path runs ONE window loop in which each entry is
+    windowed on its own rows (one dispatch for an entry that fits a
+    bucket) with ``gathered_rows`` untouched, and a second combination of
+    row counts compiles nothing. A regression to a host gather, or to an
+    eager op shaped by the combination, trips this immediately."""
     import numpy as np
 
     import h2o3_tpu
@@ -329,17 +329,26 @@ def test_multi_entry_flush_is_one_dispatch_per_bucket():
             np.random.default_rng(seed).standard_normal(m)))
         return sfr
 
+    from h2o3_tpu.obs import metrics
+
+    def compiled():
+        return sum(x["value"] for x in metrics.REGISTRY.get(
+            "h2o3_backend_compiles_total").snapshot()["samples"])
+
     sess = scoring.ScoringSession(model)
     frames = [score_fr(40 + 13 * i, 100 + i) for i in range(4)]
     sess.predict(frames[0])                 # warm the one bucket involved
+    sess.predict_batch([(f, None, False) for f in frames])
     before = sharded_frame.counters()
     scoring.reset_dispatch_counters()
-    sess.predict_batch([(f, None, False) for f in frames])
+    c0 = compiled()
+    sess.predict_batch([(f, None, False) for f in frames[::-1]])
     dc = scoring.dispatch_counters()
     after = sharded_frame.counters()
-    assert dc.get("sharded") == 1, (
-        f"a 4-entry flush recorded {dc} fused dispatches — the "
-        "coalesced one-dispatch-per-bucket contract is broken")
+    assert dc.get("sharded") == 4, (
+        f"a 4-entry flush recorded {dc} fused dispatches — one window "
+        "an entry is the contract")
+    assert compiled() == c0, "a new combination of row counts compiled"
     assert after["gathered_rows"] == before["gathered_rows"], (
         "the coalesced flush gathered columns to the coordinator host")
 

@@ -1,7 +1,9 @@
 """The inside of a scoring flush (ISSUE 36): one span a sequential phase
-where the host does the work (`view`, `parts`, `windows` with the memory
-planner's `plan` in it, `join`, `lift` under `flush`), never one a window; two counters at the same boundary; what
-the span store's bounds drop is counted.
+where the host does the work (`view`, `windows` with the memory planner's
+`plan` and each window's `pack` and `dispatch` in it, `join`, `lift` under
+`flush`), never one a window; two counters at the same boundary; what the
+span store's bounds drop is counted. Since ISSUE 37 every entry is windowed
+on its own rows, so the coalesced arm's `parts` span is gone.
 
 Small frames on the CPU mesh with the bucket ladder lowered, so that a
 flush of two 300-row requests is several windows. What is asserted is the
@@ -19,18 +21,18 @@ from tests.test_trace_tree import _frame
 
 pytestmark = pytest.mark.obs
 
-NEW = ("view", "parts", "windows", "join", "lift")
+NEW = ("view", "windows", "join", "lift")
 # what a two-entry flush may add to its lead's trace, whatever the number of
 # windows: view x 2 (x 2 more where the pipeline splice looks first),
-# parts, windows and the plan in it, join, lift x 2. A span a window would
-# pass it at once.
+# windows and the plan in it, join, lift x 2. A span a window would pass it
+# at once.
 MAX_NEW_SPANS = 12
 
 
 @pytest.fixture(scope="module")
 def served(cl):
     """A model whose session chunks at 128 rows, two 300-row frames (three
-    windows each alone, five coalesced) and one of 100 rows (one window),
+    windows each, alone or coalesced) and one of 100 rows (one window),
     every program compiled."""
     from h2o3_tpu import scoring
     from h2o3_tpu.models.tree.gbm import GBM
@@ -134,10 +136,10 @@ def test_two_entry_flush_names_every_phase(served, monkeypatch):
                             if s["name"] in NEW])
     names = [s["name"] for s in new]
     assert [n for n in names if n != "view"] == \
-        ["parts", "windows", "join", "lift", "lift"]
+        ["windows", "join", "lift", "lift"]
     assert names.count("view") in (2, 4) and \
-        names.index("parts") > max(i for i, n in enumerate(names)
-                                   if n == "view")
+        names.index("windows") > max(i for i, n in enumerate(names)
+                                     if n == "view")
     for a, b in zip(new, new[1:]):
         assert a["end_ms"] <= b["start_ms"], (a["name"], b["name"])
     for s in new:
@@ -146,28 +148,25 @@ def test_two_entry_flush_names_every_phase(served, monkeypatch):
     assert len(new) + 1 <= MAX_NEW_SPANS        # + the plan under windows
     assert len([s for s in lead if s["name"] in NEW + ("plan",)]) \
         == len(new) + 1
-    # what stays where it was: adapt, the coalescing pack, fetch, metrics
-    assert {"adapt", "pack", "fetch", "metrics"} <= {s["name"]
-                                                     for s in direct}
-    assert [s["attrs"]["path"] for s in direct if s["name"] == "pack"] == \
-        ["coalesce"]
-    # windows: one span, its count = the dispatch spans beneath it = what
-    # the dispatch counters saw; 600 rows at 128 a window
+    # what stays where it was: adapt, fetch, metrics; no coalescing pack
+    assert {"adapt", "fetch", "metrics"} <= {s["name"] for s in direct}
+    assert "pack" not in {s["name"] for s in direct}
+    assert "parts" not in {s["name"] for s in lead}
+    # windows: one span; under it one plan, then a pack and a dispatch a
+    # window, each entry's 300 rows at 128 a window: 3 + 3 = what the
+    # dispatch counters saw
     (win,) = [s for s in new if s["name"] == "windows"]
     under = [s for s in lead if s["parent_id"] == win["span_id"]]
-    assert [s["name"] for s in under] == ["plan"] + ["dispatch"] * 5
+    assert [s["name"] for s in under] == ["plan"] + ["pack", "dispatch"] * 6
     assert under[0]["attrs"] == {"family": "scoring", "mode": "full"}
-    assert win["attrs"]["windows"] == len(under) - 1 == n_dispatch == 5
+    assert [s["attrs"]["rows"] for s in under if s["name"] == "dispatch"] \
+        == [128, 128, 44] * 2
+    assert win["attrs"]["windows"] == n_dispatch == 6
     assert win["attrs"]["arm"] == "coalesced" and \
         win["attrs"]["entries"] == 2
-    own = win["ms"] - sum(s["ms"] for s in under)
-    assert 0 <= win["attrs"]["rebucket_ms"] <= own + 0.01
-    # parts holds the per-chunk packs: 3 chunks an entry
-    (parts,) = [s for s in new if s["name"] == "parts"]
-    assert [s["name"] for s in lead
-            if s["parent_id"] == parts["span_id"]] == ["pack"] * 6
+    assert win["attrs"]["rebucket_ms"] == 0.0
     (join,) = [s for s in new if s["name"] == "join"]
-    assert join["attrs"]["pieces"] == 5
+    assert join["attrs"]["pieces"] == 6
     assert [s["attrs"]["rows"] for s in new if s["name"] == "lift"] == \
         [300, 300]
     # the follower's trace is what it was
@@ -180,7 +179,7 @@ def test_two_entry_flush_names_every_phase(served, monkeypatch):
     after = {arm: (_counter("h2o3_score_flush_windows_total", arm=arm),
                    _counter("h2o3_score_flush_entries_total", arm=arm))
              for arm in ("single", "coalesced")}
-    assert after["coalesced"] == (before["coalesced"][0] + 5,
+    assert after["coalesced"] == (before["coalesced"][0] + 6,
                                   before["coalesced"][1] + 2)
     assert after["single"] == before["single"]
 
@@ -199,9 +198,8 @@ def test_single_entry_flush_has_windows_and_no_parts(served, which, windows):
     flush = next(s for s in spans if s["name"] == "flush")
     names = [s["name"] for s in spans if s["parent_id"] == flush["span_id"]
              and s["name"] in NEW]
-    # one window: nothing to join; several: the outputs' concatenation
-    assert [n for n in names if n != "view"] == \
-        ["windows"] + ["join"] * (windows > 1) + ["lift"]
+    # join lays the windows' outputs out as the frame's rows, one or many
+    assert [n for n in names if n != "view"] == ["windows", "join", "lift"]
     win = next(s for s in spans if s["name"] == "windows")
     # count_walk's sums ride the span open at the dispatch: this one now
     assert win["attrs"] == {"arm": "single", "entries": 1,
@@ -239,7 +237,7 @@ def test_a_trace_changes_nothing_a_two_entry_flush_does(served, monkeypatch):
     flush(True)                         # whatever compiles, compiles here
     counts_u, compiled_u, out_u, _none, stored_u = flush(False)
     counts_t, compiled_t, out_t, lead_id, stored_t = flush(True)
-    assert counts_t == counts_u and sum(counts_u.values()) == 5
+    assert counts_t == counts_u and sum(counts_u.values()) == 6
     assert compiled_t == compiled_u == 0
     assert sess.traversal_compiles == compiles0
     for i in (0, 1):

@@ -242,3 +242,214 @@ class TestBatchingStress:
         preds = _concurrent_scores(gbm, frames)
         for fr, exp, got in zip(frames, expected, preds):
             _assert_frames_bitwise(exp, got, fr.nrows)
+
+
+# ---------------------------------------------------------------------------
+# one window route for one entry or many (ISSUE 37)
+# ---------------------------------------------------------------------------
+
+TOP = 128
+
+
+@pytest.fixture(scope="module")
+def small_buckets(cl, gbm):
+    """A session on gbm whose bucket ladder is 64/128 rows, so a flush of
+    a few hundred rows is several windows."""
+    from h2o3_tpu import scoring
+
+    mp = pytest.MonkeyPatch()
+    mp.setenv("H2O_TPU_SCORE_BUCKETS", f"64,{TOP}")
+    try:
+        sess = scoring.ScoringSession(gbm)
+    finally:
+        mp.undo()
+    assert sess.buckets == (64, TOP)
+    return sess
+
+
+def _entries(sess, gbm, sizes, seed=0):
+    """[(adapted frame, ShardedFrame view, n)] for fresh frames of `sizes`."""
+    out = []
+    for i, n in enumerate(sizes):
+        adapted = gbm.adapt_test(_score_frame(n, 700 + 10 * seed + i))
+        sf = sess._sharded_view(adapted)
+        assert sf is not None
+        out.append((adapted, sf, n))
+    return out
+
+
+def _compiles():
+    from h2o3_tpu.obs import metrics
+
+    return sum(s["value"] for s in metrics.REGISTRY.get(
+        "h2o3_backend_compiles_total").snapshot()["samples"])
+
+
+def _flush_windows(sess, ents, monkeypatch):
+    """Run one flush of `ents` through _margins_sharded_batch under a trace;
+    -> (per-entry host margins, [(pos, m)] windows dispatched, plan spans)."""
+    from h2o3_tpu.memory import stream
+    from h2o3_tpu.obs import tracing
+
+    seen = []
+    run = stream.run_windows
+
+    def spy(family, n, dispatch, *a, **kw):
+        def d(pos, m):
+            seen.append((pos, m))
+            return dispatch(pos, m)
+        return run(family, n, d, *a, **kw)
+
+    monkeypatch.setattr(stream, "run_windows", spy)
+    with tracing.root_span("ingress", path="/3/Predictions/x") as root:
+        margins, nd = sess._margins_sharded_batch(
+            [(sf, n) for _a, sf, n in ents])
+    monkeypatch.setattr(stream, "run_windows", run)
+    spans = tracing.get_trace(root.span["trace_id"], include_remote=False)
+    assert nd == sum(1 for s in spans if s["name"] == "dispatch")
+    return ([np.asarray(m) for m in margins], seen,
+            sum(1 for s in spans if s["name"] == "plan"))
+
+
+def _assert_no_straddle(seen, sizes):
+    """Every window lies inside one entry's range of the flush's row space
+    (entry i starts at sum(ceil(n_j / TOP) * TOP for j < i))."""
+    starts = np.cumsum([0] + [-(-n // TOP) * TOP for n in sizes])
+    for pos, m in seen:
+        e = int(np.searchsorted(starts, pos, side="right")) - 1
+        assert pos + m <= starts[e + 1], (pos, m, list(starts))
+
+
+def _assert_margins_exact(sess, ents, margins):
+    """Each entry's margins equal, byte for byte, the entry scored alone
+    and the host-packed path; rows past n of its frame are exactly 0.0."""
+    for (adapted, sf, n), mg in zip(ents, margins):
+        assert mg.shape[0] == sf.padded_rows
+        alone = np.asarray(sess._margins_sharded_batch([(sf, n)])[0][0])
+        host = sess._margin_x(sess._features(adapted, n))
+        assert mg[:n].tobytes() == alone[:n].tobytes() == host.tobytes()
+        assert mg[n:].tobytes() == np.zeros_like(mg[n:]).tobytes()
+
+
+class TestOneWindowRoute:
+    @pytest.mark.parametrize("chunk", [None, 100, 40],
+                             ids=["full", "chunked-100", "chunked-40"])
+    @pytest.mark.parametrize("sizes", [(300, 200), (300, 200, 129)],
+                             ids=["two", "three"])
+    def test_flush_is_bitwise_one_plan_and_never_straddles(
+            self, gbm, small_buckets, monkeypatch, sizes, chunk):
+        """A two- and a three-entry flush whose entries end inside a top
+        bucket, planned whole or chunked at a window that is no power of
+        two (100 snaps to the 64-row bucket, 40 below the smallest bucket
+        to 32 rows): one plan, no window across two entries, every margin
+        the entry's own."""
+        from h2o3_tpu.memory import budget
+
+        sess = small_buckets
+        ents = _entries(sess, gbm, sizes)
+        if chunk is not None:
+            orig = budget.plan
+
+            def fake(fam, rows, row_bytes=None):
+                if fam == "scoring" and rows > chunk:
+                    return budget.Plan("chunked", chunk, rows, 4.0, 1 << 20)
+                return orig(fam, rows, row_bytes)
+
+            monkeypatch.setattr(budget, "plan", fake)
+        margins, seen, plans = _flush_windows(sess, ents, monkeypatch)
+        assert plans == 1
+        win = {None: TOP, 100: 64, 40: 32}[chunk]
+        assert {m for _p, m in seen[:-1]} == {win}
+        _assert_no_straddle(seen, sizes)
+        _assert_margins_exact(sess, ents, margins)
+
+    @pytest.mark.chaos
+    def test_a_halved_window_recovers_the_same_bytes(
+            self, gbm, small_buckets, monkeypatch):
+        """`mem.exhausted` on the second window: the ladder halves to 64
+        rows from that window on, no window straddles, and the margins are
+        the untroubled ones."""
+        from h2o3_tpu.core import failure
+        from h2o3_tpu.memory import stream
+
+        sess = small_buckets
+        sizes = (300, 200, 129)
+        ents = _entries(sess, gbm, sizes, seed=1)
+        hits = {"n": 0}
+        faultpoint = failure.faultpoint
+
+        def second(name):
+            if name == "mem.exhausted":
+                hits["n"] += 1
+                if hits["n"] == 2:
+                    with failure.inject(name, times=1):
+                        faultpoint(name)
+            faultpoint(name)
+
+        monkeypatch.setattr(failure, "faultpoint", second)
+        c0 = stream.counters()
+        margins, seen, plans = _flush_windows(sess, ents, monkeypatch)
+        monkeypatch.setattr(failure, "faultpoint", faultpoint)
+        c1 = stream.counters()
+        assert c1["ladder_halvings"] - c0["ladder_halvings"] == 1
+        assert c1["ladder_recoveries"] - c0["ladder_recoveries"] == 1
+        assert plans == 1
+        assert seen[0] == (0, TOP) and seen[1] == (TOP, 64)
+        _assert_no_straddle(seen, sizes)
+        _assert_margins_exact(sess, ents, margins)
+
+    def test_a_new_combination_of_row_counts_compiles_nothing(
+            self, gbm, small_buckets):
+        """Two flushes of two small entries, (3, 5) then (4, 7): once the
+        bucket programs are warm, the second combination compiles no XLA
+        program at all, eager or not (the lookup cell's blocker)."""
+        from h2o3_tpu import scoring
+
+        sess = scoring.session_for(gbm)
+        first = [_score_frame(n, 900 + n) for n in (3, 5)]
+        then = [_score_frame(n, 900 + n) for n in (4, 7)]
+        refs = [gbm.predict(fr) for fr in first + then]
+        out = sess.predict_batch([(fr, None, True) for fr in first])
+        c0 = _compiles()
+        out += sess.predict_batch([(fr, None, True) for fr in then])
+        assert _compiles() == c0
+        for fr, ref, (pred, _mm) in zip(first + then, refs, out):
+            _assert_frames_bitwise(ref, pred, fr.nrows)
+
+
+class TestPackFeaturesArguments:
+    def test_no_eager_device_op_before_the_executable(self, gbm,
+                                                      small_buckets,
+                                                      monkeypatch):
+        """On a warm geometry `pack_features` makes no device value of its
+        own (pos and n reach the executable as host int32 values) and its
+        output is bitwise the host matrix, for pos = 0 and for a tail
+        chunk that runs past n."""
+        import jax
+        import jax.numpy as jnp
+
+        sess = small_buckets
+        ((adapted, sf, n),) = _entries(sess, gbm, (300,), seed=2)
+        host = sess._features(adapted, n)
+        for pos in (0, 256):
+            sf.pack_features(pos, n, 64)          # warm this geometry
+        made = []
+
+        def counted(fn):
+            def wrapper(*a, **kw):
+                made.append(fn)
+                return fn(*a, **kw)
+            return wrapper
+
+        for mod, name in ((jax, "device_put"), (jnp, "asarray"),
+                          (jnp, "array"), (jnp, "int32")):
+            monkeypatch.setattr(mod, name, counted(getattr(mod, name)))
+        c0 = _compiles()
+        got = {pos: np.asarray(sf.pack_features(pos, n, 64))
+               for pos in (0, 256)}
+        monkeypatch.undo()
+        assert made == [] and _compiles() == c0
+        assert got[0].tobytes() == host[:64].tobytes()
+        tail = np.zeros((64, host.shape[1]), np.float32)
+        tail[:n - 256] = host[256:]
+        assert got[256].tobytes() == tail.tobytes()

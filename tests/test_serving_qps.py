@@ -77,14 +77,14 @@ def _assert_frames_bitwise(a, b, n):
 
 
 # ---------------------------------------------------------------------------
-# coalesced flush: one fused dispatch per bucket per flush
+# coalesced flush: one window loop, each entry windowed on its own rows
 # ---------------------------------------------------------------------------
 
 class TestCoalescedFlush:
-    def test_multi_entry_flush_costs_one_dispatch(self, cl, gbm):
-        """5 sharded-eligible entries totalling < one bucket → exactly ONE
-        fused dispatch, per-entry results bitwise-identical to individual
-        predicts, gathered_rows untouched."""
+    def test_multi_entry_flush_costs_one_dispatch_an_entry(self, cl, gbm):
+        """5 sharded-eligible entries, each under one bucket → one fused
+        dispatch an entry, per-entry results bitwise-identical to
+        individual predicts, gathered_rows untouched."""
         from h2o3_tpu import scoring
         from h2o3_tpu.core import sharded_frame
 
@@ -98,7 +98,7 @@ class TestCoalescedFlush:
         out = sess.predict_batch([(fr, None, False) for fr in frames])
         dc = scoring.dispatch_counters()
         after_dp = sharded_frame.counters()
-        assert dc.get("sharded") == 1, dc
+        assert dc.get("sharded") == 5, dc
         assert "host" not in dc and "local" not in dc
         assert after_dp["gathered_rows"] == before_dp["gathered_rows"]
         for fr, ref, (pred, _mm) in zip(frames, refs, out):
@@ -106,9 +106,9 @@ class TestCoalescedFlush:
 
     def test_coalesced_flush_chunks_at_bucket_ladder(self, cl, gbm,
                                                      monkeypatch):
-        """Entries whose total exceeds the largest bucket chunk at it —
-        dispatches == ceil(total/maxb), still far below one per entry,
-        and every entry's slice stays bitwise."""
+        """Entries over the largest bucket chunk at it, each on its own
+        rows — dispatches == sum(ceil(n_i / maxb)) — and every entry's
+        slice stays bitwise."""
         import os
 
         from h2o3_tpu import scoring
@@ -116,15 +116,14 @@ class TestCoalescedFlush:
         os.environ["H2O_TPU_SCORE_BUCKETS"] = "256"
         try:
             sess = scoring.ScoringSession(gbm)
-            frames = [_score_frame(100, 50 + i) for i in range(6)]
+            frames = [_score_frame(300, 50 + i) for i in range(3)]
             refs = [gbm.predict(fr) for fr in frames]
             sess.predict(frames[0])        # warm the single bucket
             scoring.reset_dispatch_counters()
             out = sess.predict_batch([(fr, None, False) for fr in frames])
             dc = scoring.dispatch_counters()
-            # 600 logical rows over 256-row buckets → 3 chunks (not 6
-            # per-entry dispatches)
-            assert dc.get("sharded") == 3, dc
+            # 300 rows over 256-row buckets → 2 windows an entry
+            assert dc.get("sharded") == 6, dc
             for fr, ref, (pred, _mm) in zip(frames, refs, out):
                 _assert_frames_bitwise(ref, pred, fr.nrows)
         finally:
