@@ -8,25 +8,39 @@ input/hidden dropout, autoencoder mode, async per-node model averaging
 
 TPU-native design: Neurons.fprop/bprop collapse into one jitted
 loss-and-grad over the whole minibatch (jax.grad; the MXU eats the batched
-matmuls). Training is data-parallel SYNCHRONOUS SGD: the batch is gathered
-from the row-sharded design matrix and the gradient all-reduce is inserted
-by the SPMD partitioner — equivalent to the reference's model averaging with
-averaging period = 1 batch, but deterministic. An entire epoch of steps runs
-inside a single lax.scan, so host↔device traffic is one call per epoch.
+matmuls). Training is data-parallel SYNCHRONOUS SGD: each step gathers its
+minibatch's rows of the stored columns (codes and numerics) and computes the
+first hidden layer from them (data_info.first_layer), so no (rows, inputs)
+design exists (the autoencoder's reconstruction target alone expands its
+minibatch's own rows, and its predictions are a reconstruction of every
+row); the gradient all-reduce is inserted
+by the SPMD partitioner — equivalent to the reference's model averaging
+with averaging period = 1 batch, but deterministic. A whole epoch of steps
+is one while loop in one program, so host<->device traffic is one call per
+epoch. The programs are specialised on static shapes (`_Net`) and take the
+data's moments as arguments: a job on a new frame of the same shape
+compiles nothing.
+
+`epochs` is honoured as H2O does: round(epochs x rows / mini_batch_size)
+steps in all, the last epoch partial, `epochs_trained` a float.
 """
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Any, Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from h2o3_tpu.compat import pcast
 from h2o3_tpu.compat import shard_map as _compat_shard_map
 from h2o3_tpu.core.frame import Column, Frame, T_NUM
-from h2o3_tpu.models.data_info import DataInfo
+from h2o3_tpu.models.data_info import (DataInfo, DesignLayout, design_rows,
+                                       first_layer)
 from h2o3_tpu.models.model import Model, ModelCategory
 from h2o3_tpu.models.model_builder import ModelBuilder, register
+from h2o3_tpu.obs import metrics, tracing
 
 ACTIVATIONS = ("tanh", "tanhwithdropout", "rectifier", "rectifierwithdropout",
                "maxout", "maxoutwithdropout")
@@ -49,70 +63,347 @@ def _activation_fn(name: str):
     raise ValueError(f"unknown activation {name!r}")
 
 
-def _forward(params, X, activation, dropout_key=None, input_dropout=0.0,
-             hidden_dropout=None, train=False):
-    """MLP forward. params = [(W,b), ...]; returns last-layer linear output."""
+class _Net(NamedTuple):
+    """What a DeepLearning program is specialised on: shapes and the
+    hyper-parameters, never the data."""
+    layout: DesignLayout
+    activation: str
+    nclasses: int               # 1: regression or autoencoder
+    autoencoder: bool
+    loss: str = "crossentropy"
+    l1: float = 0.0
+    l2: float = 0.0
+    in_drop: float = 0.0
+    hid_drop: Tuple[float, ...] = ()
+    batch: int = 32
+    opt: Tuple = ("adadelta", 0.99, 1e-8)
+
+    @property
+    def n_cols(self) -> int:
+        return len(self.layout.cards) + self.layout.n_num
+
+
+def _dense(h, W, b):
+    """A dense layer at f32 (H2O's arithmetic), not a TPU's default bf16
+    operands."""
     import jax
     import jax.numpy as jnp
 
-    act = _activation_fn(activation)
+    return jnp.dot(h, W, precision=jax.lax.Precision.HIGHEST) + b
+
+
+def _design_block(layout: DesignLayout, moments, cols):
+    """The (rows, inputs) design of a block's own rows, as `expand` gives
+    it: the autoencoder's reconstruction target."""
+    import jax.numpy as jnp
+
+    O, D = design_rows(layout, moments, cols)
+    parts = [] if O is None else [O[layout.lane_coef()].astype(jnp.float32)]
+    return jnp.concatenate(parts + [D]).T
+
+
+def _forward(net: _Net, params, moments, cols, dropout_key=None,
+             train=False):
+    """MLP forward of a block of rows from their stored columns; returns
+    the last layer's linear output. params = [(W, b), ...]."""
+    import jax
+    import jax.numpy as jnp
+
+    act = _activation_fn(net.activation)
     use_dropout = train and dropout_key is not None
-    h = X
-    if use_dropout and input_dropout > 0:
+    W0, b0 = params[0]
+    if use_dropout and net.in_drop > 0:
+        # dropping an input column of a row: x . W is linear in x, so the
+        # kept inputs' 1/(1 - p) scale applies to the product, not the bias
         dropout_key, sub = jax.random.split(dropout_key)
-        keep = jax.random.bernoulli(sub, 1.0 - input_dropout, h.shape)
-        h = jnp.where(keep, h / (1.0 - input_dropout), 0.0)
-    n_hidden = len(params) - 1
-    for li, (W, b) in enumerate(params[:-1]):
-        h = act(h @ W + b)
-        if use_dropout and hidden_dropout is not None:
-            rate = hidden_dropout[li] if li < len(hidden_dropout) else 0.0
-            if rate > 0:
-                dropout_key, sub = jax.random.split(dropout_key)
-                keep = jax.random.bernoulli(sub, 1.0 - rate, h.shape)
-                h = jnp.where(keep, h / (1.0 - rate), 0.0)
-    W, b = params[-1]
-    return h @ W + b
+        keep = jax.random.bernoulli(sub, 1.0 - net.in_drop,
+                                    (net.n_cols, cols[0].shape[0]))
+        h = (first_layer(net.layout, moments, cols, W0, b0, keep=keep)
+             - b0) / (1.0 - net.in_drop) + b0
+    else:
+        h = first_layer(net.layout, moments, cols, W0, b0)
+    for li, (W, b) in enumerate(params[1:]):
+        h = act(h)
+        if use_dropout and li < len(net.hid_drop) and net.hid_drop[li] > 0:
+            rate = net.hid_drop[li]
+            dropout_key, sub = jax.random.split(dropout_key)
+            keep = jax.random.bernoulli(sub, 1.0 - rate, h.shape)
+            h = jnp.where(keep, h / (1.0 - rate), 0.0)
+        h = _dense(h, W, b)
+    return h
 
 
-# rows per block of a whole-frame pass: a block's (rows, hidden) activations
-# are tens of MB, where a whole 8M-row frame's are 6 GB a layer
-_ROW_BLOCK = 1 << 16
+def _row_loss(net: _Net, params, moments, cols, yb, key=None, train=False):
+    import jax
+    import jax.numpy as jnp
+
+    out = _forward(net, params, moments, cols, key, train)
+    if net.autoencoder:
+        return jnp.mean((out - _design_block(net.layout, moments, cols)) ** 2,
+                        axis=-1)
+    if net.nclasses == 2:
+        # the two-class cross-entropy on the margin m = o1 - o0,
+        # log(1 + e^m) - y m: a TPU's log_softmax reads p up to 3.5e-5 off
+        # a float64 forward pass where the margin form reads 9e-7, and the
+        # gradient carries that error into every step
+        m = out[:, 1] - out[:, 0]
+        return jnp.logaddexp(0.0, m) - yb.astype(jnp.float32) * m
+    if net.nclasses > 1:
+        logp = jax.nn.log_softmax(out, axis=-1)
+        return -jnp.take_along_axis(logp, yb[:, None], axis=-1)[:, 0]
+    f = out[:, 0]
+    if net.loss == "absolute":
+        return jnp.abs(yb - f)
+    if net.loss == "huber":
+        d = jnp.abs(yb - f)
+        return jnp.where(d <= 1.0, 0.5 * d * d, d - 0.5)
+    return 0.5 * (yb - f) ** 2
 
 
-def _blocked_rows(fn):
-    """jit of a row-local ``fn(consts, *blocks) -> per-row outputs`` run
-    over whole columns: under shard_map each device walks ITS row shard in
-    blocks of _ROW_BLOCK rows, so the (rows, hidden) activations of a whole
-    frame never exist at once (found on the chip: the eager full-frame
-    loss pass asked for 5.96 GB at 8M rows and exhausted HBM). `consts`
-    (weights) ride as replicated arguments, not closure constants, so one
-    compile serves every epoch. Call as ``run(consts, cols_tuple)``."""
+def _penalty(net: _Net, params):
+    import jax.numpy as jnp
+
+    reg = 0.0
+    if net.l1 > 0 or net.l2 > 0:
+        for W, _ in params:
+            reg = reg + net.l1 * jnp.sum(jnp.abs(W)) \
+                + net.l2 * 0.5 * jnp.sum(W * W)
+    return reg
+
+
+def _batch_loss(net: _Net, params, moments, cols, yb, wb, key):
+    import jax.numpy as jnp
+
+    per_row = _row_loss(net, params, moments, cols, yb, key, train=True)
+    return jnp.sum(per_row * wb) / jnp.maximum(jnp.sum(wb), 1.0) \
+        + _penalty(net, params)
+
+
+def _optimizer(net: _Net):
+    import optax
+
+    if net.opt[0] == "adadelta":
+        _, rho, eps = net.opt
+        return optax.adadelta(learning_rate=1.0, rho=rho, eps=eps)
+    _, rate, anneal, mom = net.opt
+    batch = net.batch
+
+    def lr_sched(step):
+        return rate / (1.0 + anneal * step * batch)
+
+    return (optax.sgd(learning_rate=lr_sched, momentum=mom)
+            if mom > 0 else optax.sgd(learning_rate=lr_sched))
+
+
+def _sgd_step(net: _Net, opt, carry, idx, moments, arrays, y, w, kdrop):
+    """One minibatch: the rows ``idx`` of the stored columns, their
+    gradient, the optimizer's update."""
+    import jax
+    import optax
+
+    params, opt_state = carry
+    grads = jax.grad(functools.partial(_batch_loss, net))(
+        params, moments, tuple(a[idx] for a in arrays), y[idx], w[idx],
+        kdrop)
+    updates, opt_state = opt.update(grads, opt_state, params)
+    return optax.apply_updates(params, updates), opt_state
+
+
+# steps a run of the training program takes at most: 76 ms of a v5e at the
+# airline network. The runs of an epoch are enqueued back to back (nothing
+# is read on the host between them), so the device does not wait on the
+# host; a profile of a fraction of a second then holds whole runs.
+DL_STEPS_A_DISPATCH = 2048
+
+
+@functools.partial(__import__("jax").jit, static_argnames=("net",))
+def _dl_train_steps(params, opt_state, key, steps, nrows, arrays, moments, y,
+                    w, *, net):
+    """``steps`` minibatch steps in one program (a while loop, so one
+    compile serves whole and partial epochs). A step's rows are
+    ``jax.random.randint(kidx, (batch,), 0, nrows)`` with ``key, kidx,
+    kdrop = jax.random.split(key, 3)``: only the frame's real rows are
+    drawn."""
+    import jax
+
+    opt = _optimizer(net)
+
+    def body(_i, carry):
+        params, opt_state, key = carry
+        key, kidx, kdrop = jax.random.split(key, 3)
+        idx = jax.random.randint(kidx, (net.batch,), 0, nrows)
+        params, opt_state = _sgd_step(net, opt, (params, opt_state), idx,
+                                      moments, arrays, y, w, kdrop)
+        return params, opt_state, key
+
+    return jax.lax.fori_loop(0, steps, body, (params, opt_state, key))
+
+
+@functools.lru_cache(maxsize=16)
+def _dl_averaging_program(net: _Net, mesh, avg_period: int):
+    """Per-device model averaging (DeepLearningTask.java:19,180 — local
+    replicas train independently, reduce = weighted average): each mesh
+    device runs `avg_period` minibatches on ITS row shard, then params (and
+    optimizer moments) pmean over the rows axis; ``rounds`` of that."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from h2o3_tpu.core.runtime import cluster
+    opt = _optimizer(net)
 
-    def local(consts, cols):
-        n = cols[0].shape[0]
-        blk = min(_ROW_BLOCK, n)
-        nfull = n // blk
-        out = jax.lax.map(
-            lambda block: fn(consts, *block),
-            tuple(c[: nfull * blk].reshape((nfull, blk) + c.shape[1:])
-                  for c in cols))
-        out = jax.tree.map(
-            lambda a: a.reshape((nfull * blk,) + a.shape[2:]), out)
-        if n % blk:
-            tail = fn(consts, *(c[nfull * blk:] for c in cols))
-            out = jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
-                               out, tail)
-        return out
+    def body(params, opt_state, sub, rounds, nrows, arrays, moments, y, w):
+        shard = arrays[0].shape[0]
+        d = jax.lax.axis_index("rows")
+        # the real rows of this shard: the frame's padding is never drawn
+        real = jnp.clip(nrows - d * shard, 0, shard)
+        key_l = jax.random.fold_in(sub, d)
+
+        def local(_i, carry):
+            params, opt_state, key_l = carry
+            key_l, kidx, kdrop = jax.random.split(key_l, 3)
+            idx = jax.random.randint(kidx, (net.batch,), 0,
+                                     jnp.maximum(real, 1))
+            wl = jnp.where(real > 0, w, 0.0)
+            params, opt_state = _sgd_step(net, opt, (params, opt_state), idx,
+                                          moments, arrays, y, wl, kdrop)
+            return params, opt_state, key_l
+
+        def sync_round(_r, carry):
+            carry = jax.lax.fori_loop(0, avg_period, local, carry)
+            params, opt_state, key_l = carry
+            # average weights AND float moments so the carried state is
+            # mesh-invariant (the reference averages the whole
+            # DeepLearningModelInfo, momenta included). Integer leaves
+            # (optax step counters) must keep their dtype — pmean would
+            # float-ify them and break the loop carry
+            params, opt_state = jax.tree.map(
+                lambda v: (jax.lax.pmean(v, "rows")
+                           if jnp.issubdtype(v.dtype, jnp.floating) else v),
+                (params, opt_state))
+            return params, opt_state, key_l
+
+        params, opt_state, _ = jax.lax.fori_loop(
+            0, rounds, sync_round, (params, opt_state, key_l))
+        return params, opt_state
 
     return jax.jit(_compat_shard_map(
-        local, mesh=cluster().mesh, in_specs=(P(), P("rows")),
-        out_specs=P("rows")))
+        body, mesh=mesh,
+        in_specs=(P(), P(), P(), P(), P(), P("rows"), P(), P("rows"),
+                  P("rows")),
+        out_specs=(P(), P())))
+
+
+# bytes a whole-frame pass's row block may hold (its one-hot, activations
+# and outputs): 8,192 rows of the airline network, where a whole 48M-row
+# frame's activations would be 38 GB a layer
+DL_BLOCK_BYTES = 1 << 26
+
+
+def dl_block_rows(n_shard: int, net: _Net, width: int) -> int:
+    """Rows of one block of a whole-frame pass, from shapes alone: the
+    largest power of two whose one-hot (3 B a lane: bool and bf16) and
+    f32 activations (8 B a unit: before and after the activation) stay
+    under DL_BLOCK_BYTES, at most the shard. ``width`` is the units of the
+    hidden and output layers, summed."""
+    row = 3 * sum(net.layout.padded) + 8 * (width + net.layout.n_num)
+    blk = 1 << max((DL_BLOCK_BYTES // max(row, 1)).bit_length() - 1, 8)
+    return int(min(blk, max(n_shard, 1)))
+
+
+@functools.lru_cache(maxsize=32)
+def _dl_pass(net: _Net, mesh, kind: str, width: int):
+    """A whole-frame pass over the row shards, a block of rows at a time
+    (dl_block_rows), as one program: under shard_map each device walks ITS
+    shard, so the (rows, hidden) activations of a whole frame never exist
+    at once (found on the chip: the eager full-frame loss pass asked for
+    5.96 GB at 8M rows and exhausted HBM). The weights and moments ride as
+    arguments, not closure constants, so one compile serves every epoch and
+    every frame of the shape. ``kind``:
+
+      "loss"      -> (sum of w x row loss, sum of w) over the frame
+      "predict"   -> per row: class probabilities, the regression value, or
+                     the autoencoder's (reconstruction, error)
+      "features<k>" -> per row: hidden layer k's activations
+
+    A block is a dynamic slice of the columns, so the pass holds one
+    block's temporaries and no copy of the frame.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    act = _activation_fn(net.activation)
+
+    def block_fn(params, moments, *block):
+        if kind == "loss":
+            *cols, yb, wb = block
+            lw = _row_loss(net, params, moments, tuple(cols), yb) * wb
+            return jnp.sum(lw), jnp.sum(wb)
+        if kind.startswith("features"):
+            layer = int(kind[len("features"):])
+            W0, b0 = params[0]
+            h = act(first_layer(net.layout, moments, block, W0, b0))
+            for W, b in params[1:layer + 1]:
+                h = act(_dense(h, W, b))
+            return h
+        out = _forward(net, params, moments, block)
+        if net.autoencoder:
+            X = _design_block(net.layout, moments, block)
+            return out, jnp.mean((out - X) ** 2, axis=-1)
+        if net.nclasses > 1:
+            return jax.nn.softmax(out, axis=-1)
+        return out[:, 0]
+
+    def local(params, moments, cols):
+        n = cols[0].shape[0]
+        blk = dl_block_rows(n, net, width)
+
+        def block(i):
+            """Rows [i*blk, (i+1)*blk) of the shard; the last block starts
+            early enough to be whole (it rewrites the rows it shares with
+            the one before it, and gives them no weight in a sum)."""
+            start = jnp.minimum(i * blk, n - blk)
+            return start, tuple(jax.lax.dynamic_slice_in_dim(c, start, blk)
+                                for c in cols)
+
+        if kind == "loss":
+            def body(i, acc):
+                start, b = block(i)
+                fresh = start + jnp.arange(blk) >= i * blk
+                lw, sw = block_fn(params, moments, *b[:-1],
+                                  jnp.where(fresh, b[-1], 0.0))
+                return acc[0] + lw, acc[1] + sw
+
+            zero = pcast(jnp.float32(0), ("rows",), to="varying")
+            tot = jax.lax.fori_loop(0, -(-n // blk), body, (zero, zero))
+            return tuple(jax.lax.psum(t, "rows") for t in tot)
+
+        def body(i, out):
+            start, b = block(i)
+            return jax.tree.map(
+                lambda o, r: jax.lax.dynamic_update_slice_in_dim(o, r, start,
+                                                                 0),
+                out, block_fn(params, moments, *b))
+
+        shapes = jax.eval_shape(lambda: block_fn(params, moments,
+                                                 *block(0)[1]))
+        out = jax.tree.map(lambda sd: pcast(
+            jnp.zeros((n,) + sd.shape[1:], sd.dtype), ("rows",),
+            to="varying"), shapes)
+        return jax.lax.fori_loop(0, -(-n // blk), body, out)
+
+    local.__name__ = f"_dl_{kind}_pass"
+    return jax.jit(_compat_shard_map(
+        local, mesh=mesh, in_specs=(P(), P(), P("rows")),
+        out_specs=P() if kind == "loss" else P("rows")))
+
+
+def _run_pass(net: _Net, kind: str, params, moments, cols):
+    from h2o3_tpu.core.runtime import cluster
+
+    width = sum(int(W.shape[1]) for W, _ in params)
+    return _dl_pass(net, cluster().mesh, kind, width)(params, moments,
+                                                      tuple(cols))
 
 
 class DeepLearningModel(Model):
@@ -125,31 +416,20 @@ class DeepLearningModel(Model):
         self.activation: str = "rectifier"
         self.nclasses: int = 1
         self.autoencoder: bool = False
-        self.epochs_trained: int = 0
+        self.epochs_trained: float = 0.0
+
+    def _net(self) -> _Net:
+        return _Net(self.data_info.layout(), self.activation, self.nclasses,
+                    self.autoencoder)
 
     def _predict_raw(self, frame: Frame):
-        import jax
-        import jax.numpy as jnp
-
         di = self.data_info
-        act = self.activation
-        autoencoder, nclasses = self.autoencoder, self.nclasses
-
-        def block(params, *arrs):
-            X = di.expand(*arrs)
-            out = _forward(params, X, act, train=False)
-            if autoencoder:
-                return out, jnp.mean((out - X) ** 2, axis=-1)
-            if nclasses > 1:
-                return jax.nn.softmax(out, axis=-1)
-            return out[:, 0]
-
-        res = _blocked_rows(block)(
-            self.params_tree, tuple(c.data for c in di.cols(frame)))
-        if autoencoder:
+        res = _run_pass(self._net(), "predict", self.params_tree,
+                        di.moments(), (c.data for c in di.cols(frame)))
+        if self.autoencoder:
             out, err = res
             return {"reconstruction": out, "score": err, "value": err}
-        if nclasses > 1:
+        if self.nclasses > 1:
             return {"probs": res}
         return {"value": res}
 
@@ -176,26 +456,20 @@ class DeepLearningModel(Model):
 
     def deepfeatures(self, frame: Frame, layer: int) -> Frame:
         """Hidden-layer activations (reference deepfeatures endpoint)."""
-        import jax
-        import jax.numpy as jnp
-
         di = self.data_info
-        arrays = tuple(c.data for c in di.cols(self.adapt_test(frame)))
-        params = self.params_tree
-        act_fn = _activation_fn(self.activation)
-
-        @jax.jit
-        def fwd(*arrs):
-            h = di.expand(*arrs)
-            for W, b in params[:layer + 1]:
-                h = act_fn(h @ W + b)
-            return h
-
-        H = fwd(*arrays)
+        H = _run_pass(self._net(), f"features{int(layer)}", self.params_tree,
+                      di.moments(),
+                      (c.data for c in di.cols(self.adapt_test(frame))))
         out = Frame()
         for j in range(H.shape[1]):
             out.add(f"DF.L{layer+1}.C{j+1}", Column(H[:, j], T_NUM, frame.nrows))
         return out
+
+
+def _steps_of(epochs: float, nrows: int, batch: int) -> int:
+    """Minibatch steps of ``epochs`` over ``nrows`` rows: H2O trains
+    epochs x rows samples."""
+    return int(round(float(epochs) * nrows / batch))
 
 
 @register
@@ -246,7 +520,6 @@ class DeepLearning(ModelBuilder):
     def _fit(self, train: Frame) -> DeepLearningModel:
         import jax
         import jax.numpy as jnp
-        import optax
 
         p = self.params
         autoencoder = bool(p.get("autoencoder"))
@@ -274,13 +547,20 @@ class DeepLearning(ModelBuilder):
                     f"the original run ({prev._output.names} vs {names})")
             di = prev.data_info
         else:
-            di = DataInfo(train, response=resp,
-                          ignored=p.get("ignored_columns") or (),
-                          weights=p.get("weights_column"),
-                          standardize=bool(p.get("standardize", True)),
-                          use_all_factor_levels=bool(p.get("use_all_factor_levels", True)))
+            # stage span ``design``: DataInfo reads the rollups and modes of
+            # the predictors on the host (cached on the columns after a
+            # frame's first job), which is where it has always blocked
+            with tracing.span("design", rows=train.nrows):
+                di = DataInfo(train, response=resp,
+                              ignored=p.get("ignored_columns") or (),
+                              weights=p.get("weights_column"),
+                              standardize=bool(p.get("standardize", True)),
+                              use_all_factor_levels=bool(
+                                  p.get("use_all_factor_levels", True)))
         n = train.nrows
         arrays = tuple(c.data for c in di.cols(train))
+        moments = di.moments()
+        padded = int(arrays[0].shape[0]) if arrays else n
         activation = (p.get("activation") or "Rectifier").lower()
         if activation not in ACTIVATIONS:
             raise ValueError(f"unknown activation {p['activation']!r}")
@@ -297,8 +577,6 @@ class DeepLearning(ModelBuilder):
             y_dev = y_col.data
         w_dev = train.col(p["weights_column"]).data if p.get("weights_column") else None
 
-        X = jax.jit(di.expand)(*arrays)
-        padded = X.shape[0]
         row_w = (jnp.arange(padded) < n).astype(jnp.float32)
         if not autoencoder:
             yw = DataInfo.response_weight(y_dev, w_dev)
@@ -325,175 +603,77 @@ class DeepLearning(ModelBuilder):
             loss_name = "crossentropy" if nclasses > 1 else "quadratic"
         if nclasses > 1 and loss_name != "crossentropy":
             loss_name = "crossentropy"
-        l1 = float(p.get("l1", 0.0))
-        l2 = float(p.get("l2", 0.0))
-        in_drop = float(p.get("input_dropout_ratio", 0.0))
         hid_drop = p.get("hidden_dropout_ratios")
         if hid_drop is None and "withdropout" in activation:
             hid_drop = [0.5] * len(hidden)
-        hid_drop = tuple(float(h) for h in (hid_drop or []))
-
         batch = max(int(p.get("mini_batch_size", 32)), 1)
+        if p.get("adaptive_rate", True):
+            opt_spec = ("adadelta", float(p.get("rho", 0.99)),
+                        float(p.get("epsilon", 1e-8)))
+        else:
+            opt_spec = ("sgd", float(p.get("rate", 0.005)),
+                        float(p.get("rate_annealing", 1e-6)),
+                        max(float(p.get("momentum_start", 0.0)),
+                            float(p.get("momentum_stable", 0.0))))
+        net = _Net(di.layout(), activation, nclasses, autoencoder,
+                   loss=loss_name, l1=float(p.get("l1", 0.0)),
+                   l2=float(p.get("l2", 0.0)),
+                   in_drop=float(p.get("input_dropout_ratio", 0.0)),
+                   hid_drop=tuple(float(h) for h in (hid_drop or [])),
+                   batch=batch, opt=opt_spec)
+
         epochs = float(p.get("epochs", 10.0))
-        steps_per_epoch = max(int(math.ceil(n / batch)), 1)
-        n_epochs = max(int(math.ceil(epochs)), 1)
-        ep_start = 0
+        total = max(_steps_of(epochs, n, batch), 1)
+        per_epoch = max(-(-n // batch), 1)
+        done = 0
         if prev is not None:
             # epochs is the TOTAL target and must exceed the checkpoint's
-            ep_start = int(getattr(prev, "epochs_trained", 0) or 0)
-            if n_epochs <= ep_start:
+            done = _steps_of(getattr(prev, "epochs_trained", 0) or 0, n, batch)
+            if total <= done:
                 raise ValueError(
-                    f"checkpoint model already trained {ep_start} epochs; "
-                    f"epochs ({n_epochs}) must be greater")
-
-        if p.get("adaptive_rate", True):
-            opt = optax.adadelta(learning_rate=1.0, rho=float(p.get("rho", 0.99)),
-                                 eps=float(p.get("epsilon", 1e-8)))
-        else:
-            rate = float(p.get("rate", 0.005))
-            anneal = float(p.get("rate_annealing", 1e-6))
-            m_start = float(p.get("momentum_start", 0.0))
-            m_stable = float(p.get("momentum_stable", 0.0))
-            ramp = max(float(p.get("momentum_ramp", 1e6)), 1.0)
-
-            def lr_sched(step):
-                return rate / (1.0 + anneal * step * batch)
-
-            mom = max(m_start, m_stable)
-            opt = (optax.sgd(learning_rate=lr_sched, momentum=mom)
-                   if mom > 0 else optax.sgd(learning_rate=lr_sched))
-
-        def row_loss(params, xb, yb, key):
-            out = _forward(params, xb, activation, dropout_key=key,
-                           input_dropout=in_drop, hidden_dropout=hid_drop,
-                           train=True)
-            if autoencoder:
-                return jnp.mean((out - xb) ** 2, axis=-1)
-            if nclasses > 1:
-                logp = jax.nn.log_softmax(out, axis=-1)
-                return -jnp.take_along_axis(logp, yb[:, None], axis=-1)[:, 0]
-            f = out[:, 0]
-            if loss_name == "absolute":
-                return jnp.abs(yb - f)
-            if loss_name == "huber":
-                d = jnp.abs(yb - f)
-                return jnp.where(d <= 1.0, 0.5 * d * d, d - 0.5)
-            return 0.5 * (yb - f) ** 2
-
-        def penalty(params):
-            reg = 0.0
-            if l1 > 0 or l2 > 0:
-                for W, _ in params:
-                    reg = reg + l1 * jnp.sum(jnp.abs(W)) + l2 * 0.5 * jnp.sum(W * W)
-            return reg
-
-        def loss_fn(params, xb, yb, wb, key):
-            per_row = row_loss(params, xb, yb, key)
-            data_loss = jnp.sum(per_row * wb) / jnp.maximum(jnp.sum(wb), 1.0)
-            return data_loss + penalty(params)
-
-        grad_fn = jax.grad(loss_fn)
-        # the per-epoch training loss over ALL rows, walked in row blocks
-        # and expanded from the COLUMNS block by block: slicing the (rows,
-        # fullN) matrix X into blocks instead cost XLA:TPU 239 s of compile
-        # at 8M x 28 (measured on a v5e, PR 21) against 7 s for this form
-        def weighted_block_loss(params, *block):
-            *feats, yb, wb = block
-            return row_loss(params, di.expand(*feats), yb, None) * wb
-
-        weighted_row_loss = _blocked_rows(weighted_block_loss)
+                    f"checkpoint model already trained {prev.epochs_trained} "
+                    f"epochs; epochs ({epochs}) must be greater")
 
         def full_loss(params):
-            lw = weighted_row_loss(params, arrays + (y, row_w))
-            return float(jnp.sum(lw) / jnp.maximum(jnp.sum(row_w), 1.0)
-                         + penalty(params))
+            lw, sw = _run_pass(net, "loss", params, moments,
+                               arrays + (y, row_w))
+            return float(lw / jnp.maximum(sw, 1.0) + _penalty(net, params))
 
-        @jax.jit
-        def _epoch_impl(params, opt_state, key, Xa, ya, wa):
-            # data arrives as ARGUMENTS, not closed-over globals: on a
-            # multi-process cloud closing over an array that spans
-            # non-addressable devices is an error (jax multi-controller)
-            def step(carry, _):
-                params, opt_state, key = carry
-                key, kidx, kdrop = jax.random.split(key, 3)
-                idx = jax.random.randint(kidx, (batch,), 0, padded)
-                xb, yb, wb = Xa[idx], ya[idx], wa[idx]
-                grads = grad_fn(params, xb, yb, wb, kdrop)
-                updates, opt_state = opt.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
-                return (params, opt_state, key), None
-
-            (params, opt_state, key), _ = jax.lax.scan(
-                step, (params, opt_state, key), None, length=steps_per_epoch)
-            return params, opt_state, key
-
-        def run_epoch(params, opt_state, key):
-            return _epoch_impl(params, opt_state, key, X, y, row_w)
-
-        # per-device model averaging (DeepLearningTask.java:19,180 — local
-        # replicas train independently, reduce = weighted average): each
-        # mesh device runs `avg_period` minibatches on ITS row shard, then
-        # params (and optimizer moments) pmean over the rows axis
+        # per-device model averaging: each mesh device runs `avg_period`
+        # minibatches on ITS row shard between averages
         tspi = int(p.get("train_samples_per_iteration", 0) or 0)
         from h2o3_tpu.core.runtime import cluster as _cluster
 
-        n_dev = int(_cluster().mesh.shape["rows"])
+        mesh = _cluster().mesh
+        n_dev = int(mesh.shape["rows"])
         avg_period = max(1, tspi // max(batch * n_dev, 1)) if tspi > 0 else 1
-        if avg_period > 1 and n_dev > 1:
-            from jax.sharding import PartitionSpec as P
+        averaging = avg_period > 1 and n_dev > 1
 
-            shard_rows = padded // n_dev
-            n_rounds = max(int(math.ceil(steps_per_epoch / avg_period)), 1)
+        dispatches = 0
 
-            def epoch_avg_body(params, opt_state, sub, Xs, ys, ws):
-                key_l = jax.random.fold_in(sub, jax.lax.axis_index("rows"))
-
-                def local(carry, _):
-                    params, opt_state, key_l = carry
-                    key_l, kidx, kdrop = jax.random.split(key_l, 3)
-                    idx = jax.random.randint(kidx, (batch,), 0, shard_rows)
-                    grads = grad_fn(params, Xs[idx], ys[idx], ws[idx], kdrop)
-                    updates, opt_state = opt.update(grads, opt_state, params)
-                    params = optax.apply_updates(params, updates)
-                    return (params, opt_state, key_l), None
-
-                def sync_round(carry, _):
-                    (params, opt_state, key_l), _ = jax.lax.scan(
-                        local, carry, None, length=avg_period)
-                    # average weights AND float moments so the carried state
-                    # is mesh-invariant (the reference averages the whole
-                    # DeepLearningModelInfo, momenta included). Integer
-                    # leaves (optax step counters) must keep their dtype —
-                    # pmean would float-ify them and break the scan carry
-                    params, opt_state = jax.tree.map(
-                        lambda v: (jax.lax.pmean(v, "rows")
-                                   if jnp.issubdtype(v.dtype, jnp.floating)
-                                   else v),
-                        (params, opt_state))
-                    return (params, opt_state, key_l), None
-
-                (params, opt_state, _), _ = jax.lax.scan(
-                    sync_round, (params, opt_state, key_l), None,
-                    length=n_rounds)
-                return params, opt_state
-
-            epoch_avg = jax.jit(_compat_shard_map(
-                epoch_avg_body, mesh=_cluster().mesh,
-                in_specs=(P(), P(), P(), P("rows", None), P("rows"), P("rows")),
-                out_specs=(P(), P())))
-
-            def run_epoch(params, opt_state, key):  # noqa: F811 — override
+        def run_steps(params, opt_state, key, k):
+            nonlocal dispatches
+            if averaging:
                 key, sub = jax.random.split(key)
-                params, opt_state = epoch_avg(params, opt_state, sub,
-                                              X, y, row_w)
+                params, opt_state = _dl_averaging_program(
+                    net, mesh, avg_period)(params, opt_state, sub,
+                                           -(-k // avg_period), n, arrays,
+                                           moments, y, row_w)
+                dispatches += 1
                 return params, opt_state, key
+            for lo in range(0, k, DL_STEPS_A_DISPATCH):
+                params, opt_state, key = _dl_train_steps(
+                    params, opt_state, key, min(DL_STEPS_A_DISPATCH, k - lo),
+                    n, arrays, moments, y, row_w, net=net)
+                dispatches += 1
+            return params, opt_state, key
 
-        opt_state = opt.init(params0)
+        opt_state = _optimizer(net).init(params0)
         key = jax.random.PRNGKey(seed)
-        if ep_start:
-            # resumed runs must not replay the original epochs' batch/dropout
+        if done:
+            # resumed runs must not replay the original steps' batch/dropout
             # draws (same reseeding rule as the tree path's host RNG)
-            key = jax.random.fold_in(key, ep_start)
+            key = jax.random.fold_in(key, done)
         params_t = params0
 
         model = DeepLearningModel(parms=dict(p))
@@ -506,17 +686,19 @@ class DeepLearning(ModelBuilder):
         model.nclasses = nclasses
         model.autoencoder = autoencoder
 
+        def epochs_at(steps: int) -> float:
+            return epochs if steps == total else steps * batch / n
+
         stop_rounds = int(p.get("stopping_rounds", 0) or 0)
         tol = float(p.get("stopping_tolerance", 1e-3))
         history: List[float] = []
-        ep_done = ep_start
+        ep = 0                      # epochs (whole or partial) run here
         rs = self._take_resume_state("dl_epochs")
         if rs is not None:
             # durable-progress fast-forward: weights, optimizer moments and
-            # the LIVE RNG key (all epoch splits already consumed), so the
+            # the LIVE RNG key (all splits already consumed), so the
             # continued run walks the identical batch/dropout draws
-            ep_start = int(rs["epoch"])
-            ep_done = ep_start
+            ep, done = int(rs["epoch"]), int(rs["steps"])
             params_t = jax.tree.map(jnp.asarray, rs["params"])
             opt_state = jax.tree.map(jnp.asarray, rs["opt_state"])
             key = jnp.asarray(rs["key"])
@@ -524,34 +706,53 @@ class DeepLearning(ModelBuilder):
             model._output.scoring_history = [dict(h)
                                              for h in rs["scoring_history"]]
         jp_every = self._job_ckpt_every()
-        for ep in range(ep_start, n_epochs):
-            params_t, opt_state, key = run_epoch(params_t, opt_state, key)
-            ep_done = ep + 1
-            tr_loss = full_loss(params_t)
-            model._output.scoring_history.append(
-                {"epoch": ep + 1, "training_loss": tr_loss})
-            history.append(tr_loss)
-            if self.job:
-                self.job.update(progress=(ep + 1) / n_epochs,
-                                msg=f"epoch {ep+1}/{n_epochs} loss={tr_loss:.5f}")
-            if jp_every and (ep + 1) % jp_every == 0:
-                self._tick_job_progress(ep + 1, lambda: {
-                    "phase": "dl_epochs", "epoch": ep_done,
+        n_epochs = ep + -(-(total - done) // per_epoch)
+        first = done
+
+        def progress():
+            """The durable progress of the epochs run so far (read only
+            when a save is due: it fetches the weights to the host)."""
+            return {"phase": "dl_epochs", "epoch": ep, "steps": done,
                     "params": jax.tree.map(np.asarray, params_t),
                     "opt_state": jax.tree.map(np.asarray, opt_state),
-                    "key": np.asarray(key),
-                    "history": list(history),
+                    "key": np.asarray(key), "history": list(history),
                     "scoring_history":
-                        [dict(h) for h in model._output.scoring_history]})
-            if stop_rounds > 0 and len(history) > stop_rounds:
-                best_recent = min(history[-stop_rounds:])
-                best_before = min(history[:-stop_rounds])
-                if best_recent > best_before * (1.0 - tol):
-                    break
-            if self._out_of_time():
-                break
+                        [dict(h) for h in model._output.scoring_history]}
 
-        model.epochs_trained = ep_done
+        # stage span ``epochs``: from the first dispatch of the training
+        # program to the last epoch's loss read, where the host has always
+        # blocked
+        with tracing.span("epochs", batch=batch) as sp:
+            while done < total:
+                k = min(per_epoch, total - done)
+                params_t, opt_state, key = run_steps(params_t, opt_state,
+                                                     key, k)
+                done += k
+                ep += 1
+                tr_loss = full_loss(params_t)
+                model._output.scoring_history.append(
+                    {"epoch": epochs_at(done), "training_loss": tr_loss})
+                history.append(tr_loss)
+                if self.job:
+                    self.job.update(progress=done / total,
+                                    msg=f"epoch {ep}/{n_epochs} "
+                                        f"loss={tr_loss:.5f}")
+                if jp_every and ep % jp_every == 0:
+                    self._tick_job_progress(ep, progress)
+                if stop_rounds > 0 and len(history) > stop_rounds:
+                    best_recent = min(history[-stop_rounds:])
+                    best_before = min(history[:-stop_rounds])
+                    if best_recent > best_before * (1.0 - tol):
+                        break
+                if self._out_of_time():
+                    break
+            sp.set(steps=done - first, samples=(done - first) * batch,
+                   epochs=epochs_at(done), dispatches=dispatches)
+        metrics.inc("h2o3_dl_steps_total", done - first)
+        metrics.inc("h2o3_dl_samples_total", (done - first) * batch)
+        metrics.inc("h2o3_dl_dispatches_total", dispatches)
+
+        model.epochs_trained = epochs_at(done)
         model.params_tree = jax.tree.map(np.asarray, params_t)
         model.params_tree = [(jnp.asarray(W), jnp.asarray(b))
                              for W, b in model.params_tree]

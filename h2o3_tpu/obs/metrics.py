@@ -496,6 +496,14 @@ def _install_default_metrics() -> None:
               "passes over the rows that built a Gram (one an IRLS "
               "iteration), by form: onehot3 | dense = gram_form's rule "
               "from the design's shape")
+    r.counter("h2o3_dl_steps_total",
+              "minibatch steps of the DeepLearning jobs that ended, counted "
+              "on the host after the job's last epoch")
+    r.counter("h2o3_dl_samples_total",
+              "rows those steps drew (steps x mini_batch_size)")
+    r.counter("h2o3_dl_dispatches_total",
+              "runs of the DeepLearning training program those steps took "
+              "(an epoch's steps in runs of at most DL_STEPS_A_DISPATCH)")
     r.counter("h2o3_forest_walk_total",
               "dispatches of a forest-walk program (predict_binned, "
               "leaf_index, the scoring session's fused programs), by form: "

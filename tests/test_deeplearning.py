@@ -113,3 +113,56 @@ def test_autoencoder_metrics_and_versioned_save(cl, tmp_path):
 
     with pytest.raises(ValueError, match="not an h2o3_tpu model"):
         Model.load(bad)
+
+
+def test_dl_epochs_count_samples_not_whole_passes(cl):
+    """H2O trains epochs x rows samples: round(epochs x rows / batch)
+    steps, the last epoch partial, epochs_trained a float."""
+    from h2o3_tpu.models.deeplearning import DeepLearning
+    from h2o3_tpu.obs import metrics
+
+    def steps_total():
+        return sum(s["value"] for s in metrics.REGISTRY.get(
+            "h2o3_dl_steps_total").snapshot()["samples"])
+
+    fr = _xor_data(n=1000, seed=9)
+    before = steps_total()
+    m = DeepLearning(hidden=[8], epochs=2.5, seed=4,
+                     mini_batch_size=32).train(y="y", training_frame=fr)
+    assert steps_total() - before == round(2.5 * 1000 / 32)
+    assert [h["epoch"] for h in m._output.scoring_history] == \
+        [32 * 32 / 1000, 64 * 32 / 1000, 2.5]
+    assert m.epochs_trained == 2.5
+    short = DeepLearning(hidden=[8], epochs=0.3, seed=4,
+                         mini_batch_size=32).train(y="y", training_frame=fr)
+    assert [h["epoch"] for h in short._output.scoring_history] == [0.3]
+    assert short.epochs_trained == 0.3
+    m.delete()
+    short.delete()
+
+
+def test_dl_epoch_in_runs_of_the_training_program_is_the_same_bits(
+        cl, monkeypatch):
+    """An epoch's steps go to the device in runs of at most
+    DL_STEPS_A_DISPATCH; the runs carry the state and the key, so the
+    weights are the same to the bit however the steps are cut."""
+    from h2o3_tpu.models import deeplearning as dl_mod
+    from h2o3_tpu.models.deeplearning import DeepLearning
+
+    fr = _xor_data(n=1000, seed=11)
+
+    def fit():
+        return DeepLearning(hidden=[8], epochs=1.5, seed=2,
+                            mini_batch_size=32).train(y="y",
+                                                      training_frame=fr)
+
+    whole = fit()
+    monkeypatch.setattr(dl_mod, "DL_STEPS_A_DISPATCH", 5)
+    cut = fit()
+    try:
+        for (a, b), (c, d) in zip(whole.params_tree, cut.params_tree):
+            assert np.array_equal(np.asarray(a), np.asarray(c))
+            assert np.array_equal(np.asarray(b), np.asarray(d))
+    finally:
+        whole.delete()
+        cut.delete()

@@ -429,3 +429,65 @@ def test_device_programs_carry_scopes_and_keep_their_names(cl):
         *(forest[k] for k in compressed.WALK_ARGS)).as_text(debug_info=True)
     assert "module @jit_run" in text
     assert "bin" in text and "walk" in text
+
+
+def test_dl_job_spans_and_counters_add_no_dispatch_or_compile(cl, monkeypatch):
+    """A DeepLearning fit: `design`, `epochs` and `metrics` end where the
+    host already blocks, so a fit under an active trace dispatches the same
+    programs as often, compiles what a plain one does and gives the same
+    bits; the step and sample counters count on the host."""
+    from h2o3_tpu.models import deeplearning as dl_mod
+    from h2o3_tpu.models.deeplearning import DeepLearning
+
+    calls = {"train": 0, "pass": 0}
+
+    def counted(name, fn):
+        def call(*a, **kw):
+            calls[name] += 1
+            return fn(*a, **kw)
+        return call
+
+    monkeypatch.setattr(dl_mod, "_dl_train_steps",
+                        counted("train", dl_mod._dl_train_steps))
+    monkeypatch.setattr(dl_mod, "_run_pass",
+                        counted("pass", dl_mod._run_pass))
+    fr = _frame(seed=11)
+
+    def fit():
+        c0, d0 = _counter("h2o3_backend_compiles_total"), dict(calls)
+        m = DeepLearning(hidden=[8], epochs=2.5, seed=3,
+                         mini_batch_size=32).train(y="y", training_frame=fr)
+        return m, _counter("h2o3_backend_compiles_total") - c0, \
+            {k: calls[k] - d0[k] for k in calls}
+
+    warm, _, _ = fit()
+    steps0 = _counter("h2o3_dl_steps_total")
+    samples0 = _counter("h2o3_dl_samples_total")
+    dispatches0 = _counter("h2o3_dl_dispatches_total")
+    plain, plain_compiles, plain_calls = fit()
+    with tracing.root_span("ingress",
+                           path="/3/ModelBuilders/deeplearning") as root:
+        traced, traced_compiles, traced_calls = fit()
+    try:
+        steps = round(2.5 * 1200 / 32)
+        # three epochs' steps, their three losses and the metrics' scoring
+        assert plain_calls == traced_calls == {"train": 3, "pass": 4}
+        assert traced_compiles == plain_compiles
+        for (a, b), (c, d) in zip(plain.params_tree, traced.params_tree):
+            assert np.array_equal(np.asarray(a), np.asarray(c))
+            assert np.array_equal(np.asarray(b), np.asarray(d))
+        spans = {s["name"]: s for s in tracing.get_trace(
+            root.span["trace_id"], include_remote=False)}
+        assert {"design", "epochs", "metrics"} <= set(spans)
+        assert spans["epochs"]["attrs"] == {
+            "batch": 32, "steps": steps, "samples": steps * 32,
+            "epochs": 2.5, "dispatches": 3}
+        assert spans["design"]["end_ms"] <= spans["epochs"]["start_ms"] \
+            <= spans["epochs"]["end_ms"] <= spans["metrics"]["start_ms"]
+        assert _counter("h2o3_dl_steps_total") - steps0 == 2 * steps
+        assert _counter("h2o3_dl_samples_total") - samples0 == 64 * steps
+        assert _counter("h2o3_dl_dispatches_total") - dispatches0 == 6
+        assert traced.epochs_trained == 2.5
+    finally:
+        for m in (warm, plain, traced):
+            m.delete()
