@@ -26,17 +26,67 @@ NBINS = 400  # hex/AUC2.java:36 (MAX_AUC_BINS)
 # jitted accumulation kernels (compiled once per shape)
 # ---------------------------------------------------------------------------
 
-@functools.partial(__import__("jax").jit, static_argnames=("nbins",))
+@functools.lru_cache(maxsize=16)
+def _hist_matmul(nbins: int, mesh, axis):
+    """One ops.segsum.segment_sum_mxu of w·y and w·(1−y) over the bins; a
+    shard_map over `axis` of `mesh` where the rows are sharded (the
+    (nbins, 2) sums psum'd once), plain jit on one device. Keyed by what
+    repeats within a deployment; the rows per shard key the jit."""
+    from jax.sharding import PartitionSpec as P
+
+    from h2o3_tpu.compat import shard_map
+    from h2o3_tpu.obs import compiles
+    from h2o3_tpu.ops.segsum import segment_sum_mxu
+
+    def binomial_hist(y, p, w):
+        import jax.numpy as jnp
+
+        def bin_of(sl):
+            return jnp.clip((sl(p) * nbins).astype(jnp.int32), 0, nbins - 1)
+
+        def cols_of(sl):
+            # zero where w == 0: a NaN y on a pad or NA row adds to no bin
+            wb, yb = sl(w), sl(y)
+            live = wb != 0
+            return (jnp.where(live, wb * yb, 0.0).astype(jnp.float32),
+                    jnp.where(live, wb * (1.0 - yb), 0.0).astype(jnp.float32))
+
+        out = segment_sum_mxu(bin_of, cols_of, n=y.shape[0], k=2,
+                              nslots=nbins, axis=axis)
+        return out[:, 0], out[:, 1]
+
+    fn = binomial_hist if mesh is None else shard_map(
+        binomial_hist, mesh=mesh, in_specs=(P(axis),) * 3, out_specs=(P(), P()))
+    return compiles.ledgered_jit("metrics", fn, program="binomial_hist")
+
+
+def _row_mesh(x):
+    """(mesh, axis) of x's row sharding where it spans several devices,
+    else (None, None): read from the input, so a frame on one device and
+    one over four each get their own program."""
+    sh = getattr(x, "sharding", None)
+    spec = getattr(sh, "spec", None)
+    if not spec or spec[0] is None:
+        return None, None
+    axis = spec[0]
+    shards = int(np.prod([sh.mesh.shape[a] for a in
+                          (axis if isinstance(axis, tuple) else (axis,))]))
+    if shards <= 1:
+        return None, None
+    return sh.mesh, axis
+
+
 def _binomial_hist(y, p, w, nbins: int = NBINS):
-    """Per-bin (tp-candidate, fp-candidate) counts: histogram of predicted
-    P(class1) split by truth. Replaces AUC2's sorted-threshold builder."""
+    """Per-bin (tp-candidate, fp-candidate) f32 sums: histogram of predicted
+    P(class1) split by truth, weighted. Replaces AUC2's sorted-threshold
+    builder; on the MXU (_hist_matmul), not as scatter-adds, which a TPU
+    serializes on the rows."""
     import jax.numpy as jnp
 
-    b = jnp.clip((p * nbins).astype(jnp.int32), 0, nbins - 1)
-    pos = jnp.zeros(nbins, jnp.float64 if y.dtype == jnp.float64 else jnp.float32)
-    pos = pos.at[b].add(w * y)
-    neg = jnp.zeros_like(pos).at[b].add(w * (1.0 - y))
-    return pos, neg
+    if y.shape[0] == 0:
+        z = jnp.zeros(nbins, jnp.float32)
+        return z, z
+    return _hist_matmul(nbins, *_row_mesh(y))(y, p, w)
 
 
 def _jit(fn):

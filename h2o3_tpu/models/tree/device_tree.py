@@ -85,6 +85,7 @@ from __future__ import annotations
 
 from h2o3_tpu.compat import pcast as _compat_pcast
 from h2o3_tpu.compat import shard_map as _compat_shard_map
+from h2o3_tpu.ops import segsum
 import functools
 from typing import List, Optional, Tuple
 
@@ -415,33 +416,22 @@ def hist_lowering(S: int):
 # ---------------------------------------------------------------------------
 
 _LEAF_COLS = 4              # leaf_sums' columns: w, w·y, num, den
-_LEAF_PIECES = 3            # bf16 pieces an f32 value splits into, exactly
-_LANES = 128                # lanes of a TPU tile
 
 
 def leaf_split(L: int) -> Tuple[int, int]:
     """(H, lo): how leaf_sums lays L slots out, slot = hi·lo + low with
-    hi < H — the one rule of the leaf pass, from its static width. Up to a
-    tile's 128 lanes the slots are one one-hot (H = 1, lo = L). Past that
-    the one-hot carries the low lo of a slot and the values are masked by
-    its high part: 12·H + lo lanes a row where a flat one-hot would be L,
-    least near lo = sqrt(12·L); lo is the power of two at or above it (the
-    sweep on a v5e, 16M rows, ms a tree at lo 128 / 256 / 512 / 1,024:
-    2,048 slots 11.4 / 8.9 / 16.7 / 21.7, flat 33.1; 8,192 slots 29.5 /
-    25.5 / 24.9 / 23.9; 16,384 slots 55.4 / 45.7 / 42.0 / 42.7; 40,960
-    slots 133 / 113 / 105 / 102)."""
-    if L <= _LANES:
-        return 1, L
-    lo = _LANES
-    while lo * lo < _LEAF_COLS * _LEAF_PIECES * L:
-        lo *= 2
-    return -(-L // lo), lo
+    hi < H — ops.segsum.onehot_split's rule for its four columns, from the
+    pass's static width: one one-hot up to 128 slots, 12·H + lo lanes a row
+    past that (the sweep on a v5e, 16M rows, ms a tree at lo 128 / 256 /
+    512 / 1,024: 2,048 slots 11.4 / 8.9 / 16.7 / 21.7, flat 33.1; 8,192
+    slots 29.5 / 25.5 / 24.9 / 23.9; 16,384 slots 55.4 / 45.7 / 42.0 /
+    42.7; 40,960 slots 133 / 113 / 105 / 102)."""
+    return segsum.onehot_split(L, _LEAF_COLS)
 
 
 def leaf_lanes(L: int) -> int:
     """Lanes a row takes in leaf_sums' two operands (_pick_blk's input)."""
-    H, lo = leaf_split(L)
-    return H * _LEAF_COLS * _LEAF_PIECES + lo
+    return segsum.onehot_lanes(L, _LEAF_COLS)
 
 
 def leaf_sums(row_leaf, w, y, num, den, tot_slots: int, blk: int):
@@ -452,60 +442,24 @@ def leaf_sums(row_leaf, w, y, num, den, tot_slots: int, blk: int):
     off-range slot tot_slots and dropped. The four (n,) vectors are never
     stacked into an (n, 4) array, whose minor axis a TPU pads to 128 lanes.
 
-    A block of blk rows is one dot contracting the row axis, rows on
-    lanes in both operands: the values (12, blk) are the four columns each
-    as its three bf16 pieces (ops.elementwise.bf16_pieces: their sum is the
-    f32 value), the leaf one-hot (L, blk) is one compare against a static
-    slot a lane and exact in bf16, so every product is exact and only the
-    f32 accumulation rounds: f32 sums, in blocks, not a lower precision.
-    Past 128 slots (leaf_split) the one-hot carries the slot's low part and
-    the values are laid out (12, H) and masked by its high part:
-    (12·H, blk) · (lo, blk)ᵀ. n need not be a multiple of blk: the last
-    block starts early and the rows it shares with the one before count
-    once."""
-    import jax
+    ops.segsum.segment_sum_mxu over L = tot_slots + 1 slots in blocks of
+    blk rows: each block one dot of the leaf one-hot against the four
+    columns' exact bf16 pieces, so every product is exact and only the f32
+    accumulation rounds: f32 sums, in blocks, not a lower precision. Past
+    128 slots the layout is leaf_split's, (12·H, blk) · (lo, blk)ᵀ."""
     import jax.numpy as jnp
 
-    from h2o3_tpu.ops.elementwise import bf16_pieces
-
-    L = tot_slots + 1
-    H, lo = leaf_split(L)
-    C = _LEAF_COLS * _LEAF_PIECES
-    n = row_leaf.shape[0]
-    blk = min(blk, n)
-    lane_lo = np.arange(lo, dtype=np.int32)[:, None]
-    lane_hi = np.tile(np.arange(H, dtype=np.int32), C)[:, None]
-    at = jnp.arange(blk, dtype=jnp.int32)
-
-    def body(i, acc):
-        start = jnp.minimum(i * blk, n - blk)
-        sl = lambda a: jax.lax.dynamic_slice_in_dim(a, start, blk)
+    def slot_of(sl):
         slot = sl(row_leaf)
-        slot = jnp.where(slot >= 0, jnp.minimum(slot, tot_slots), tot_slots)
-        slot = jnp.where(start + at >= i * blk, slot, -1)   # counted before
-        wb = sl(w)
-        V = jnp.stack([p for ps in zip(*(bf16_pieces(c) for c in
-                                         (wb, wb * sl(y), sl(num), sl(den))))
-                       for p in ps])                         # (12, blk)
-        if H == 1:
-            O = slot[None, :] == lane_lo
-        else:
-            # lo is a power of two here: low part by mask, high by shift
-            O = (slot & (lo - 1))[None, :] == lane_lo
-            V = jnp.where((slot >> (lo.bit_length() - 1))[None, :] == lane_hi,
-                          jnp.repeat(V, H, axis=0), 0.0)     # (12·H, blk)
-        return acc + jax.lax.dot_general(
-            V.astype(jnp.bfloat16), O.astype(jnp.bfloat16),
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        return jnp.where(slot >= 0, jnp.minimum(slot, tot_slots), tot_slots)
 
-    acc0 = _compat_pcast(jnp.zeros((C * H, lo), jnp.float32), ("rows",),
-                         to="varying")
-    acc = jax.lax.fori_loop(0, -(-n // blk), body, acc0)
-    acc = acc.reshape(_LEAF_PIECES, _LEAF_COLS, H * lo)
-    acc = (acc[2] + acc[1] + acc[0])[:, :L].T                # (L, 4)
-    with jax.named_scope("psum"):
-        acc = jax.lax.psum(acc, "rows")
-    return acc[:tot_slots]
+    def cols_of(sl):
+        wb = sl(w)
+        return wb, wb * sl(y), sl(num), sl(den)
+
+    return segsum.segment_sum_mxu(
+        slot_of, cols_of, n=row_leaf.shape[0], k=_LEAF_COLS,
+        nslots=tot_slots + 1, axis="rows", blk=blk)[:tot_slots]
 
 
 # ---------------------------------------------------------------------------
@@ -730,10 +684,8 @@ def _count_psum(sites: dict, shards: int) -> None:
 
 def _pick_blk(n_shard: int, lanes: int) -> int:
     """Row-block size: keep the per-block (blk, lanes) bf16 one-hot under
-    ~64 MB."""
-    budget = 64 * 1024 * 1024 // (2 * lanes)
-    blk = 1 << max(int(np.floor(np.log2(max(budget, 1)))), 10)
-    return int(min(blk, max(n_shard, 1)))
+    ~64 MB (ops.segsum.row_block)."""
+    return segsum.row_block(n_shard, lanes)
 
 
 def _mesh_size(mesh) -> int:

@@ -43,9 +43,10 @@ from typing import Any, Dict, List, Optional
 # exporter lowerings, pack = sharded data-plane packers, probe = the
 # supervised boot first-compile, tree = tree-grower programs (histogram
 # builds, grow/apply steps, per-tree pre/post residual math, compressed
-# forest traversal — everything a GBM/DRF train compiles)
+# forest traversal — everything a GBM/DRF train compiles), metrics =
+# model-metrics accumulation passes (the AUC histogram)
 FAMILIES = frozenset({"scoring", "explain", "binning", "rapids", "pipeline",
-                      "artifact", "pack", "probe", "tree"})
+                      "artifact", "pack", "probe", "tree", "metrics"})
 
 # persistent-compile-cache families whose actual compiles feed the legacy
 # note_compile() counter (the warm-restart zero-compile assertions)

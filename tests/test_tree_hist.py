@@ -14,6 +14,7 @@ import pytest
 
 from h2o3_tpu.core.frame import Column, Frame
 from h2o3_tpu.models.tree import device_tree
+from h2o3_tpu.ops import segsum
 
 ROOT = Path(__file__).resolve().parent.parent
 
@@ -455,6 +456,7 @@ def test_leaf_split_rule_from_shape(name, form, split):
         depth, device_tree.frontier_cap(F, maxB)) + 1
     H, lo = device_tree.leaf_split(L)
     assert (H, lo) == split and (H - 1) * lo < L <= H * lo
+    assert (H, lo) == segsum.onehot_split(L, 4)     # ops/segsum's rule
     assert device_tree.leaf_forms(depth, F, maxB) == form
     assert device_tree.leaf_lanes(L) == 12 * H + lo
     if H > 1:       # no other power of two gives fewer lanes by a tile
