@@ -223,18 +223,31 @@ def _onehot_dot(O, V):
     return dot(O, V)
 
 
+def _is_const(col: Column) -> bool:
+    r = col.rollups
+    return r.rows == 0 or r.min == r.max
+
+
 class DataInfo:
     """Expansion plan for a predictor set + response.
 
     use_all_factor_levels: False drops the first level per categorical
     (reference DataInfo 'useAllFactorLevels' — GLM drops, DL keeps).
+
+    ignore_const_cols: True leaves out every predictor whose rollups read
+    min == max or no valid row (H2O ModelBuilder's ``ignore_const_cols``):
+    imputed, such a column is one value in every row. Their names, in frame
+    order, are ``const_cols``.
     """
+
+    const_cols: Tuple[str, ...] = ()
 
     def __init__(self, frame: Frame, response: Optional[str] = None,
                  *, ignored: Sequence[str] = (),
                  weights: Optional[str] = None, offset: Optional[str] = None,
                  standardize: bool = True, use_all_factor_levels: bool = False,
-                 missing_values_handling: str = "MeanImputation"):
+                 missing_values_handling: str = "MeanImputation",
+                 ignore_const_cols: bool = False):
         self.response_name = response
         self.weights_name = weights
         self.offset_name = offset
@@ -245,11 +258,16 @@ class DataInfo:
         skip = set(ignored) | {response, weights, offset} - {None}
         self.cat_names: List[str] = []
         self.num_names: List[str] = []
+        const = []
         for n in frame.names:
             c = frame.col(n)
             if n in skip or c.is_string:
                 continue
+            if ignore_const_cols and _is_const(c):
+                const.append(n)
+                continue
             (self.cat_names if c.is_categorical else self.num_names).append(n)
+        self.const_cols = tuple(const)
         # categoricals first, then numerics — reference column ordering
         self.predictor_names = self.cat_names + self.num_names
 

@@ -77,6 +77,7 @@ class _Net(NamedTuple):
     hid_drop: Tuple[float, ...] = ()
     batch: int = 32
     opt: Tuple = ("adadelta", 0.99, 1e-8)
+    max_w2: float = math.inf     # inf: no rescale is traced at all
 
     @property
     def n_cols(self) -> int:
@@ -195,9 +196,28 @@ def _optimizer(net: _Net):
             if mom > 0 else optax.sgd(learning_rate=lr_sched))
 
 
+def _max_w2(max_w2: float, params):
+    """H2O's ``max_w2`` (Neurons.java): after an update, a unit whose
+    incoming weights' squared norm r2 (a column of W, fan_in x fan_out)
+    exceeds max_w2 has them scaled by sqrt(max_w2 / r2); biases untouched.
+    -> (params, units rescaled)."""
+    import jax.numpy as jnp
+
+    out, rescaled = [], 0
+    for W, b in params:
+        r2 = jnp.sum(W * W, axis=0)
+        over = r2 > max_w2
+        W = W * jnp.where(over, jnp.sqrt(max_w2 / jnp.where(over, r2, 1.0)),
+                          1.0)
+        out.append((W, b))
+        rescaled = rescaled + jnp.sum(over, dtype=jnp.int32)
+    return out, rescaled
+
+
 def _sgd_step(net: _Net, opt, carry, idx, moments, arrays, y, w, kdrop):
     """One minibatch: the rows ``idx`` of the stored columns, their
-    gradient, the optimizer's update."""
+    gradient, the optimizer's update, then ``max_w2``'s rescale where it is
+    finite. -> (params, opt_state, units rescaled: 0 without the rescale)."""
     import jax
     import optax
 
@@ -206,7 +226,11 @@ def _sgd_step(net: _Net, opt, carry, idx, moments, arrays, y, w, kdrop):
         params, moments, tuple(a[idx] for a in arrays), y[idx], w[idx],
         kdrop)
     updates, opt_state = opt.update(grads, opt_state, params)
-    return optax.apply_updates(params, updates), opt_state
+    params = optax.apply_updates(params, updates)
+    if math.isinf(net.max_w2):
+        return params, opt_state, 0
+    params, rescaled = _max_w2(net.max_w2, params)
+    return params, opt_state, rescaled
 
 
 # steps a run of the training program takes at most: 76 ms of a v5e at the
@@ -218,25 +242,30 @@ DL_STEPS_A_DISPATCH = 2048
 
 @functools.partial(__import__("jax").jit, static_argnames=("net",))
 def _dl_train_steps(params, opt_state, key, steps, nrows, arrays, moments, y,
-                    w, *, net):
+                    w, rescaled=None, *, net):
     """``steps`` minibatch steps in one program (a while loop, so one
     compile serves whole and partial epochs). A step's rows are
     ``jax.random.randint(kidx, (batch,), 0, nrows)`` with ``key, kidx,
     kdrop = jax.random.split(key, 3)``: only the frame's real rows are
-    drawn."""
+    drawn. Where ``net.max_w2`` is finite, ``rescaled`` (an int32 scalar)
+    carries the count of units its rescale touched and comes back as a
+    fourth output; otherwise it is None and the program holds no trace of
+    the rescale."""
     import jax
 
     opt = _optimizer(net)
 
     def body(_i, carry):
-        params, opt_state, key = carry
+        params, opt_state, key, *count = carry
         key, kidx, kdrop = jax.random.split(key, 3)
         idx = jax.random.randint(kidx, (net.batch,), 0, nrows)
-        params, opt_state = _sgd_step(net, opt, (params, opt_state), idx,
-                                      moments, arrays, y, w, kdrop)
-        return params, opt_state, key
+        params, opt_state, n = _sgd_step(net, opt, (params, opt_state), idx,
+                                         moments, arrays, y, w, kdrop)
+        return (params, opt_state, key) + tuple(c + n for c in count)
 
-    return jax.lax.fori_loop(0, steps, body, (params, opt_state, key))
+    init = (params, opt_state, key) + (
+        () if rescaled is None else (rescaled,))
+    return jax.lax.fori_loop(0, steps, body, init)
 
 
 @functools.lru_cache(maxsize=16)
@@ -264,8 +293,9 @@ def _dl_averaging_program(net: _Net, mesh, avg_period: int):
             idx = jax.random.randint(kidx, (net.batch,), 0,
                                      jnp.maximum(real, 1))
             wl = jnp.where(real > 0, w, 0.0)
-            params, opt_state = _sgd_step(net, opt, (params, opt_state), idx,
-                                          moments, arrays, y, wl, kdrop)
+            params, opt_state, _ = _sgd_step(net, opt, (params, opt_state),
+                                             idx, moments, arrays, y, wl,
+                                             kdrop)
             return params, opt_state, key_l
 
         def sync_round(_r, carry):
@@ -510,6 +540,12 @@ class DeepLearning(ModelBuilder):
             "initial_weight_scale": 1.0,
             "score_each_iteration": False,
             "variable_importances": True,
+            # H2O's Neurons: a unit's incoming squared weights are capped
+            # after every update; +inf (or Float.MAX_VALUE) is off
+            "max_w2": math.inf,
+            # H2O's ModelBuilder: predictors with min == max or all NA
+            # leave the design (their names on the model's output)
+            "ignore_const_cols": True,
         })
         return p
 
@@ -536,7 +572,8 @@ class DeepLearning(ModelBuilder):
             # expanded layout: predictor names and categorical domains must
             # match (same guard SharedTree._fit applies)
             skip = {resp, p.get("weights_column"), p.get("offset_column"),
-                    p.get("fold_column")} | set(p.get("ignored_columns") or [])
+                    p.get("fold_column")} | set(p.get("ignored_columns") or []) \
+                | set(getattr(prev._output, "const_cols_dropped", ()))
             names = [c for c in train.names
                      if c not in skip and not train.col(c).is_string]
             doms = {c: list(train.col(c).domain) for c in names
@@ -550,13 +587,17 @@ class DeepLearning(ModelBuilder):
             # stage span ``design``: DataInfo reads the rollups and modes of
             # the predictors on the host (cached on the columns after a
             # frame's first job), which is where it has always blocked
-            with tracing.span("design", rows=train.nrows):
+            with tracing.span("design", rows=train.nrows) as sp:
                 di = DataInfo(train, response=resp,
                               ignored=p.get("ignored_columns") or (),
                               weights=p.get("weights_column"),
                               standardize=bool(p.get("standardize", True)),
                               use_all_factor_levels=bool(
-                                  p.get("use_all_factor_levels", True)))
+                                  p.get("use_all_factor_levels", True)),
+                              ignore_const_cols=bool(
+                                  p.get("ignore_const_cols", True)))
+                sp.set(inputs=di.fullN,
+                       const_cols_dropped=len(di.const_cols))
         n = train.nrows
         arrays = tuple(c.data for c in di.cols(train))
         moments = di.moments()
@@ -620,7 +661,7 @@ class DeepLearning(ModelBuilder):
                    l2=float(p.get("l2", 0.0)),
                    in_drop=float(p.get("input_dropout_ratio", 0.0)),
                    hid_drop=tuple(float(h) for h in (hid_drop or [])),
-                   batch=batch, opt=opt_spec)
+                   batch=batch, opt=opt_spec, max_w2=_max_w2_of(p))
 
         epochs = float(p.get("epochs", 10.0))
         total = max(_steps_of(epochs, n, batch), 1)
@@ -650,9 +691,12 @@ class DeepLearning(ModelBuilder):
         averaging = avg_period > 1 and n_dev > 1
 
         dispatches = 0
+        # units max_w2 rescaled, summed on the device across the runs of
+        # the training program and read once after the last
+        rescaled = None if math.isinf(net.max_w2) else jnp.int32(0)
 
         def run_steps(params, opt_state, key, k):
-            nonlocal dispatches
+            nonlocal dispatches, rescaled
             if averaging:
                 key, sub = jax.random.split(key)
                 params, opt_state = _dl_averaging_program(
@@ -662,9 +706,10 @@ class DeepLearning(ModelBuilder):
                 dispatches += 1
                 return params, opt_state, key
             for lo in range(0, k, DL_STEPS_A_DISPATCH):
-                params, opt_state, key = _dl_train_steps(
+                params, opt_state, key, *count = _dl_train_steps(
                     params, opt_state, key, min(DL_STEPS_A_DISPATCH, k - lo),
-                    n, arrays, moments, y, row_w, net=net)
+                    n, arrays, moments, y, row_w, rescaled, net=net)
+                rescaled = count[0] if count else None
                 dispatches += 1
             return params, opt_state, key
 
@@ -678,6 +723,15 @@ class DeepLearning(ModelBuilder):
 
         model = DeepLearningModel(parms=dict(p))
         self._init_output(model, train)
+        # the constant predictors are ignored columns from here on (H2O's
+        # warning lists them): scoring a frame takes no notice of them
+        dropped = set(di.const_cols)
+        model._output.names = [c for c in model._output.names
+                               if c not in dropped]
+        model._output.domains = {c: d for c, d in
+                                 model._output.domains.items()
+                                 if c not in dropped}
+        model._output.const_cols_dropped = list(di.const_cols)
         if autoencoder:
             model._output.model_category = ModelCategory.AutoEncoder
             model._output.response_name = None
@@ -751,6 +805,8 @@ class DeepLearning(ModelBuilder):
         metrics.inc("h2o3_dl_steps_total", done - first)
         metrics.inc("h2o3_dl_samples_total", (done - first) * batch)
         metrics.inc("h2o3_dl_dispatches_total", dispatches)
+        if rescaled is not None:
+            metrics.inc("h2o3_dl_max_w2_rescaled_total", int(rescaled))
 
         model.epochs_trained = epochs_at(done)
         model.params_tree = jax.tree.map(np.asarray, params_t)
@@ -760,6 +816,13 @@ class DeepLearning(ModelBuilder):
             model._output.variable_importances = _garson_importance(
                 model.params_tree, di)
         return model
+
+
+def _max_w2_of(p: dict) -> float:
+    """The ``max_w2`` parameter; H2O's clients send Float.MAX_VALUE for
+    its default, which is no cap either."""
+    v = float(p.get("max_w2", math.inf))
+    return math.inf if v >= 3.4028235e38 else v
 
 
 def _init_params(in_dim: int, hidden: List[int], out_dim: int, seed: int,

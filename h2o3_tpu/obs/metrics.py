@@ -504,6 +504,11 @@ def _install_default_metrics() -> None:
     r.counter("h2o3_dl_dispatches_total",
               "runs of the DeepLearning training program those steps took "
               "(an epoch's steps in runs of at most DL_STEPS_A_DISPATCH)")
+    r.counter("h2o3_dl_max_w2_rescaled_total",
+              "units whose incoming weights max_w2 rescaled, once a step "
+              "each, summed on the device over a job's runs of the "
+              "training program and read after the last (jobs with a "
+              "finite max_w2 and no model averaging)")
     r.counter("h2o3_forest_walk_total",
               "dispatches of a forest-walk program (predict_binned, "
               "leaf_index, the scoring session's fused programs), by form: "

@@ -96,7 +96,7 @@ _HEAVY_MODULES = [
     "test_survival_gam_rulefit", "test_grid", "test_search_resume",
     # long single fits / many submodels
     "test_automl", "test_automl_bindings", "test_deep_trees",
-    "test_deeplearning",
+    "test_deeplearning", "test_mnist_dl_reference",
     # 2-process localhost clouds: minutes per test, run dead last
     "test_multiprocess",
 ]

@@ -273,6 +273,28 @@ def test_first_layer_equals_the_expanded_form(cl, all_levels):
         model.delete()
 
 
+def test_ignore_const_cols_keeps_the_airline_design(cl, cfg):
+    """H2O's default ``ignore_const_cols`` on the cell's frame at
+    dry_run_rows: no predictor of the airline recipe is constant, so the
+    design keeps its 674 inputs and the layout the training program is
+    compiled for is the one without the parameter."""
+    from h2o3_tpu.core.dkv import DKV
+
+    key = "airline_dl_const.hex"
+    out = recipe.device_columns(SEEDS[0], int(cfg["dry_run_rows"]),
+                                sharding=cl.row_sharding())
+    frame = _install(cl, key, out[:-1], out[-1])
+    try:
+        args = dict(response=recipe.RESPONSE_NAME,
+                    use_all_factor_levels=bool(
+                        cfg["params"]["use_all_factor_levels"]))
+        di = DataInfo(frame, ignore_const_cols=True, **args)
+        assert di.const_cols == () and di.fullN == 674
+        assert di.layout() == DataInfo(frame, **args).layout()
+    finally:
+        DKV.remove(key)
+
+
 def test_programs_hold_no_design_at_one_million_rows(cl, cfg):
     """The training program, the loss pass and the scoring pass of the
     configuration's network compiled at 1M rows: their temporaries stay
